@@ -112,3 +112,57 @@ def build_poseidon_rf_circuit(
         state = mds.apply(cs, sboxed)
     PublicInputGate.place(cs, state[0])
     return cs, state[0]
+
+
+# ---------------------------------------------------------------------------
+# Bench-scale circuits (bench.py, chip_smoke.py, scripts/)
+# ---------------------------------------------------------------------------
+
+SHA256_BENCH_GEOMETRY = CSGeometry(
+    num_columns_under_copy_permutation=60,
+    num_witness_columns=0,
+    num_constant_columns=8,
+    max_allowed_constraint_degree=7,
+)
+SHA256_BENCH_LOOKUP = LookupParameters(width=4, num_repetitions=8)
+
+
+def build_sha256_bench_circuit(num_bytes: int = 8192):
+    """Upstream's SHA-256 benchmark at its own widths (reference
+    src/gadgets/sha256/mod.rs:269 and README "For curions in benchmarks"):
+    60 copy columns, 8 constant columns, 8 width-4 lookup sub-arguments.
+    An 8 kB message fills a 2^16-row trace; the lookup tables keep anything
+    up to 1 kB at 2^14. Returns the ConstraintSystem."""
+    from .gadgets import allocate_u8_input, sha256
+
+    # a CAPACITY bound — pad_and_shrink rounds the trace to the smallest
+    # power of two that fits: 8 kB fills 2^16, the north-star 128 kB 2^20
+    capacity = 1 << max(17, (num_bytes // 8192).bit_length() + 16)
+    cs = ConstraintSystem(
+        SHA256_BENCH_GEOMETRY, capacity, lookup_params=SHA256_BENCH_LOOKUP
+    )
+    data = bytes(i % 255 for i in range(num_bytes))
+    sha256(cs, allocate_u8_input(cs, data))
+    return cs
+
+
+def build_fma_bench_circuit(log_n: int):
+    """A 2^log_n-row fma chain over 16 copy columns. Degree-3 chunks keep
+    every relation at degree <= 4, so the whole pipeline runs at LDE
+    factor 4 (half the memory of the SHA geometry). Returns the
+    ConstraintSystem."""
+    geom = CSGeometry(
+        num_columns_under_copy_permutation=16,
+        num_witness_columns=0,
+        num_constant_columns=6,
+        max_allowed_constraint_degree=3,
+    )
+    cs = ConstraintSystem(geom, 1 << log_n)
+    a = cs.alloc_variable_with_value(1)
+    b = cs.alloc_variable_with_value(2)
+    per_row = FmaGate.instance().num_repetitions(geom)
+    steps = ((1 << log_n) - 8) * per_row
+    for _ in range(steps):
+        a, b = b, FmaGate.fma(cs, a, b, a, 1, 1)
+    PublicInputGate.place(cs, b)
+    return cs

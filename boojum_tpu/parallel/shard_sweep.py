@@ -66,10 +66,7 @@ def mesh_from_shape(shape) -> Mesh:
 
 
 def _interp() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------

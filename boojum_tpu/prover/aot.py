@@ -83,8 +83,8 @@ MANIFEST_NAME = "manifest.json"
 # platform fields that must match EXACTLY between build and load for the
 # compiled cache entries to be usable: the persistent-cache key covers
 # jax/backend identity, and XLA:CPU AOT code additionally embeds the
-# compile host's vector features (_hostfp.py — loading a mismatched
-# entry SIGILLs rather than missing). Device topology is keyed on
+# compile host's vector features (host_fingerprint below — a bundle is
+# carried between machines on purpose, so its manifest records them). Device topology is keyed on
 # (process_count, per-host device count), NOT the global device count:
 # every host of a multi-process run sees the same pair, so a bundle
 # built on host 0 of a pod warms hosts 1..P-1, while a single-host run
@@ -185,6 +185,29 @@ def variant_fingerprint(mesh_shape=None) -> dict:
 _PLATFORM_INFO: dict | None = None
 
 
+def host_fingerprint() -> str:
+    """Short stable hash of this host's CPU feature set: the machine
+    identity of AOT manifests and report lines (XLA:CPU executables embed
+    the compile machine's vector features). /proc/cpuinfo's flags where
+    readable, else arch + processor + node name."""
+    import platform
+
+    desc = platform.machine()
+    flags_found = False
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    desc += " " + " ".join(sorted(line.split(":", 1)[1].split()))
+                    flags_found = True
+                    break
+    except OSError:
+        pass
+    if not flags_found:
+        desc += f" {platform.processor()} {platform.node()}"
+    return hashlib.sha256(desc.encode()).hexdigest()[:8]
+
+
 def platform_info() -> dict:
     """The exact-match stack identity the compiled cache entries are
     valid on (manifest-recorded, load-validated). Computed once per
@@ -196,8 +219,6 @@ def platform_info() -> dict:
         return dict(_PLATFORM_INFO)
     import jax
     import jaxlib
-
-    from .._hostfp import host_fingerprint
 
     try:
         dev = jax.devices()[0]
@@ -361,21 +382,11 @@ def _active_cache_dir() -> str | None:
     if d:
         os.makedirs(d, exist_ok=True)
         return d
-    if os.environ.get("BOOJUM_TPU_NO_COMPILE_CACHE"):
-        return None
-    from .._hostfp import host_fingerprint
+    from .. import compile_cache
 
-    plat = (
-        os.environ.get("JAX_PLATFORMS", "").strip().replace(",", "-")
-        or "default"
-    )
-    d = os.environ.get(
-        "BOOJUM_TPU_COMPILE_CACHE",
-        os.path.expanduser(
-            f"~/.cache/boojum_tpu_xla-{plat}-{host_fingerprint()}"
-        ),
-    )
-    jax.config.update("jax_compilation_cache_dir", d)
+    d = compile_cache.enable()
+    if not d:
+        return None
     _reset_persistent_cache()
     os.makedirs(d, exist_ok=True)
     return d
@@ -606,16 +617,6 @@ class LoadedBundle:
     load_s: float = 0.0
 
 
-# cache-entry basenames installed by any load this process performed —
-# bench.py's size-capped prune consults this so artifact-backed entries
-# are never evicted out from under the run that loaded them
-_LOADED_CACHE_FILES: set[str] = set()
-
-
-def loaded_cache_files() -> set[str]:
-    return set(_LOADED_CACHE_FILES)
-
-
 def load_bundle(
     out_root: str,
     assembly,
@@ -739,7 +740,6 @@ def load_bundle(
             installed.append(base)
             total_bytes += int(ent.get("bytes", 0))
     load_s = time.perf_counter() - t0
-    _LOADED_CACHE_FILES.update(installed)
     _metrics.count_aot("bundles_loaded")
     _metrics.gauge_aot_add("load_s", load_s)
     _metrics.gauge_aot_add("bundle_bytes", float(total_bytes))

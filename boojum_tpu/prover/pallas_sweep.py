@@ -85,10 +85,7 @@ def limb_sweep_enabled() -> bool:
     mode)."""
     from ..utils.transfer import env_flag_opt
 
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        return False
+    backend = jax.default_backend()
     # the backend-dependent default makes the knob tri-state: unset means
     # "native backends only"
     explicit = env_flag_opt("BOOJUM_TPU_LIMB_SWEEP")
@@ -139,17 +136,13 @@ def limb_resident_enabled() -> bool:
         return False
     if explicit is True:
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    # interpret mode because the backend IS the CPU, never because the
+    # backend failed to start: that error propagates
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------

@@ -119,7 +119,11 @@ DEVICE_PEAKS = (
     # XLA:CPU single-core nominal: a few int64 lanes at a few GHz
     ("cpu", (20.0, 25.0, 0.0, 0.0)),
 )
-_DEFAULT_PEAKS = (50.0, 50.0, 0.0, 0.0)
+
+
+class UnknownDeviceError(LookupError):
+    """The device kind is not in DEVICE_PEAKS and no override names its
+    peaks: a roofline share against an invented peak would be a guess."""
 
 
 def cost_enabled() -> bool:
@@ -134,16 +138,13 @@ def device_peaks() -> dict:
     """The active device's nominal peaks: {kind, peak_gflops,
     peak_hbm_gbps, peak_ici_gbps, peak_dcn_gbps, source}.
     BOOJUM_TPU_COST_PEAKS="gflops,hbm_gbps[,ici_gbps[,dcn_gbps]]"
-    overrides the table (source:"env"); an unknown device kind falls
-    to a conservative default (source:"default")."""
-    kind = "unknown"
-    try:
-        import jax
+    overrides the table (source:"env"); a device kind that is in neither
+    raises UnknownDeviceError (attach_cost_record then stamps no roofline
+    record — a share of a guessed peak is worse than none)."""
+    import jax
 
-        dev = jax.devices()[0]
-        kind = str(getattr(dev, "device_kind", dev.platform))
-    except Exception:
-        pass
+    dev = jax.devices()[0]
+    kind = str(getattr(dev, "device_kind", dev.platform))
     env = os.environ.get("BOOJUM_TPU_COST_PEAKS", "").strip()
     if env:
         # a malformed override falls back to the table (logged), never
@@ -177,12 +178,10 @@ def device_peaks() -> dict:
                 "peak_hbm_gbps": peaks[1], "peak_ici_gbps": peaks[2],
                 "peak_dcn_gbps": peaks[3], "source": "table",
             }
-    return {
-        "kind": kind, "peak_gflops": _DEFAULT_PEAKS[0],
-        "peak_hbm_gbps": _DEFAULT_PEAKS[1],
-        "peak_ici_gbps": _DEFAULT_PEAKS[2],
-        "peak_dcn_gbps": _DEFAULT_PEAKS[3], "source": "default",
-    }
+    raise UnknownDeviceError(
+        f"no peaks for device kind {kind!r}: add it to DEVICE_PEAKS or "
+        f"set BOOJUM_TPU_COST_PEAKS"
+    )
 
 
 # ---------------------------------------------------------------------------

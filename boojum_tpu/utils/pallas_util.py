@@ -68,10 +68,7 @@ def pallas_enabled(opt_in_env: str | None = None) -> bool:
 
     if not env_flag("BOOJUM_TPU_PALLAS", True):
         return False
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:
+    if jax.default_backend() != "tpu":
         return False
     if _LOCAL_OPERANDS[0]:
         return True
@@ -81,17 +78,11 @@ def pallas_enabled(opt_in_env: str | None = None) -> bool:
 
 
 def tpu_compiler_params(vmem_limit_bytes: int):
-    """A pltpu CompilerParams instance tolerating both pallas API
-    generations (`CompilerParams` was `TPUCompilerParams` before jax 0.5),
-    or None when neither exists — so interpret-mode fallback, which the
-    shard_map mesh path uses for CPU parity tests, imports everywhere.
+    """The Mosaic compiler parameters carrying a scoped-VMEM limit.
     Shared by the Poseidon2 / limb-sweep / MXU-NTT kernel modules."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    return cls(vmem_limit_bytes=vmem_limit_bytes) if cls else None
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes)
 
 
 def pick_tile(R: int, budget_rows: int) -> int:

@@ -179,14 +179,14 @@ def _roundtrip():
     tmp = tempfile.mkdtemp(prefix="boojum_aot_")
     build = _run_driver(
         _BUILD_SRC, tmp, "build",
-        {"BOOJUM_TPU_COMPILE_CACHE": os.path.join(tmp, "build_cache")},
+        {"JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "build_cache")},
     )
     serve = _run_driver(
         _SERVE_SRC, tmp, "serve",
         {
             "BOOJUM_TPU_AOT_DIR": os.path.join(tmp, "bundles"),
             # an EMPTY cache dir: the only warm state is the bundle
-            "BOOJUM_TPU_COMPILE_CACHE": os.path.join(tmp, "fresh_cache"),
+            "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "fresh_cache"),
         },
     )
     return tmp, build, serve
@@ -440,55 +440,6 @@ def test_corrupt_entry_skipped(tmp_path):
     assert reg.counters.get("aot.corrupt_entries") == 1
     assert os.path.basename(victim["file"]) not in out.cache_files
     assert len(out.cache_files) == len(manifest["cache_entries"]) - 1
-
-
-def test_bench_prune_protects_current_run_and_bundle_entries(tmp_path):
-    """Satellite: the BENCH_CACHE_MAX_BYTES prune evicts old stems but
-    never entries touched since process start or installed from a
-    loaded artifact bundle (runs bench's prune in a subprocess — bench
-    import reconfigures jax caches)."""
-    root = str(tmp_path)
-    d = os.path.join(root, ".jax_cache_bench_test_fp")
-    os.makedirs(d)
-    names = {
-        "old1-cache": -86400, "old1-atime": -86400,
-        "old2-cache": -86400, "old2-atime": -86400,
-        "bundle1-cache": -86400, "bundle1-atime": -86400,
-        "fresh1-cache": +3600,
-    }
-    for name, dt in names.items():
-        p = os.path.join(d, name)
-        with open(p, "wb") as f:
-            f.write(b"x" * 1024)
-        ts = __import__("time").time() + dt
-        os.utime(p, (ts, ts))
-    driver = os.path.join(root, "prune_driver.py")
-    with open(driver, "w") as f:
-        f.write(
-            textwrap.dedent(
-                f"""
-                import sys
-                sys.path.insert(0, {REPO!r})
-                import bench
-                from boojum_tpu.prover import aot
-                aot._LOADED_CACHE_FILES.update(
-                    ["bundle1-cache", "bundle1-atime"]
-                )
-                bench._prune_bench_caches({root!r})
-                """
-            )
-        )
-    env = dict(os.environ)
-    env["BENCH_CACHE_MAX_BYTES"] = "2048"  # force eviction pressure
-    proc = subprocess.run(
-        [sys.executable, driver], capture_output=True, text=True,
-        timeout=300, env=env, cwd=REPO,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    left = set(os.listdir(d))
-    # bundle-installed and freshly-touched stems survive; old ones die
-    assert {"bundle1-cache", "bundle1-atime", "fresh1-cache"} <= left
-    assert "old1-cache" not in left and "old2-cache" not in left
 
 
 def test_platform_info_does_not_memoize_failed_probe(monkeypatch):

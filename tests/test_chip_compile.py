@@ -1,0 +1,200 @@
+"""Compile the main path's kernels for a DESCRIBED TPU v5e, with
+`interpret=False`, at the SHA-256 2^16 shapes (60 copy columns + 8 width-4
+lookups: 93 witness columns, LDE 8, so 2^19 leaves).
+
+The chip's compiler is installed here and compiles for a chip that is not
+attached: what it would refuse on the machine (a slice not aligned to the
+tiling, more VMEM or SMEM than a kernel may use) it refuses in this file,
+at no chip time. Nothing runs; a compile that passes is not a chip run.
+
+This is the ONLY test file that describes the chip, and it does so inside
+a fixture: only one process may load the TPU library, pytest-xdist workers
+each import every test file, and a module that touched libtpu at import
+would leave the workers with different collections. The whole-oracle
+kernels that take minutes (`*:leaf_digests_limbres`, `node_layers_limbres`,
+the L=8 sponge at a 256-row tile) belong to
+scripts/chip_compile_rehearsal.py, not here.
+"""
+
+import os
+import sys
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+LOG_N = 16           # SHA-256 8 kB trace
+LDE = 8
+LEAVES = (1 << LOG_N) * LDE
+COPY_COLS = 60
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """An entry compiled for an absent chip is written to the persistent
+    cache but cannot be read back: keep it off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _u32(one_chip, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=one_chip)
+
+
+def _pair(one_chip, *shape):
+    s = _u32(one_chip, *shape)
+    return (s, s)
+
+
+def _compile(fn, *args):
+    txt = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in txt, "no Mosaic kernel in the compiled module"
+
+
+def _jit_static(fn, *static):
+    """A jitted wrapper fixing a kernel entry point's static arguments, so
+    `.lower` takes only the array shapes."""
+    return jax.jit(lambda *arrays: fn(*arrays, *static))
+
+
+@pytest.mark.parametrize("L", [64, 128])
+def test_poseidon2_leaf_sponge(one_chip, L):
+    """The leaf sponge over 2^19 leaves of an L-wide column block (the
+    oracles absorb their columns in such blocks), with the tile
+    sponge_hash_planes picks for that width."""
+    from boojum_tpu.hashes import pallas_poseidon2 as p2
+
+    R = LEAVES // 128
+    chunks = L // 8
+    tile = p2._pick_tile(R, max(8, (2 << 20) // (8 * chunks * 128 * 8)))
+    v = _u32(one_chip, L, R, 128)
+    _compile(_jit_static(p2._sponge_planes, chunks, tile, False), v, v)
+
+
+def test_poseidon2_node_permutation(one_chip):
+    """One Merkle node layer: 2^19 states as (12, 4096, 128) planes."""
+    from boojum_tpu.hashes import pallas_poseidon2 as p2
+
+    R = LEAVES // 128
+    s = _u32(one_chip, 12, R, 128)
+    _compile(_jit_static(p2._permute_planes, p2._pick_tile(R, 16), False), s, s)
+
+
+def _mxu_planes(one_chip, lead):
+    from boojum_tpu.ntt import mxu_ntt
+
+    ctx = mxu_ntt.get_mxu_ctx(LOG_N)
+    return _pair(one_chip, *lead, ctx.R, ctx.C)
+
+
+def test_mxu_ntt_forward(one_chip):
+    from boojum_tpu.ntt import mxu_ntt
+
+    planes = _mxu_planes(one_chip, (COPY_COLS,))
+    _compile(
+        jax.jit(lambda p: mxu_ntt._fft_planes(p, LOG_N, False)), planes
+    )
+
+
+def test_mxu_ntt_inverse(one_chip):
+    from boojum_tpu.ntt import mxu_ntt
+
+    planes = _mxu_planes(one_chip, (COPY_COLS,))
+    _compile(
+        jax.jit(lambda p: mxu_ntt._ifft_planes(p, LOG_N, False)), planes
+    )
+
+
+def test_mxu_fused_lde(one_chip):
+    from boojum_tpu.ntt import mxu_ntt
+
+    coeffs = _mxu_planes(one_chip, (COPY_COLS,))
+    scale = _mxu_planes(one_chip, (LDE,))
+    _compile(
+        jax.jit(lambda c, s: mxu_ntt._lde_planes(c, s, LOG_N, False)),
+        coeffs, scale,
+    )
+
+
+def test_fri_fold(one_chip):
+    """The first (largest) FRI fold: 2^19 ext values to 2^18."""
+    from boojum_tpu.prover import pallas_sweep
+
+    values = (_pair(one_chip, LEAVES), _pair(one_chip, LEAVES))
+    table = _u32(one_chip, 4, 1)
+    inv_x = _pair(one_chip, LEAVES // 2)
+    _compile(
+        jax.jit(
+            lambda v, t, x: pallas_sweep.fri_fold_planes(
+                v, t, x, interpret=False
+            )
+        ),
+        values, table, inv_x,
+    )
+
+
+def test_fused_limb_coset_sweep(one_chip, monkeypatch):
+    """`coset_sweep_terms_limbres` of upstream's SHA-256 circuit: gates,
+    copy-permutation and the 8 lookup arguments fused over 93 witness and
+    106 setup columns — the kernel most likely to meet the VMEM or SMEM
+    limit. The spec comes from the program's own enumeration; the 1 kB
+    message synthesizes in seconds and has the 8 kB circuit's geometry
+    (the sweep closes over structure only), so its row count is scaled
+    from 2^14 to the 8 kB trace's 2^16 here. The dispatchers are steered
+    to the native set from the test, not through an option of the
+    program."""
+    from boojum_tpu.examples import build_sha256_bench_circuit
+    from boojum_tpu.prover import ProofConfig, enumerate_kernels
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    asm = build_sha256_bench_circuit(1024).into_assembly()
+    n = asm.trace_len
+    assert n == 1 << 14
+    cfg = ProofConfig(
+        fri_lde_factor=LDE, merkle_tree_cap_size=16, num_queries=50,
+        pow_bits=0, fri_final_degree=16,
+    )
+    (spec,) = [
+        s for s in enumerate_kernels(asm, cfg)
+        if s.name == "coset_sweep_terms_limbres"
+    ]
+    scale = (1 << LOG_N) // n
+
+    def place(x):
+        if not isinstance(x, jax.ShapeDtypeStruct):
+            return x
+        shape = tuple(d * scale if d % n == 0 else d for d in x.shape)
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=one_chip)
+
+    args = jax.tree.map(place, spec.args)
+    assert args[0][0].shape == (93, 1 << LOG_N)
+    _compile(spec.fn, *args)

@@ -477,20 +477,46 @@ def _report_artifact(path, total, stage_walls, label):
     return path
 
 
-def _bench_history_paths():
-    return [
-        os.path.join(REPO, f)
-        for f in (
-            "BENCH_BASELINE.json", "BENCH_r01.json", "BENCH_r02.json",
-            "BENCH_r03.json", "BENCH_r04.json",
+def _bench_history_paths(tmp_path):
+    """The five-point history the old chip rounds left (the record files
+    went in PR 22; their figures live on in ROADMAP.md): a bare baseline
+    line, two good driver wrappers, one rc=124 wrapper with no line, and
+    one watchdog line that never reached a prove."""
+    def line(value, **kw):
+        return dict(
+            {"metric": "sha256_8192B_prove_wall", "value": value,
+             "unit": "s"}, **kw
         )
-    ]
+
+    def wrapper(n, rc, parsed):
+        tail = "trace_len=65536\n"
+        if parsed is not None:
+            tail += json.dumps(parsed) + "\n"
+        return {"n": n, "cmd": "python bench.py", "rc": rc, "tail": tail,
+                "parsed": parsed}
+
+    docs = {
+        "BENCH_BASELINE.json": line(35.6178),
+        "BENCH_r01.json": wrapper(1, 0, line(21.6716)),
+        "BENCH_r02.json": wrapper(2, 0, line(19.7891)),
+        "BENCH_r03.json": wrapper(3, 124, None),
+        "BENCH_r04.json": wrapper(4, 0, line(
+            1500.0, status="timeout+no_prove", phase="warmup_prove",
+            reps=[],
+        )),
+    }
+    paths = []
+    for name, doc in docs.items():
+        p = tmp_path / name
+        p.write_text(json.dumps(doc))
+        paths.append(str(p))
+    return paths
 
 
 def test_trend_gate_fires_exactly_on_regressed_stage(tmp_path):
     """Acceptance: BENCH_*.json history + synthetic report artifacts —
     the gate exits nonzero exactly on the regressed stage: round3 blew
-    up 3x, every other series (including the totals fed by the real
+    up 3x, every other series (including the totals fed by the
     BENCH history and round5) stays quiet."""
     prev = _report_artifact(
         tmp_path / "prev.jsonl", 20.0,
@@ -501,7 +527,7 @@ def test_trend_gate_fires_exactly_on_regressed_stage(tmp_path):
         {"round3_quotient": 3.0, "round5_deep_fri": 2.05}, "last",
     )
     points, notes = report.load_trend_points(
-        _bench_history_paths() + [str(prev), str(last)]
+        _bench_history_paths(tmp_path) + [str(prev), str(last)]
     )
     # r03 (rc=124, parsed null) and r04 (timeout+no_prove) are skipped
     assert sum("BENCH_r03" in n for n in notes) == 1, notes
@@ -584,7 +610,7 @@ def test_trend_total_series_spans_bench_and_reports(tmp_path):
         tmp_path / "prev.jsonl", 20.0, {"round3_quotient": 1.0}, "prev"
     )
     points, _ = report.load_trend_points(
-        _bench_history_paths() + [str(prev)]
+        _bench_history_paths(tmp_path) + [str(prev)]
     )
     series = report.trend_series(points)
     totals = series[("", "total_wall")]["points"]
