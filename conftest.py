@@ -6,7 +6,10 @@ before anything imports jax.
 """
 
 import os
+import signal
 import sys
+
+import pytest
 
 # Force CPU: unit tests never take the chip (python chip_smoke.py does, in
 # its own process), and no subprocess a test starts may inherit it.
@@ -21,6 +24,13 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
 def pytest_configure(config):
+    # Workers that meet a fresh checkout together all build the native
+    # resolver into one temporary name, and a loser falls back to the
+    # Python resolver for its whole run (and skips the native engine's
+    # test). The controller builds it here, before xdist starts a worker.
+    from boojum_tpu import native
+
+    native.get_lib()
     # tier-1 runs with -m 'not slow' (ROADMAP.md); register the marker so
     # slow-lane tests don't warn as unknown
     config.addinivalue_line(
@@ -44,14 +54,30 @@ def pytest_configure(config):
     )
 
 
-def pytest_collection_modifyitems(items):
-    # run the AOT artifact tests LAST (stable sort): their subprocess
-    # bundle build pays real XLA compiles into a fresh bundle dir every
-    # run (the whole point is an isolated cache), which the repo-local
-    # persistent cache cannot amortize — if the tier-1 wall-clock budget
-    # dies mid-suite, that fixed cost must burn the END of the budget,
-    # not starve the alphabetically-later test files
-    items.sort(key=lambda it: it.fspath.basename == "test_aot.py")
+# One test may hold its worker this long, then it fails by name and the
+# run goes on. The alarm reaches Python code only (a sleep, a lock, a
+# socket or subprocess wait): a call into XLA (a compile, a compiled
+# program's run) is not interrupted, its test fails when the call returns.
+TEST_TIME_LIMIT_S = 420.0
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    def expired(_signum, _frame):
+        pytest.fail(
+            f"{item.nodeid} ran past the time limit of "
+            f"{TEST_TIME_LIMIT_S:g} s",
+            pytrace=False,
+        )
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, TEST_TIME_LIMIT_S)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
 
 # The package import applies the one compile-cache rule
 # (boojum_tpu/compile_cache.py): XLA:CPU compiles of the big unrolled prover

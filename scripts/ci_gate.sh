@@ -2,8 +2,9 @@
 # ci_gate.sh — the one-command CI gate (ISSUE 15 satellite).
 #
 # Runs, in order:
-#   1. the tier-1 pytest invocation (ROADMAP.md — CPU backend, fast
-#      markers only), and
+#   1. tier-1, with the selection, workers and limit of the driver's
+#      command (tests/ -m 'not slow', six xdist workers by file, 1470 s;
+#      the slow lane is -m slow), and
 #   2. the perf-trend regression gate over the checked-in BENCH_*.json
 #      and MULTICHIP_r*.json round history (scripts/prove_report.py
 #      --trend --gate: last point of every stage/metric series vs the
@@ -30,8 +31,8 @@
 # half-HBM cost sheet, sha256-over-babybear rejected at synthesis.
 #
 # Exits nonzero when any requested leg fails. Knobs:
-#   CI_GATE_TIMEOUT_S     tier-1 budget in seconds (default 870, as in
-#                         ROADMAP.md; the -k kill grace stays 10 s)
+#   CI_GATE_TIMEOUT_S     tier-1 budget in seconds (default 1470, the
+#                         driver's; the -k kill grace stays 10 s)
 #   CI_GATE_THRESHOLD     relative regression threshold (default 0.2)
 #   CI_GATE_MH_TIMEOUT_S  --multihost leg budget in seconds (default 3600)
 #   CI_GATE_TL_TIMEOUT_S  --timeline leg budget in seconds (default 300)
@@ -41,7 +42,7 @@ set -u -o pipefail
 root="$(cd "$(dirname "$0")/.." && pwd)"
 cd "$root"
 
-timeout_s="${CI_GATE_TIMEOUT_S:-870}"
+timeout_s="${CI_GATE_TIMEOUT_S:-1470}"
 threshold="${CI_GATE_THRESHOLD:-0.2}"
 mh_timeout_s="${CI_GATE_MH_TIMEOUT_S:-3600}"
 tl_timeout_s="${CI_GATE_TL_TIMEOUT_S:-300}"
@@ -67,7 +68,7 @@ echo "== ci_gate: tier-1 tests (budget ${timeout_s}s) =="
 timeout -k 10 "$timeout_s" env JAX_PLATFORMS=cpu \
     python -m pytest tests/ -q -m 'not slow' \
     --continue-on-collection-errors \
-    -p no:cacheprovider -p no:xdist -p no:randomly
+    -p no:cacheprovider -p xdist -n 6 --dist loadfile -p no:randomly
 t1_rc=$?
 if [ "$t1_rc" -ne 0 ]; then
     echo "ci_gate: tier-1 tests FAILED (rc=$t1_rc)"

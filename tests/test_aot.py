@@ -24,8 +24,8 @@ pin the acceptance criteria at 2^10 on CPU:
   the current run or installed from a loaded bundle.
 
 The build/serve circuit is the same 2^10 fma circuit + smallest-honest
-config as test_limb_sweep._small_prove_parts, so the in-process
-reference prove reuses the tier-1 persistent compile cache.
+config as the rest of tier-1 (tests/proving.py); the in-process reference
+is the shared baseline prove.
 """
 
 import functools
@@ -40,34 +40,18 @@ import textwrap
 import pytest
 
 from boojum_tpu.utils import report
+from proving import baseline, small_parts
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# the SAME circuit/config as test_limb_sweep._small_prove_parts, as
-# standalone source both subprocess drivers embed — synthesis only, no
-# jit dispatch before build_bundle redirects the cache
+# the shared circuit and config (tests/proving.py), as the first lines of
+# both subprocess drivers — synthesis only, no jit dispatch before
+# build_bundle redirects the cache
 _CIRCUIT_SRC = textwrap.dedent(
     '''
-    def build_parts():
-        from boojum_tpu.cs.gates import FmaGate, PublicInputGate
-        from boojum_tpu.cs.implementations import ConstraintSystem
-        from boojum_tpu.cs.types import CSGeometry
-        from boojum_tpu.prover import ProofConfig
+    from proving import fma_assembly, small_config
 
-        geom = CSGeometry(8, 0, 6, 4)
-        cs = ConstraintSystem(geom, 1 << 10)
-        a = cs.alloc_variable_with_value(1)
-        b = cs.alloc_variable_with_value(2)
-        per_row = FmaGate.instance().num_repetitions(geom)
-        for _ in range(((1 << 10) - 8) * per_row):
-            a, b = b, FmaGate.fma(cs, a, b, a, 1, 1)
-        PublicInputGate.place(cs, b)
-        asm = cs.into_assembly()
-        config = ProofConfig(
-            fri_lde_factor=2, merkle_tree_cap_size=4,
-            num_queries=4, fri_final_degree=16,
-        )
-        return asm, config
+    asm, config = fma_assembly(), small_config()
     '''
 )
 
@@ -77,7 +61,6 @@ _BUILD_SRC = (
         '''
     import json, sys
 
-    asm, config = build_parts()
     from boojum_tpu.prover.aot import build_bundle
     from boojum_tpu.utils.profiling import start_compile_ledger
 
@@ -104,7 +87,6 @@ _SERVE_SRC = (
         '''
     import json, sys
 
-    asm, config = build_parts()
     from boojum_tpu.prover import generate_setup, prove
     from boojum_tpu.prover import aot as _aot
     from boojum_tpu.utils import report as _report
@@ -149,7 +131,7 @@ def _run_driver(src: str, tmp: str, name: str, env_extra: dict) -> dict:
     with open(path, "w") as f:
         f.write(
             "import sys\n"
-            f"sys.path.insert(0, {REPO!r})\n"
+            f"sys.path[:0] = [{REPO!r}, {os.path.join(REPO, 'tests')!r}]\n"
             f"OUT_ROOT = {os.path.join(tmp, 'bundles')!r}\n"
             f"OUT_JSON = {out_json!r}\n"
         )
@@ -192,19 +174,6 @@ def _roundtrip():
     return tmp, build, serve
 
 
-def _reference():
-    """In-process JIT prove of the identical circuit (shares the tier-1
-    persistent cache with test_limb_sweep/test_overlap)."""
-    from test_limb_sweep import _small_prove_parts
-
-    from boojum_tpu.prover import prove
-
-    asm, setup, config = _small_prove_parts()
-    with report.flight_recording(label="ref") as rec:
-        proof = prove(asm, setup, config)
-    return proof, report.build_report(rec)
-
-
 def test_roundtrip_zero_compile_bit_parity():
     """Acceptance: with a pre-built bundle, a cold process records ZERO
     XLA compiles (no cache misses, no dispatch compiles), every
@@ -222,7 +191,7 @@ def test_roundtrip_zero_compile_bit_parity():
     misses = [k for k, v in serve["aot_entries"].items() if not v]
     assert not misses, f"kernels that escaped the artifact store: {misses}"
 
-    ref_proof, ref_line = _reference()
+    ref_proof, ref_line = baseline()
     assert serve["proof"] == ref_proof.to_json()
     ref_ckpts = [
         (e["seq"], e["round"], e["label"], e["digest"])
@@ -344,12 +313,10 @@ def test_stale_bundle_graceful_jit_fallback(tmp_path):
     """Wrong jaxlib version in the manifest: load_bundle warns and
     returns None (counted as aot.stale_bundles), and prove() under
     BOOJUM_TPU_AOT_DIR still proves bit-identically via JIT."""
-    from test_limb_sweep import _small_prove_parts
-
     from boojum_tpu.prover import aot, prove
     from boojum_tpu.utils import metrics as _metrics
 
-    asm, setup, config = _small_prove_parts()
+    asm, setup, config = small_parts()
     root = _stale_root(tmp_path, asm, config)
 
     records = []
@@ -369,7 +336,7 @@ def test_stale_bundle_graceful_jit_fallback(tmp_path):
     assert stale_msgs and "jaxlib" in stale_msgs[0], records
 
     # the prove-side consult degrades to JIT, not a crash
-    ref_proof, _ = _reference()
+    ref_proof, _ = baseline()
     prev = os.environ.get("BOOJUM_TPU_AOT_DIR")
     os.environ["BOOJUM_TPU_AOT_DIR"] = root
     try:
@@ -383,11 +350,9 @@ def test_stale_bundle_graceful_jit_fallback(tmp_path):
 
 
 def test_stale_bundle_raises_under_require(tmp_path, monkeypatch):
-    from test_limb_sweep import _small_prove_parts
-
     from boojum_tpu.prover import aot
 
-    asm, _setup, config = _small_prove_parts()
+    asm, _setup, config = small_parts()
     root = _stale_root(tmp_path, asm, config)
     monkeypatch.setenv("BOOJUM_TPU_AOT_REQUIRE", "1")
     with pytest.raises(aot.AotBundleError, match="stale bundle"):
@@ -424,9 +389,7 @@ def test_corrupt_entry_skipped(tmp_path):
     # the serve subprocesses own the sticky cache-key flip; restore this
     # process's value so later tier-1 tests keep their cache keys
     prev_flag = jax.config.jax_persistent_cache_enable_xla_caches
-    from test_limb_sweep import _small_prove_parts
-
-    asm, _setup, config = _small_prove_parts()
+    asm, _setup, config = small_parts()
     reg = _metrics.start_metrics()
     try:
         out = aot.load_bundle(root, asm, config, require=False)

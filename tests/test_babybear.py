@@ -352,25 +352,9 @@ def test_zero_limb_conversions_during_bb_prove():
 
 
 def _fma_cfg_asm():
-    from boojum_tpu.cs.gates import FmaGate, PublicInputGate
-    from boojum_tpu.cs.implementations import ConstraintSystem
-    from boojum_tpu.cs.types import CSGeometry
-    from boojum_tpu.prover import ProofConfig
+    from proving import fma_assembly, small_config
 
-    geom = CSGeometry(8, 0, 6, 4)
-    cs = ConstraintSystem(geom, 1 << 10)
-    a = cs.alloc_variable_with_value(1)
-    b = cs.alloc_variable_with_value(2)
-    per_row = FmaGate.instance().num_repetitions(geom)
-    for _ in range(((1 << 10) - 8) * per_row):
-        a, b = b, FmaGate.fma(cs, a, b, a, 1, 1)
-    PublicInputGate.place(cs, b)
-    asm = cs.into_assembly()
-    cfg = ProofConfig(
-        fri_lde_factor=2, merkle_tree_cap_size=4,
-        num_queries=4, fri_final_degree=16,
-    )
-    return asm, cfg
+    return fma_assembly(), small_config()
 
 
 def test_dispatcher_selects_bb_set_and_vetoes_limbs(monkeypatch):

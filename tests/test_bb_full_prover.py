@@ -30,7 +30,6 @@ the acceptance criteria:
   bb_ntt table set under its field-aware key.
 """
 
-import contextlib
 import functools
 import os
 import sys
@@ -42,23 +41,14 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from boojum_tpu.examples import (
-    build_fma_chain_circuit,
     build_poseidon_rf_circuit,
     build_xor_lookup_circuit,
 )
+from proving import environ, fma_assembly
 
 
-@contextlib.contextmanager
 def _bb_field():
-    prev = os.environ.get("BOOJUM_TPU_FIELD")
-    os.environ["BOOJUM_TPU_FIELD"] = "babybear"
-    try:
-        yield
-    finally:
-        if prev is None:
-            os.environ.pop("BOOJUM_TPU_FIELD", None)
-        else:
-            os.environ["BOOJUM_TPU_FIELD"] = prev
+    return environ({"BOOJUM_TPU_FIELD": "babybear"})
 
 
 def _cfg():
@@ -72,15 +62,16 @@ def _circuit(kind):
     """(assembly, setup) synthesized UNDER the babybear env var — the CS
     stamps its field at synthesis, generate_setup dispatches on it."""
     with _bb_field():
-        if kind == "fma":
-            cs, _ = build_fma_chain_circuit(num_rows=(1 << 10) - 8)
+        if kind == "fma":  # tier-1's shared circuit, over this field
+            asm = fma_assembly()
         elif kind == "xor4":
             cs, _, _ = build_xor_lookup_circuit(
                 num_lookups=600, capacity=1 << 11
             )
+            asm = cs.into_assembly()
         else:  # poseidon-rf
             cs, _ = build_poseidon_rf_circuit(num_rounds=48)
-        asm = cs.into_assembly()
+            asm = cs.into_assembly()
         assert asm.field == "babybear"
         from boojum_tpu.prover import generate_setup
 
@@ -264,26 +255,17 @@ def test_cost_sheet_hbm_bytes_exactly_half_of_goldilocks():
 
 
 def test_goldilocks_path_unaffected_when_env_unset(monkeypatch):
+    from proving import baseline, small_parts
+
     from boojum_tpu.field.spec import active_field
-    from boojum_tpu.prover import (
-        ProofConfig,
-        generate_setup,
-        prove,
-        verify,
-    )
+    from boojum_tpu.prover import verify
 
     monkeypatch.delenv("BOOJUM_TPU_FIELD", raising=False)
     assert active_field() == "goldilocks"
-    cs, _ = build_fma_chain_circuit(num_rows=56, capacity=1 << 6)
-    asm = cs.into_assembly()
+    asm, setup, _cfg = small_parts()
     assert asm.field == "goldilocks"
-    cfg = ProofConfig(
-        fri_lde_factor=2, merkle_tree_cap_size=4,
-        num_queries=4, fri_final_degree=8,
-    )
-    setup = generate_setup(asm, cfg)
     assert setup.vk.transcript == "poseidon2"  # not the _babybear twin
-    proof = prove(asm, setup, cfg)
+    proof, _report = baseline()
     assert proof.config.get("field") != "babybear"
     assert verify(setup.vk, proof, asm.gates)
 

@@ -38,6 +38,7 @@ import sys
 
 from boojum_tpu.utils import report
 from boojum_tpu.utils import costmodel as cm
+from proving import fma_assembly, small_config, small_parts
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -45,36 +46,16 @@ STAGES = cm.STAGE_NAMES
 
 
 def _fma_cfg_asm():
-    from boojum_tpu.cs.gates import FmaGate, PublicInputGate
-    from boojum_tpu.cs.implementations import ConstraintSystem
-    from boojum_tpu.cs.types import CSGeometry
-    from boojum_tpu.prover import ProofConfig
-
-    geom = CSGeometry(8, 0, 6, 4)
-    cs = ConstraintSystem(geom, 1 << 10)
-    a = cs.alloc_variable_with_value(1)
-    b = cs.alloc_variable_with_value(2)
-    per_row = FmaGate.instance().num_repetitions(geom)
-    for _ in range(((1 << 10) - 8) * per_row):
-        a, b = b, FmaGate.fma(cs, a, b, a, 1, 1)
-    PublicInputGate.place(cs, b)
-    asm = cs.into_assembly()
-    cfg = ProofConfig(
-        fri_lde_factor=2, merkle_tree_cap_size=4,
-        num_queries=4, fri_final_degree=16,
-    )
-    return asm, cfg
+    return fma_assembly(), small_config()
 
 
 @functools.lru_cache(maxsize=1)
 def _proved_with_costs():
     """ONE precompile sweep (capturing per-kernel XLA actuals into a
     process-wide ledger) + ONE recorded 2^10 prove — the shared e2e
-    artifact most tests here read. Same circuit/config as
-    test_limb_sweep._small_prove_parts, so the persistent compile cache
-    is shared with the rest of the tier-1 suite."""
-    from test_limb_sweep import _small_prove_parts
-
+    artifact most tests here read, on the shared circuit and config.
+    It is a prove of its own and not the shared baseline: the precompile
+    ledger has to be live around it."""
     from boojum_tpu.prover import prove
     from boojum_tpu.prover.precompile import enumerate_kernels, precompile
     from boojum_tpu.utils.profiling import (
@@ -82,7 +63,7 @@ def _proved_with_costs():
         stop_compile_ledger,
     )
 
-    asm, setup, config = _small_prove_parts()
+    asm, setup, config = small_parts()
     led = start_compile_ledger()
     specs = enumerate_kernels(asm, config)
     precompile(asm, config, ledger=led, max_workers=2, specs=specs)

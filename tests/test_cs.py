@@ -308,13 +308,12 @@ def test_explicit_constants_allocator_gate():
         PublicInputGate,
     )
     from boojum_tpu.cs.implementations import ConstraintSystem
-    from boojum_tpu.cs.types import CSGeometry
+    from boojum_tpu.examples import EXAMPLE_GEOMETRY
     from boojum_tpu.field import gl
     from boojum_tpu.prover import ProofConfig, generate_setup, prove, verify
     from boojum_tpu.prover.satisfiability import check_if_satisfied
 
-    geom = CSGeometry(8, 0, 6, 4)
-    cs = ConstraintSystem(geom, 1 << 10)
+    cs = ConstraintSystem(EXAMPLE_GEOMETRY, 1 << 10)
     table = ExplicitConstantsAllocatorGate.allocate(cs, (5, 1 << 32))
     assert cs.get_value(table[0]) == 0
     assert cs.get_value(table[1]) == 1

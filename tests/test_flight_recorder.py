@@ -12,6 +12,7 @@ import sys
 import pytest
 
 from boojum_tpu.utils import metrics, profiling, report, spans
+from proving import baseline, prove_recorded, small_parts
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -357,51 +358,10 @@ def test_digest_of_nested_values_stable():
 # ---------------------------------------------------------------------------
 
 
-import functools
-
-
-@functools.lru_cache(maxsize=1)
-def _small_prove_parts():
-    """A genuine 2^10-row trace (the acceptance geometry), with the same
-    circuit + smallest-honest config as test_precompile's 2^10 e2e so the
-    kernel shapes are already in the tier-1 persistent compile cache."""
-    from boojum_tpu.cs.gates import FmaGate, PublicInputGate
-    from boojum_tpu.cs.implementations import ConstraintSystem
-    from boojum_tpu.cs.types import CSGeometry
-    from boojum_tpu.prover import ProofConfig, generate_setup
-
-    geom = CSGeometry(8, 0, 6, 4)
-    cs = ConstraintSystem(geom, 1 << 10)
-    a = cs.alloc_variable_with_value(1)
-    b = cs.alloc_variable_with_value(2)
-    per_row = FmaGate.instance().num_repetitions(geom)
-    for _ in range(((1 << 10) - 8) * per_row):
-        a, b = b, FmaGate.fma(cs, a, b, a, 1, 1)
-    PublicInputGate.place(cs, b)
-    asm = cs.into_assembly()
-    assert asm.trace_len == 1 << 10
-    config = ProofConfig(
-        fri_lde_factor=2,
-        merkle_tree_cap_size=4,
-        num_queries=4,
-        fri_final_degree=16,
-    )
-    setup = generate_setup(asm, config)
-    return asm, setup, config
-
-
-def _recorded_prove(asm, setup, config, label):
-    from boojum_tpu.prover import prove
-
-    with report.flight_recording(label=label) as rec:
-        proof = prove(asm, setup, config)
-    return proof, report.build_report(rec)
-
-
 def test_checkpoints_identical_across_reruns_and_diverge_on_flip():
-    asm, setup, config = _small_prove_parts()
-    _p1, rep1 = _recorded_prove(asm, setup, config, "run1")
-    _p2, rep2 = _recorded_prove(asm, setup, config, "run2")
+    asm, setup, config = small_parts()
+    _p1, rep1 = baseline()
+    _p2, rep2 = prove_recorded("rerun")  # a second, fresh prove
 
     assert report.validate_report(rep1) == []
     # every Fiat–Shamir round is checkpointed
@@ -431,7 +391,9 @@ def test_checkpoints_identical_across_reruns_and_diverge_on_flip():
     place = int(placed[placed >= 0].min())  # a place wired into copy cols
     wv[place] = (int(wv[place]) + 1) % gl.P
     asm_flipped = asm.with_external_witness(wv)
-    _p3, rep3 = _recorded_prove(asm_flipped, setup, config, "flipped")
+    _p3, rep3 = prove_recorded(
+        "flipped", parts=(asm_flipped, setup, config)
+    )
     d2 = report.diff_reports(rep1, rep3)
     fd = d2["first_checkpoint_divergence"]
     assert fd is not None
@@ -443,7 +405,7 @@ def test_report_env_emission_schema_and_cli(tmp_path, monkeypatch):
     """BOOJUM_TPU_REPORT=<path> makes a plain prove() emit a ProveReport
     line; the artifact passes --check, covers >= 90% of the prove wall in
     spans, and self-diffs clean (the post-bench smoke gate)."""
-    asm, setup, config = _small_prove_parts()
+    asm, setup, config = small_parts()
     path = str(tmp_path / "prove_report.jsonl")
     monkeypatch.setenv("BOOJUM_TPU_REPORT", path)
     from boojum_tpu.prover import prove, verify

@@ -37,6 +37,7 @@ import jax
 import pytest
 
 from boojum_tpu.utils import report
+from proving import baseline, checkpoint_stream, small_parts
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -331,12 +332,6 @@ class _FakeProof:
         return json.dumps({"proof": self._payload})
 
 
-def _parts_small():
-    from test_limb_sweep import _small_prove_parts
-
-    return _small_prove_parts()
-
-
 def _fake_run_request(self, req, placement, packed=1, device=None):
     """Stands in for ProvingService._run_request: stamps a well-formed
     SLO record + a deterministic fake proof, no proving."""
@@ -389,7 +384,7 @@ def stub_gateway(tmp_path, monkeypatch):
         spool_dir=str(tmp_path / "spool"),
         shed_mem_bytes=None,
     )
-    gw = Gateway(svc, cfg, resolver=lambda spec: _parts_small())
+    gw = Gateway(svc, cfg, resolver=lambda spec: small_parts())
     return gw, svc, rpt
 
 
@@ -870,13 +865,6 @@ def test_http_metrics_500_body_and_error_counter(monkeypatch):
         plane.stop()
 
 
-def _checkpoint_stream(rep):
-    return [
-        (e["seq"], e["round"], e["label"], e["digest"])
-        for e in rep["checkpoints"]
-    ]
-
-
 def _wait_done(base, job, token, deadline_s=300.0):
     deadline = time.time() + deadline_s
     while time.time() < deadline:
@@ -897,7 +885,6 @@ def test_e2e_two_tenants_over_http(tmp_path):
     artifact passes prove_report.py --check and --slo shows tenants."""
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
-    from boojum_tpu.prover import prove
     from boojum_tpu.service import (
         Gateway,
         GatewayConfig,
@@ -906,10 +893,8 @@ def test_e2e_two_tenants_over_http(tmp_path):
         TenantSpec,
     )
 
-    asm, setup, cfg = _parts_small()
-    with report.flight_recording(label="direct") as rec:
-        direct = prove(asm, setup, cfg)
-    direct_line = report.build_report(rec)
+    asm, setup, cfg = small_parts()
+    direct, direct_line = baseline()
 
     rpt = str(tmp_path / "gw_e2e.jsonl")
     svc = ProvingService(
@@ -1014,10 +999,10 @@ def test_e2e_two_tenants_over_http(tmp_path):
     lines = report.load_reports(rpt)
     req_lines = [ln for ln in lines if "request" in ln]
     assert len(req_lines) == 3
-    base_stream = _checkpoint_stream(direct_line)
+    base_stream = checkpoint_stream(direct_line)
     assert base_stream
     for ln in req_lines:
-        assert _checkpoint_stream(ln) == base_stream, ln["request"]["id"]
+        assert checkpoint_stream(ln) == base_stream, ln["request"]["id"]
         assert ln["request"]["gateway"] is True
         assert ln["tenant"]["charged_bytes"] > 0
         assert report.validate_report(ln) == [], ln["request"]["id"]

@@ -21,53 +21,31 @@ survives only at the API edge. These tests pin the acceptance criteria:
 - the resident flag surfaces as a span attribute and in --slo.
 """
 
-import functools
-import os
-
 import jax
-import numpy as np
 import pytest
 
 from boojum_tpu.utils import report
+from proving import (
+    baseline,
+    checkpoint_stream,
+    interpret_e2e,
+    mesh_2x4,
+    recorded_prove,
+    small_parts,
+)
 
 
-def _small_prove_parts():
-    from test_limb_sweep import _small_prove_parts as parts
-
-    return parts()
-
-
-def _recorded_prove(label, env, mesh=None):
-    from boojum_tpu.prover import prove
-
-    asm, setup, config = _small_prove_parts()
-    saved = {k: os.environ.get(k) for k in env}
-    os.environ.update(env)
-    try:
-        with report.flight_recording(label=label) as rec:
-            proof = prove(asm, setup, config, mesh=mesh)
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    return proof, report.build_report(rec)
-
-
-@functools.lru_cache(maxsize=1)
+# Every test that reads the resident prove is slow (proving.interpret_e2e
+# says why). Tier-1 keeps the limb cores (test_limb_sweep's kernel
+# parities), the resident kernel set's enumeration and lowering
+# (test_precompile) and the dispatch predicate below.
 def _both_runs():
-    # u64 FIRST so its caches never benefit from resident-run state
-    u64 = _recorded_prove("u64", {"BOOJUM_TPU_LIMB_RESIDENT": "0"})
-    res = _recorded_prove("res", {"BOOJUM_TPU_LIMB_RESIDENT": "1"})
+    # the shared baseline is the u64 prove (residency is off on CPU unless
+    # asked for); it is proved first, so its caches never benefit from
+    # resident-run state
+    u64 = baseline()
+    res = recorded_prove("res", {"BOOJUM_TPU_LIMB_RESIDENT": "1"})
     return {"u64": u64, "res": res}
-
-
-def _checkpoint_stream(rep):
-    return [
-        (e["seq"], e["round"], e["label"], e["digest"])
-        for e in rep["checkpoints"]
-    ]
 
 
 # ---------------------------------------------------------------------------
@@ -108,6 +86,7 @@ def test_resident_flag_dispatch(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
+@interpret_e2e
 def test_bit_parity_resident_vs_u64_2pow10():
     """Acceptance: proof bytes AND the checkpoint stream are bit-identical
     with BOOJUM_TPU_LIMB_RESIDENT=1 vs =0 — residency changes WHERE the
@@ -118,16 +97,17 @@ def test_bit_parity_resident_vs_u64_2pow10():
     runs = _both_runs()
     p_u, r_u = runs["u64"]
     p_r, r_r = runs["res"]
-    base = _checkpoint_stream(r_u)
+    base = checkpoint_stream(r_u)
     assert base, "no checkpoints recorded"
-    assert _checkpoint_stream(r_r) == base
+    assert checkpoint_stream(r_r) == base
     assert p_r.to_json() == p_u.to_json()
-    asm, setup, _config = _small_prove_parts()
+    asm, setup, _config = small_parts()
     assert verify(setup.vk, p_r, asm.gates)
     for rep in (r_u, r_r):
         assert report.validate_report(rep) == []
 
 
+@interpret_e2e
 def test_resident_kernels_actually_dispatched():
     """Metrics guard: the =1 run must have gone through the resident
     coset sweeps, FRI folds, plane commits and plane transforms — a
@@ -149,6 +129,7 @@ def test_resident_kernels_actually_dispatched():
     assert c_r["deep.resident_codewords"] >= 1
 
 
+@interpret_e2e
 def test_zero_interior_conversions_guard():
     """THE residency guard: a resident prove records ZERO interior
     limb.splits / limb.joins (the device-op counters charged inside
@@ -169,6 +150,7 @@ def test_zero_interior_conversions_guard():
     assert c_u.get("quotient.resident_coset_sweeps", 0) == 0
 
 
+@interpret_e2e
 def test_check_gate_rejects_lying_resident_line():
     """report.validate_report (the prove_report.py --check gate) FAILS a
     line claiming resident dispatch while counting interior conversions,
@@ -193,6 +175,7 @@ def test_check_gate_rejects_lying_resident_line():
     assert any("limb metric" in p for p in report.validate_report(bad3))
 
 
+@interpret_e2e
 def test_resident_flag_surfaces_in_spans_and_slo():
     """The resident flag rides the round-3/FRI spans as an attribute
     (rendered in the span tree) and --slo counts resident lines."""
@@ -216,20 +199,14 @@ def test_resident_flag_surfaces_in_spans_and_slo():
 # ---------------------------------------------------------------------------
 
 
-@functools.lru_cache(maxsize=1)
 def _mesh_run():
-    from jax.sharding import Mesh
-
-    mesh = Mesh(
-        np.array(jax.devices()[:8]).reshape(2, 4), axis_names=("col", "row")
-    )
-    return _recorded_prove(
+    return recorded_prove(
         "res_sm",
         {
             "BOOJUM_TPU_MESH_MODE": "shard_map",
             "BOOJUM_TPU_LIMB_RESIDENT": "1",
         },
-        mesh=mesh,
+        mesh=mesh_2x4(),
     )
 
 
@@ -244,11 +221,11 @@ def test_streamed_resident_bit_parity_2pow10():
     zero interior conversions."""
     runs = _both_runs()
     p0, r0 = runs["u64"]
-    p, r = _recorded_prove(
+    p, r = recorded_prove(
         "res_stream",
         {"BOOJUM_TPU_LIMB_RESIDENT": "1", "BOOJUM_TPU_STREAM_LDE": "1"},
     )
-    assert _checkpoint_stream(r) == _checkpoint_stream(r0)
+    assert checkpoint_stream(r) == checkpoint_stream(r0)
     assert p.to_json() == p0.to_json()
     c = r["metrics"]["counters"]
     assert c["stream.double_buffered_blocks"] > 0
@@ -272,7 +249,7 @@ def test_resident_mesh_bit_parity_2pow10():
     runs = _both_runs()
     p0, r0 = runs["u64"]
     p, r = _mesh_run()
-    assert _checkpoint_stream(r) == _checkpoint_stream(r0)
+    assert checkpoint_stream(r) == checkpoint_stream(r0)
     assert p.to_json() == p0.to_json()
     c = r["metrics"]["counters"]
     g = r["metrics"]["gauges"]

@@ -18,7 +18,6 @@ interpret mode):
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +30,13 @@ from boojum_tpu.field import goldilocks as gf
 from boojum_tpu.field import limb_ops as lop
 from boojum_tpu.field import limbs
 from boojum_tpu.utils import report
+from proving import (
+    baseline,
+    checkpoint_stream,
+    interpret_e2e,
+    recorded_prove,
+    small_parts,
+)
 
 # values that stress every carry/borrow/canonicalization branch: around 0,
 # around p, around the 2^32 limb seam, and the non-canonical top band
@@ -266,6 +272,15 @@ def _rnd(rng, *s):
     return jnp.asarray(rng.integers(0, gl.P, s, dtype=np.uint64))
 
 
+# jitted like the prover dispatches them, but compiled without XLA:CPU's
+# fusion emitters: with them (jax 0.9.0) the limb cores compile in 17 s
+# and then RUN for over half an hour at n=96; without, 15 s and 3 ms
+# (CHANGES.md PR 24). Integer arithmetic: the results are the same.
+_jit = functools.partial(
+    jax.jit, compiler_options={"xla_cpu_use_fusion_emitters": False}
+)
+
+
 # 256 exercises the tiled pallas path (R=2 sublane rows); 96 the
 # non-tiled plain-XLA fallback of the same cores
 @pytest.mark.parametrize("n", [256, 96])
@@ -288,10 +303,7 @@ def test_cp_quotient_kernel_parity(n):
     ref = _cp_quotient_core(
         z, zs, partials, copy, sigma, xs, l0, b, g, a0, a1, chunks, ks
     )
-    # jitted like the prover dispatches it (eager interpret-mode pallas
-    # pays per-op dispatch; the compiled form also persists in the tier-1
-    # compile cache)
-    got = jax.jit(lambda *a: ps.cp_quotient(*a, chunks, ks))(
+    got = _jit(lambda *a: ps.cp_quotient(*a, chunks, ks))(
         z, zs, partials, copy, sigma, xs, l0, b, g, a0, a1
     )
     _assert_ext_equal(got, ref, f"cp n={n}")
@@ -319,14 +331,14 @@ def test_lookup_quotient_kernel_parity(general):
         ref = _lookup_quotient_core_general(
             a_ldes, b_lde, cols, tid, tbl, mult, sel, b, g, a0, a1, R, w
         )
-        got = jax.jit(lambda *a: ps.lookup_quotient_general(*a, R, w))(
+        got = _jit(lambda *a: ps.lookup_quotient_general(*a, R, w))(
             a_ldes, b_lde, cols, tid, tbl, mult, sel, b, g, a0, a1
         )
     else:
         ref = _lookup_quotient_core(
             a_ldes, b_lde, cols, tid, tbl, mult, b, g, a0, a1, R, w
         )
-        got = jax.jit(lambda *a: ps.lookup_quotient(*a, R, w))(
+        got = _jit(lambda *a: ps.lookup_quotient(*a, R, w))(
             a_ldes, b_lde, cols, tid, tbl, mult, b, g, a0, a1
         )
     _assert_ext_equal(got, ref, f"lookup general={general}")
@@ -338,7 +350,7 @@ def test_gate_terms_kernel_parity(scan_threshold, monkeypatch):
     (threshold 1 forces even the 3-op FMA program through _scan_replay)."""
     from boojum_tpu.cs.gate_capture import _PACKED_CACHE
     from boojum_tpu.cs.gates import FmaGate
-    from boojum_tpu.cs.types import CSGeometry
+    from boojum_tpu.examples import EXAMPLE_GEOMETRY as geom
     from boojum_tpu.prover import pallas_sweep as ps
     from boojum_tpu.prover.stages import _build_gate_sweep
 
@@ -346,7 +358,6 @@ def test_gate_terms_kernel_parity(scan_threshold, monkeypatch):
         monkeypatch.setenv("BOOJUM_TPU_SCAN_GATE_THRESHOLD", str(scan_threshold))
     saved = dict(_PACKED_CACHE)
     try:
-        geom = CSGeometry(8, 0, 6, 4)
         gates = (FmaGate.instance(),)
         paths = ((),)
         rng = np.random.default_rng(12)
@@ -356,7 +367,7 @@ def test_gate_terms_kernel_parity(scan_threshold, monkeypatch):
         a0, a1 = _rnd(rng, reps), _rnd(rng, reps)
         ref = _build_gate_sweep(gates, paths, geom)(copy, None, const, a0, a1)
         limb_fn = ps.gate_terms_fn(gates, paths, geom)
-        got = jax.jit(lambda c, k, x, y: limb_fn(c, None, k, x, y))(
+        got = _jit(lambda c, k, x, y: limb_fn(c, None, k, x, y))(
             copy, const, a0, a1
         )
         _assert_ext_equal(got, ref, f"gate threshold={scan_threshold}")
@@ -378,7 +389,7 @@ def test_fri_fold_kernel_parity(m):
         tuple(int(v) for v in rng.integers(0, gl.P, 2, dtype=np.uint64))
     )
     ref = _fold_once_jit(vals, ch, invx)
-    got = jax.jit(ps.fri_fold)(vals, ch, invx)
+    got = _jit(ps.fri_fold)(vals, ch, invx)
     _assert_ext_equal(got, ref, f"fold m={m}")
 
 
@@ -413,69 +424,19 @@ def test_limb_sweep_enabled_dispatch(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-@functools.lru_cache(maxsize=1)
-def _small_prove_parts():
-    """Same 2^10 circuit + smallest-honest config as test_overlap /
-    test_precompile, so kernel shapes are already in the tier-1 persistent
-    compile cache."""
-    from boojum_tpu.cs.gates import FmaGate, PublicInputGate
-    from boojum_tpu.cs.implementations import ConstraintSystem
-    from boojum_tpu.cs.types import CSGeometry
-    from boojum_tpu.prover import ProofConfig, generate_setup
-
-    geom = CSGeometry(8, 0, 6, 4)
-    cs = ConstraintSystem(geom, 1 << 10)
-    a = cs.alloc_variable_with_value(1)
-    b = cs.alloc_variable_with_value(2)
-    per_row = FmaGate.instance().num_repetitions(geom)
-    for _ in range(((1 << 10) - 8) * per_row):
-        a, b = b, FmaGate.fma(cs, a, b, a, 1, 1)
-    PublicInputGate.place(cs, b)
-    asm = cs.into_assembly()
-    assert asm.trace_len == 1 << 10
-    config = ProofConfig(
-        fri_lde_factor=2,
-        merkle_tree_cap_size=4,
-        num_queries=4,
-        fri_final_degree=16,
-    )
-    setup = generate_setup(asm, config)
-    return asm, setup, config
-
-
-def _recorded_prove(label, env):
-    from boojum_tpu.prover import prove
-
-    asm, setup, config = _small_prove_parts()
-    saved = {k: os.environ.get(k) for k in env}
-    os.environ.update(env)
-    try:
-        with report.flight_recording(label=label) as rec:
-            proof = prove(asm, setup, config)
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    return proof, report.build_report(rec)
-
-
-@functools.lru_cache(maxsize=1)
+# The e2e pair is slow (proving.interpret_e2e says why). Tier-1 keeps the
+# same limb cores in the eight kernel parities above, and the limb kernel
+# set's enumeration and lowering in test_precompile.
 def _both_path_runs():
-    # u64 FIRST so its caches never benefit from limb-run state
-    u64 = _recorded_prove("u64", {"BOOJUM_TPU_LIMB_SWEEP": "0"})
-    limb = _recorded_prove("limb", {"BOOJUM_TPU_LIMB_SWEEP": "1"})
+    # the shared baseline is the u64 prove (the limb sweep is off on CPU
+    # unless asked for); it is proved first, so its caches never benefit
+    # from limb-run state
+    u64 = baseline()
+    limb = recorded_prove("limb", {"BOOJUM_TPU_LIMB_SWEEP": "1"})
     return {"u64": u64, "limb": limb}
 
 
-def _checkpoint_stream(rep):
-    return [
-        (e["seq"], e["round"], e["label"], e["digest"])
-        for e in rep["checkpoints"]
-    ]
-
-
+@interpret_e2e
 def test_bit_parity_limb_vs_u64_2pow10():
     """Acceptance: proof bytes AND the report.py checkpoint stream are
     bit-identical with BOOJUM_TPU_LIMB_SWEEP=1 vs =0 — the limb kernels
@@ -486,16 +447,17 @@ def test_bit_parity_limb_vs_u64_2pow10():
     runs = _both_path_runs()
     p_u64, r_u64 = runs["u64"]
     p_limb, r_limb = runs["limb"]
-    base = _checkpoint_stream(r_u64)
+    base = checkpoint_stream(r_u64)
     assert base, "no checkpoints recorded"
-    assert _checkpoint_stream(r_limb) == base
+    assert checkpoint_stream(r_limb) == base
     assert p_limb.to_json() == p_u64.to_json()
-    asm, setup, _config = _small_prove_parts()
+    asm, setup, _config = small_parts()
     assert verify(setup.vk, p_limb, asm.gates)
     for rep in (r_u64, r_limb):
         assert report.validate_report(rep) == []
 
 
+@interpret_e2e
 def test_limb_kernels_actually_dispatched():
     """Metrics guard: the =1 run must have gone through the limb coset
     sweep and the limb FRI folds (a silent fallback to u64 would make the

@@ -20,63 +20,42 @@ all_to_all per col->row Merkle pivot, one all_gather per cap), charged to
   boojum_tpu logger and records the chosen axis as a span attribute.
 """
 
-import functools
 import logging
-import os
 
 import jax
-import numpy as np
 import pytest
 
 from boojum_tpu.utils import report
+from proving import (
+    baseline,
+    checkpoint_stream,
+    interpret_e2e,
+    mesh_2x4 as _mesh,
+    recorded_prove,
+    small_parts,
+)
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices"
 )
 
 
-def _mesh():
-    from jax.sharding import Mesh
-
-    return Mesh(
-        np.array(jax.devices()[:8]).reshape(2, 4), axis_names=("col", "row")
-    )
-
-
-def _small_prove_parts():
-    from test_limb_sweep import _small_prove_parts as parts
-
-    return parts()
-
-
-def _recorded_prove(label, env, mesh=None):
-    from boojum_tpu.prover import prove
-
-    asm, setup, config = _small_prove_parts()
-    saved = {k: os.environ.get(k) for k in env}
-    os.environ.update(env)
-    try:
-        with report.flight_recording(label=label) as rec:
-            proof = prove(asm, setup, config, mesh=mesh)
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    return proof, report.build_report(rec)
-
-
-@functools.lru_cache(maxsize=1)
+# Every test that reads the shard_map run is slow: its per-chip kernels
+# are the interpret-mode limb ones (proving.interpret_e2e says why).
+# Tier-1 keeps shard_map over the whole mesh against a direct prove
+# (test_service's shard-parallel request, ici.all_to_alls > 0), gspmd
+# against one device (test_sharding), the sm kernel set's enumeration and
+# lowering (test_precompile) and the limb cores (test_limb_sweep).
 def _three_mode_runs():
-    # meshless FIRST so its caches never benefit from mesh-run state; the
-    # shard_map run forces the limb kernels (interpret mode on CPU) so the
-    # parity covers the per-chip Pallas path, not an XLA fallback
-    nomesh = _recorded_prove("nomesh", {})
-    gspmd = _recorded_prove(
+    # the shared meshless baseline is proved first, so its caches never
+    # benefit from mesh-run state; the shard_map run forces the limb
+    # kernels (interpret mode on CPU) so the parity covers the per-chip
+    # Pallas path, not an XLA fallback
+    nomesh = baseline()
+    gspmd = recorded_prove(
         "gspmd", {"BOOJUM_TPU_MESH_MODE": "gspmd"}, mesh=_mesh()
     )
-    sm = _recorded_prove(
+    sm = recorded_prove(
         "sm",
         {"BOOJUM_TPU_MESH_MODE": "shard_map", "BOOJUM_TPU_LIMB_SWEEP": "1"},
         mesh=_mesh(),
@@ -84,13 +63,7 @@ def _three_mode_runs():
     return {"nomesh": nomesh, "gspmd": gspmd, "sm": sm}
 
 
-def _checkpoint_stream(rep):
-    return [
-        (e["seq"], e["round"], e["label"], e["digest"])
-        for e in rep["checkpoints"]
-    ]
-
-
+@interpret_e2e
 def test_three_mode_bit_parity_2pow10():
     """Acceptance: proof bytes AND the digest-checkpoint stream are
     bit-identical across no-mesh / GSPMD-mesh / shard_map-mesh."""
@@ -98,16 +71,17 @@ def test_three_mode_bit_parity_2pow10():
 
     runs = _three_mode_runs()
     p0, r0 = runs["nomesh"]
-    base_ck = _checkpoint_stream(r0)
+    base_ck = checkpoint_stream(r0)
     assert base_ck, "no checkpoints recorded"
     for mode in ("gspmd", "sm"):
         p, r = runs[mode]
-        assert _checkpoint_stream(r) == base_ck, mode
+        assert checkpoint_stream(r) == base_ck, mode
         assert p.to_json() == p0.to_json(), mode
-    asm, setup, _config = _small_prove_parts()
+    asm, setup, _config = small_parts()
     assert verify(setup.vk, runs["sm"][0], asm.gates)
 
 
+@interpret_e2e
 def test_sm_limb_kernels_actually_dispatched():
     """Metrics guard: the shard_map run must have gone through the
     per-chip limb coset sweep, the limb FRI folds AND the fused limb leaf
@@ -133,6 +107,7 @@ def test_sm_limb_kernels_actually_dispatched():
         assert c_g.get(k, 0) == 0, k
 
 
+@interpret_e2e
 def test_ici_gauges_present_and_checked():
     """Acceptance: ici.all_to_all_bytes / ici.pivot_s appear in the
     shard_map ProveReport line, validate_report (the prove_report.py
@@ -165,6 +140,7 @@ def test_ici_gauges_present_and_checked():
     assert any("ici.pivot_s" in p for p in report.validate_report(bad2))
 
 
+@interpret_e2e
 def test_streamed_sm_bit_parity_2pow10():
     """The streamed commit path under a shard_map mesh (BOOJUM_TPU_
     STREAM_LDE=1: shard_sweep.streamed_leaf_digests_sm per-chip absorbs
@@ -174,7 +150,7 @@ def test_streamed_sm_bit_parity_2pow10():
     the per-chip streamed blocks actually dispatched."""
     runs = _three_mode_runs()
     p0, r0 = runs["nomesh"]
-    p, r = _recorded_prove(
+    p, r = recorded_prove(
         "sm_stream",
         {
             "BOOJUM_TPU_MESH_MODE": "shard_map",
@@ -183,7 +159,7 @@ def test_streamed_sm_bit_parity_2pow10():
         },
         mesh=_mesh(),
     )
-    assert _checkpoint_stream(r) == _checkpoint_stream(r0)
+    assert checkpoint_stream(r) == checkpoint_stream(r0)
     assert p.to_json() == p0.to_json()
     c = r["metrics"]["counters"]
     assert c["stream.sm_blocks"] > 0

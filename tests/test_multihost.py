@@ -2,9 +2,10 @@
 hybrid mesh drives a full sharded prove (the virtual 8-device CPU mesh —
 process-count > 1 behavior uses the identical GSPMD code paths)."""
 
-import numpy as np
+import os
 
 import jax
+import pytest
 
 from boojum_tpu.parallel.multihost import (
     distribute_proofs,
@@ -102,16 +103,10 @@ def _spawn_workers(mode, tmp_path, nprocs=2, mesh_mode=None, tag=""):
     return [json.load(open(o)) for o in outs]
 
 
-import os
-import pytest
-
-_TWO_PROC = bool(os.environ.get("BOOJUM_TPU_SLOW_TESTS")) or bool(
-    os.environ.get("BOOJUM_TPU_TWO_PROC_TESTS")
-)
 two_proc = pytest.mark.skipif(
-    not _TWO_PROC,
+    not os.environ.get("BOOJUM_TPU_TWO_PROC_TESTS"),
     reason="spawns 2 jax.distributed processes (minutes of CPU compile); "
-    "BOOJUM_TPU_SLOW_TESTS=1 or BOOJUM_TPU_TWO_PROC_TESTS=1 to run",
+    "BOOJUM_TPU_TWO_PROC_TESTS=1 to run",
 )
 
 

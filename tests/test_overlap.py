@@ -13,16 +13,17 @@ Pins the acceptance criteria:
   failure).
 """
 
-import functools
-import os
-
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from boojum_tpu.utils import metrics, report, transfer
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from proving import (
+    baseline,
+    checkpoint_stream,
+    recorded_prove,
+    small_parts,
+)
 
 
 # ---------------------------------------------------------------------------
@@ -158,72 +159,14 @@ def test_render_report_shows_occupancy():
 # ---------------------------------------------------------------------------
 
 
-@functools.lru_cache(maxsize=1)
-def _small_prove_parts():
-    """Same 2^10 circuit + smallest-honest config as test_flight_recorder
-    / test_precompile, so the kernel shapes are already in the tier-1
-    persistent compile cache."""
-    from boojum_tpu.cs.gates import FmaGate, PublicInputGate
-    from boojum_tpu.cs.implementations import ConstraintSystem
-    from boojum_tpu.cs.types import CSGeometry
-    from boojum_tpu.prover import ProofConfig, generate_setup
-
-    geom = CSGeometry(8, 0, 6, 4)
-    cs = ConstraintSystem(geom, 1 << 10)
-    a = cs.alloc_variable_with_value(1)
-    b = cs.alloc_variable_with_value(2)
-    per_row = FmaGate.instance().num_repetitions(geom)
-    for _ in range(((1 << 10) - 8) * per_row):
-        a, b = b, FmaGate.fma(cs, a, b, a, 1, 1)
-    PublicInputGate.place(cs, b)
-    asm = cs.into_assembly()
-    assert asm.trace_len == 1 << 10
-    config = ProofConfig(
-        fri_lde_factor=2,
-        merkle_tree_cap_size=4,
-        num_queries=4,
-        fri_final_degree=16,
-    )
-    setup = generate_setup(asm, config)
-    return asm, setup, config
-
-
-def _recorded_prove(label, env):
-    from boojum_tpu.prover import prove
-
-    asm, setup, config = _small_prove_parts()
-    saved = {k: os.environ.get(k) for k in env}
-    os.environ.update(env)
-    try:
-        with report.flight_recording(label=label) as rec:
-            proof = prove(asm, setup, config)
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    return proof, report.build_report(rec)
-
-
-@functools.lru_cache(maxsize=1)
 def _three_path_runs():
     # sequenced FIRST so its counters never benefit from state the
-    # overlapped run warmed
-    seq = _recorded_prove("sequenced", {"BOOJUM_TPU_OVERLAP": "0"})
-    ovl = _recorded_prove("overlapped", {"BOOJUM_TPU_OVERLAP": "1"})
-    streamed = _recorded_prove(
-        "streamed",
-        {"BOOJUM_TPU_OVERLAP": "1", "BOOJUM_TPU_STREAM_LDE": "1"},
-    )
+    # overlapped run warmed; the overlapped run is the shared baseline
+    # (overlap is on unless BOOJUM_TPU_OVERLAP=0)
+    seq = recorded_prove("sequenced", {"BOOJUM_TPU_OVERLAP": "0"})
+    ovl = baseline()
+    streamed = recorded_prove("streamed", {"BOOJUM_TPU_STREAM_LDE": "1"})
     return {"sequenced": seq, "overlapped": ovl, "streamed": streamed}
-
-
-def _checkpoint_stream(rep):
-    return [
-        (e["seq"], e["round"], e["label"], e["digest"])
-        for e in rep["checkpoints"]
-    ]
 
 
 def test_bit_parity_overlapped_sequenced_streamed():
@@ -237,14 +180,14 @@ def test_bit_parity_overlapped_sequenced_streamed():
     p_ovl, r_ovl = runs["overlapped"]
     p_str, r_str = runs["streamed"]
 
-    base = _checkpoint_stream(r_seq)
+    base = checkpoint_stream(r_seq)
     assert base, "no checkpoints recorded"
-    assert _checkpoint_stream(r_ovl) == base
-    assert _checkpoint_stream(r_str) == base
+    assert checkpoint_stream(r_ovl) == base
+    assert checkpoint_stream(r_str) == base
     assert p_ovl.to_json() == p_seq.to_json()
     assert p_str.to_json() == p_seq.to_json()
 
-    asm, setup, _config = _small_prove_parts()
+    asm, setup, _config = small_parts()
     assert verify(setup.vk, p_ovl, asm.gates)
     for _label, (_p, rep) in runs.items():
         assert report.validate_report(rep) == []
@@ -287,7 +230,7 @@ def test_error_in_streamed_block_yields_partial_report(monkeypatch):
     from boojum_tpu.prover import prove
     from boojum_tpu.prover import streaming
 
-    asm, setup, config = _small_prove_parts()
+    asm, setup, config = small_parts()
     monkeypatch.setenv("BOOJUM_TPU_OVERLAP", "1")
     monkeypatch.setenv("BOOJUM_TPU_STREAM_LDE", "1")
 

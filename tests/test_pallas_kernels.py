@@ -6,19 +6,14 @@ hashes/poseidon2.py and ntt/ntt.py route to them only on the TPU backend, so
 everything below pins bit-parity between the two implementations.
 """
 
-import os
-
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
 # interpret-mode kernel runs compile slowly on XLA:CPU (~30-90s each); the
-# full set runs under BOOJUM_TPU_SLOW_TESTS=1 and on real TPU hardware via
-# the bench + scripts, while the default suite keeps one per kernel family.
-_SLOW = bool(os.environ.get("BOOJUM_TPU_SLOW_TESTS"))
-slow_only = pytest.mark.skipif(
-    not _SLOW, reason="interpret-mode compile heavy; BOOJUM_TPU_SLOW_TESTS=1"
-)
+# full set is the slow lane (-m slow) and runs on real TPU hardware via the
+# bench + scripts, while tier-1 keeps one per kernel family.
+slow_only = pytest.mark.slow
 
 from boojum_tpu.field import gl, limbs
 from boojum_tpu.field import goldilocks as gf

@@ -10,9 +10,7 @@ layout (2Q quotient leaf values), tamper rejection, and VK serde roundtrip.
 import numpy as np
 import pytest
 
-from boojum_tpu.cs.implementations import ConstraintSystem
-from boojum_tpu.cs.types import CSGeometry, LookupParameters
-from boojum_tpu.cs.gates import FmaGate, PublicInputGate
+from boojum_tpu.examples import build_fma_chain_circuit
 from boojum_tpu.prover import (
     ProofConfig,
     generate_setup,
@@ -24,13 +22,8 @@ from boojum_tpu.prover import (
 
 
 def _fma_circuit():
-    cs = ConstraintSystem(CSGeometry(8, 0, 6, 4), 1 << 10)
-    x = cs.alloc_variable_with_value(3)
-    y = cs.alloc_variable_with_value(4)
-    for _ in range(300):
-        x, y = y, FmaGate.fma(cs, x, y, x, 1, 1)
-    PublicInputGate.place(cs, y)
-    return cs
+    # 300 fma gates: a 2^8-row trace
+    return build_fma_chain_circuit(num_rows=150)[0]
 
 
 def test_decoupled_commit_rate_below_quotient_degree():

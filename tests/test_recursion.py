@@ -98,10 +98,14 @@ def test_recursive_verifier_rejects_bad_proof():
     assert not check_if_satisfied(outer_asm)
 
 
-import os
 import pytest
 
 
+# 348 s cold alone (PR 24): the 130-column outer circuit's own kernel set.
+# Tier-1 keeps prove() on that geometry (test_poseidon2_gate's
+# test_gate_proves_e2e) and the verifier circuit itself
+# (test_recursive_verifier_satisfiable and the three tests below).
+@pytest.mark.slow
 def test_recursive_proof_proves_and_verifies():
     """The counterpart of the reference's recursive bench
     (sha256_bench_recursive_poseidon2.sh / recursive_verifier.rs:2213

@@ -19,12 +19,11 @@ from boojum_tpu.compat.prove_reference import prove_reference_dialect
 from boojum_tpu.compat.verifier import verify_reference_proof
 from boojum_tpu.cs.gates import ConstantsAllocatorGate, FmaGate, PublicInputGate
 from boojum_tpu.cs.implementations import ConstraintSystem
-from boojum_tpu.cs.types import CSGeometry
+from boojum_tpu.examples import EXAMPLE_GEOMETRY
 
 
 def _fma_assembly(n_gates=300, capacity=1 << 9):
-    geom = CSGeometry(8, 0, 6, 4)
-    cs = ConstraintSystem(geom, capacity)
+    cs = ConstraintSystem(EXAMPLE_GEOMETRY, capacity)
     a = ConstantsAllocatorGate.allocate_constant(cs, 3)
     b = ConstantsAllocatorGate.allocate_constant(cs, 5)
     out = a

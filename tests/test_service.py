@@ -23,7 +23,6 @@ flight recorder. These tests pin the acceptance criteria on the virtual
 """
 
 import functools
-import json
 import os
 import subprocess
 import sys
@@ -33,67 +32,23 @@ import numpy as np
 import pytest
 
 from boojum_tpu.utils import report
+from proving import (
+    baseline,
+    checkpoint_stream,
+    fma_assembly,
+    prove_recorded,
+    small_parts,
+)
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices"
 )
 
 
-def _build_fma(log_n: int, seed: int = 0):
-    from boojum_tpu.cs.gates import FmaGate, PublicInputGate
-    from boojum_tpu.cs.implementations import ConstraintSystem
-    from boojum_tpu.cs.types import CSGeometry
-
-    geom = CSGeometry(8, 0, 6, 4)
-    cs = ConstraintSystem(geom, 1 << log_n)
-    a = cs.alloc_variable_with_value(1 + seed)
-    b = cs.alloc_variable_with_value(2 + seed)
-    per_row = FmaGate.instance().num_repetitions(geom)
-    for _ in range(((1 << log_n) - 8) * per_row):
-        a, b = b, FmaGate.fma(cs, a, b, a, 1, 1)
-    PublicInputGate.place(cs, b)
-    return cs
-
-
-def _parts_a():
-    """Geometry A: the shared 2^10 circuit + smallest-honest config of
-    test_limb_sweep/test_mesh_parity, so its kernel shapes are already
-    in the tier-1 persistent compile cache."""
-    from test_limb_sweep import _small_prove_parts
-
-    return _small_prove_parts()
-
-
-@functools.lru_cache(maxsize=1)
 def _parts_b():
-    """Geometry B: same gate set at 2^11 — a DIFFERENT shape bucket."""
-    from boojum_tpu.prover import ProofConfig, generate_setup
-
-    config = ProofConfig(
-        fri_lde_factor=2,
-        merkle_tree_cap_size=4,
-        num_queries=4,
-        fri_final_degree=16,
-    )
-    asm = _build_fma(11).into_assembly()
-    assert asm.trace_len == 1 << 11
-    return asm, generate_setup(asm, config), config
-
-
-def _checkpoint_stream(rep):
-    return [
-        (e["seq"], e["round"], e["label"], e["digest"])
-        for e in rep["checkpoints"]
-    ]
-
-
-def _direct_recorded(parts):
-    from boojum_tpu.prover import prove
-
-    asm, setup, config = parts
-    with report.flight_recording(label="direct") as rec:
-        proof = prove(asm, setup, config)
-    return proof, report.build_report(rec)
+    """Geometry B: same gate set at 2^11 — a DIFFERENT shape bucket, which
+    is the point: two buckets, and a trace at the forced shard threshold."""
+    return small_parts(11)
 
 
 @functools.lru_cache(maxsize=1)
@@ -107,8 +62,8 @@ def _e2e_runs(tmp_dir=None):
 
     from boojum_tpu.service import ProvingService, ServiceConfig
 
-    direct_a = _direct_recorded(_parts_a())
-    direct_b = _direct_recorded(_parts_b())
+    direct_a = baseline()
+    direct_b = prove_recorded("direct", parts=_parts_b())
 
     rpt = tempfile.mktemp(suffix=".service.jsonl")
     # precompile="off": the tier-1 persistent cache already holds every
@@ -122,7 +77,7 @@ def _e2e_runs(tmp_dir=None):
             cache_bytes=2 << 30,
         )
     )
-    asm_a, setup_a, cfg_a = _parts_a()
+    asm_a, setup_a, cfg_a = small_parts()
     asm_b, setup_b, cfg_b = _parts_b()
     reqs = {
         # two same-bucket batch jobs (second is the device-cache HIT)...
@@ -167,8 +122,8 @@ def test_shape_bucket_key_is_shared(monkeypatch):
     from boojum_tpu.prover.shape_key import bucket_key, shape_bucket
     from boojum_tpu.utils.profiling import CompileLedger
 
-    asm_a, _setup, cfg = _parts_a()
-    asm_same_shape = _build_fma(10, seed=5).into_assembly()
+    asm_a, _setup, cfg = small_parts()
+    asm_same_shape = fma_assembly(10, seed=5)
     assert bucket_key(asm_same_shape, cfg) == bucket_key(asm_a, cfg)
     asm_b, _sb, cfg_b = _parts_b()
     assert bucket_key(asm_b, cfg_b) != bucket_key(asm_a, cfg)
@@ -326,7 +281,7 @@ def test_variant_warmer_warms_dispatched_set(monkeypatch):
     mesh = Mesh(
         np.array(jax.devices()[:8]).reshape(2, 4), axis_names=("col", "row")
     )
-    asm, _setup, cfg = _parts_a()
+    asm, _setup, cfg = small_parts()
     from boojum_tpu.prover.shape_key import shape_bucket
 
     sb = shape_bucket(asm, cfg)
@@ -411,14 +366,14 @@ def test_e2e_mixed_batch_bit_parity():
         for ln in runs["lines"]
         if "request" in ln
     }
-    base_a = _checkpoint_stream(ra)
+    base_a = checkpoint_stream(ra)
     assert base_a
     for name in ("a1", "a2", "ai"):
         ln = by_id[reqs[name].id]
-        assert _checkpoint_stream(ln) == base_a, name
+        assert checkpoint_stream(ln) == base_a, name
         assert ln["request"]["placement"] == "proof_parallel"
     ln_b = by_id[reqs["b1"].id]
-    assert _checkpoint_stream(ln_b) == _checkpoint_stream(rb)
+    assert checkpoint_stream(ln_b) == checkpoint_stream(rb)
     assert ln_b["request"]["placement"] == "shard_parallel"
     # the shard-parallel prove really ran the mesh path: explicit
     # collectives billed to ici.* in ITS request line only
@@ -475,7 +430,7 @@ def test_e2e_backpressure_at_service_bound():
         ServiceConfig,
     )
 
-    asm, setup, cfg = _parts_a()
+    asm, setup, cfg = small_parts()
     svc = ProvingService(
         ServiceConfig(precompile="off", queue_capacity=2, report_path=None)
     )
@@ -567,7 +522,7 @@ def test_packed_proof_parallel_parity_with_recording(monkeypatch):
 
     runs = _e2e_runs()
     pa, ra = runs["direct"]["a"]
-    asm, setup, cfg = _parts_a()
+    asm, setup, cfg = small_parts()
     rpt = tempfile.mktemp(suffix=".packed.jsonl")
     svc = ProvingService(
         ServiceConfig(precompile="off", max_inflight=2, report_path=rpt)
@@ -593,7 +548,7 @@ def test_packed_proof_parallel_parity_with_recording(monkeypatch):
     lines = report.load_reports(rpt)
     req_lines = [ln for ln in lines if "request" in ln]
     assert len(req_lines) == 2
-    base = _checkpoint_stream(ra)
+    base = checkpoint_stream(ra)
     assert base
     by_id = {ln["request"]["id"]: ln for ln in req_lines}
     for r in rs:
@@ -601,7 +556,7 @@ def test_packed_proof_parallel_parity_with_recording(monkeypatch):
         ln = by_id[r.id]
         # bit-identical transcript: the packed request recorded the
         # SAME checkpoint stream as the sequential direct prove
-        assert _checkpoint_stream(ln) == base, r.id
+        assert checkpoint_stream(ln) == base, r.id
         assert report.validate_report(ln) == [], r.id
         counters = ln["metrics"]["counters"]
         assert counters.get(f"canary.{r.id}") == 1

@@ -57,6 +57,12 @@ def test_sha256_satisfiable():
     assert check_if_satisfied(asm, verbose=True)
 
 
+# 285 s cold alone, 298 to 404 s among six workers (PR 24), where the
+# per-test limit is 420 s: the 60-column, 8-lookup kernel set at LDE 8.
+# Tier-1 keeps the gadget (the three tests above), prove() with
+# specialized lookup columns (test_lookup's e2e) and this geometry's whole
+# kernel library lowering (test_precompile's SHA enumeration).
+@pytest.mark.slow
 def test_sha256_e2e_prove_verify():
     data = b"abc"
     cs, digest = build_sha_circuit(data)

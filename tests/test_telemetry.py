@@ -19,6 +19,7 @@ import urllib.request
 import pytest
 
 from boojum_tpu.utils import metrics, profiling, report, telemetry
+from proving import small_parts
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -323,7 +324,7 @@ def test_request_lines_use_service_sampler_not_foreign_global(
             return 1
 
         monkeypatch.setattr(svc, "_run_request", fake_run)
-        req = svc.submit(*_parts_small())
+        req = svc.submit(*small_parts())
         svc.queue.pop_batch()
         placement = Placement("proof_parallel", None, total_devices=8)
         assert svc._serve_one(req, placement) == 1
@@ -457,12 +458,6 @@ def test_check_validates_telemetry_record(tmp_path, capsys):
 # ---------------------------------------------------------------------------
 
 
-def _parts_small():
-    from test_limb_sweep import _small_prove_parts
-
-    return _small_prove_parts()
-
-
 @pytest.fixture
 def eight_devices():
     import jax
@@ -479,7 +474,7 @@ def test_service_worker_loop_serves_live_plane(eight_devices, tmp_path):
     /slo reflects the drained batch."""
     from boojum_tpu.service import ProvingService, ServiceConfig
 
-    asm, setup, cfg = _parts_small()
+    asm, setup, cfg = small_parts()
     rpt = str(tmp_path / "svc.jsonl")
     svc = ProvingService(
         ServiceConfig(
@@ -547,7 +542,7 @@ def test_service_capture_trace_per_request(eight_devices, tmp_path):
     attributable to exactly that request (trace record in ITS line)."""
     from boojum_tpu.service import ProvingService, ServiceConfig
 
-    asm, setup, cfg = _parts_small()
+    asm, setup, cfg = small_parts()
     rpt = str(tmp_path / "trace.jsonl")
     os.environ["BOOJUM_TPU_XPROF"] = str(tmp_path / "xprof")
     try:

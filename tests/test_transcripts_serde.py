@@ -136,9 +136,7 @@ def test_legacy_poseidon_permutation_device_host_parity():
 
 
 def test_pluggable_transcript_prove_verify():
-    from boojum_tpu.cs.implementations import ConstraintSystem
-    from boojum_tpu.cs.types import CSGeometry
-    from boojum_tpu.cs.gates import FmaGate, PublicInputGate
+    from boojum_tpu.examples import build_fma_chain_circuit
     from boojum_tpu.prover import (
         ProofConfig,
         prove_one_shot,
@@ -146,13 +144,7 @@ def test_pluggable_transcript_prove_verify():
     )
 
     def build():
-        cs = ConstraintSystem(CSGeometry(8, 0, 6, 4), 1 << 10)
-        x = cs.alloc_variable_with_value(3)
-        y = cs.alloc_variable_with_value(4)
-        for _ in range(300):
-            x, y = y, FmaGate.fma(cs, x, y, x, 1, 1)
-        PublicInputGate.place(cs, y)
-        return cs
+        return build_fma_chain_circuit(num_rows=150)[0]
 
     for kind in ("poseidon", "blake2s"):
         cfg = ProofConfig(
