@@ -246,22 +246,27 @@ def mul_wide(a, b):
     return ll_lo, p1, p2, p3
 
 
-def reduce128(p0, p1, p2, p3):
-    """(p3·2^96 + p2·2^64 + p1·2^32 + p0) mod p, canonical.
-
-    Same identity as goldilocks.reduce128: x ≡ lo64 - hi_hi + hi_lo·ε with
-    hi_lo·ε = hi_lo·2^32 - hi_lo computed without a multiply."""
-    # t0 = lo64 - p3 (64-bit), borrow -> -= EPSILON
-    lo, hi, br = sub64((p0, p1), (p3, jnp.zeros_like(p3)))
-    lo, hi = _minus_eps_where(lo, hi, br)
-    # t1 = p2 * EPSILON = (p2 << 32) - p2
+def _reduce96(lo, hi, p2):
+    """(p2·2^64 + hi·2^32 + lo) mod p, canonical, for ANY u32 limbs: the
+    tail of `reduce128` (p2·2^64 ≡ p2·ε, and p2·ε = p2·2^32 - p2 needs no
+    multiply)."""
     nz = _b2u(p2 != 0)
     t1_lo = jnp.zeros_like(p2) - p2
     t1_hi = p2 - nz
-    # t2 = t0 + t1, carry -> += EPSILON
+    # (lo, hi) + t1, carry -> += EPSILON
     lo2, hi2, c = add64((lo, hi), (t1_lo, t1_hi))
     lo2, hi2 = _plus_eps_where(lo2, hi2, c)
     return _canonicalize(lo2, hi2)
+
+
+def reduce128(p0, p1, p2, p3):
+    """(p3·2^96 + p2·2^64 + p1·2^32 + p0) mod p, canonical.
+
+    Same identity as goldilocks.reduce128: x ≡ lo64 - hi_hi + hi_lo·ε."""
+    # t0 = lo64 - p3 (64-bit), borrow -> -= EPSILON
+    lo, hi, br = sub64((p0, p1), (p3, jnp.zeros_like(p3)))
+    lo, hi = _minus_eps_where(lo, hi, br)
+    return _reduce96(lo, hi, p2)
 
 
 def mul(a, b):
@@ -286,6 +291,35 @@ def sqr(a):
     d2 = _b2u(p2 < s2)
     p3 = hh_hi + xc1 + d1 + d2
     return reduce128(ll_lo, s1, p2, p3)
+
+
+def _shl96(a, k: int):
+    """a·2^k as three u32 limbs (p0 lowest), static 0 <= k < 32."""
+    lo, hi = a
+    if k == 0:  # no `x >> 32`: a shift by the full width is not 0 everywhere
+        return lo, hi, jnp.zeros_like(hi)
+    return lo << k, (hi << k) | (lo >> (32 - k)), hi >> (32 - k)
+
+
+def mul_pow2(a, k, plus=None):
+    """a·2^k (+ plus) mod p by shifts and ONE short reduction: the product
+    is a (64+k)-bit value, so no 64x64 multiply and no p3 limb.
+
+    `k` is a static exponent in [0, 32), or a sequence of them, one per
+    leading row of `a`: the five shift ops are then unrolled over the rows
+    and the reduction runs once on the restacked planes. `plus` (canonical,
+    broadcastable against `a`) is folded in before that same reduction."""
+    if isinstance(k, int):
+        assert 0 <= k < 32, k
+        p0, p1, p2 = _shl96(a, k)
+    else:
+        assert len(k) == a[0].shape[0] and all(0 <= e < 32 for e in k), k
+        rows = [_shl96((a[0][i], a[1][i]), int(e)) for i, e in enumerate(k)]
+        p0, p1, p2 = (jnp.stack(limb) for limb in zip(*rows))
+    if plus is not None:
+        p0, p1, c = add64((p0, p1), plus)
+        p2 = p2 + c  # < 2^31 + 1: no wrap
+    return _reduce96(p0, p1, p2)
 
 
 def mul_const(a, c_pair):

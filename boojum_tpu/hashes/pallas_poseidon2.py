@@ -12,7 +12,9 @@ Layout: the batch axis is tiled (rows x 128 lanes); the state axis (12) and
 the limb axis (2) are leading dims, so every field op is an elementwise VPU op
 over (TILE, 128) tiles. Round constants live in SMEM as u32 limb pairs and are
 broadcast per round inside `fori_loop`s (4 full / 22 partial / 4 full — the
-same phase structure as `poseidon2.py`).
+same phase structure as `poseidon2.py`). The internal matrix's diagonal is
+powers of two: its product is a static shift per row and one short reduction
+(`limbs.mul_pow2`), no field multiply and no table row.
 
 Used by `poseidon2.py:poseidon2_permutation` when running on TPU (env
 BOOJUM_TPU_PALLAS=0 disables); bit-parity with the XLA path is asserted in
@@ -30,32 +32,28 @@ import numpy as np
 from ..field import limbs
 from . import poseidon2_params as params
 
-_DIAG_ROW = 30
+# M_I's diagonal is powers of two by design (upstream multiplies by shifts):
+# the kernel needs only the exponents, and takes them from the one copy of
+# the constants.
+assert all(
+    0 < d < 1 << 32 and d & (d - 1) == 0 for d in params.M_I_DIAGONAL
+), params.M_I_DIAGONAL
+_M_I_DIAG_LOG2 = tuple(d.bit_length() - 1 for d in params.M_I_DIAGONAL)
 
-from functools import lru_cache as _lru_cache  # noqa: E402
 
+@lru_cache(maxsize=None)
+def rc_table(layout: str = "lohi24") -> np.ndarray:
+    """Round-constant limbs in one kernel-variant-keyed spec cache.
 
-@_lru_cache(maxsize=None)
-def rc_diag_table(layout: str = "lohi24") -> np.ndarray:
-    """RC/DIAG limb constants in one kernel-variant-keyed spec cache.
-
-    (30, 12) limb pairs -> (30, 24) u32: [lo(12) | hi(12)] per round, plus
-    a 31st row carrying the M_I diagonal in the same [lo | hi] layout —
-    pallas kernels cannot close over array constants, so the diagonal
-    rides the same SMEM table as the round constants. Built at first
-    kernel build (NOT import time) and keyed by the variant's constant
-    layout, so the resident and converting kernel variants can never
-    share a stale layout (ISSUE 10 satellite)."""
+    (30, 12) limb pairs -> (30, 24) u32: [lo(12) | hi(12)] per round —
+    pallas kernels cannot close over array constants, so the round
+    constants ride an SMEM table (M_I's diagonal is static shifts and
+    needs none). Built at first kernel build (NOT import time) and keyed
+    by the variant's constant layout, so the resident and converting
+    kernel variants can never share a stale layout (ISSUE 10 satellite)."""
     assert layout == "lohi24", layout
     rc = np.array(params.ALL_ROUND_CONSTANTS, dtype=np.uint64).reshape(30, 12)
-    diag = np.array(params.M_I_DIAGONAL, dtype=np.uint64)
-    return np.concatenate(
-        [
-            np.concatenate(limbs.split_np(rc), axis=1),
-            np.concatenate(limbs.split_np(diag[None, :]), axis=1),
-        ],
-        axis=0,
-    )
+    return np.concatenate(limbs.split_np(rc), axis=1)
 
 
 def _sbox7(x):
@@ -101,13 +99,14 @@ def _external_mds_planes(lo, hi):
     return olo, ohi
 
 
-def _internal_mds_planes(rc_ref, lo, hi):
-    """M_I = all-ones + diag(d) on stacked planes."""
+def _internal_mds_planes(lo, hi):
+    """M_I = all-ones + diag(2^k) on stacked planes: row i becomes
+    x_i·2^k_i + sum(x), the shifts unrolled per row and the sum folded into
+    the one reduction of the restacked planes."""
     total = (lo[0], hi[0])
     for i in range(1, 12):
         total = limbs.add(total, (lo[i], hi[i]))
-    scaled = limbs.mul((lo, hi), _rc_row(rc_ref, _DIAG_ROW, lo[0]))
-    return limbs.add(scaled, total)  # (12,T,128) + (T,128) broadcast
+    return limbs.mul_pow2((lo, hi), _M_I_DIAG_LOG2, plus=total)
 
 
 def _rc_row(rc_ref, r, like):
@@ -140,7 +139,7 @@ def _permutation_planes_stacked(rc_ref, lo, hi):
         el = _sbox7(limbs.add((lo[0], hi[0]), rc0))
         lo = jnp.concatenate([el[0][None], lo[1:]], axis=0)
         hi = jnp.concatenate([el[1][None], hi[1:]], axis=0)
-        return _internal_mds_planes(rc_ref, lo, hi)
+        return _internal_mds_planes(lo, hi)
 
     carry = jax.lax.fori_loop(0, 4, full_round, carry)
     carry = jax.lax.fori_loop(4, 26, partial_round, carry)
@@ -202,7 +201,7 @@ def _smem_spec():
     # explicit block + index map: the default index map traces i64 under the
     # global x64 flag, which Mosaic cannot legalize
     return pl.BlockSpec(
-        (31, 24), imap32(lambda *_: (0, 0)), memory_space=pltpu.SMEM
+        (30, 24), imap32(lambda *_: (0, 0)), memory_space=pltpu.SMEM
     )
 
 
@@ -225,7 +224,7 @@ def _permute_planes(lo, hi, tile_rows: int, interpret: bool):
         out_specs=[spec, spec],
         interpret=interpret,
         compiler_params=None if interpret else _CP,
-    )(jnp.asarray(rc_diag_table()), lo, hi)
+    )(jnp.asarray(rc_table()), lo, hi)
 
 
 @partial(jax.jit, static_argnums=(2, 3, 4))
@@ -252,7 +251,7 @@ def _sponge_planes(vlo, vhi, num_chunks: int, tile_rows: int, interpret: bool):
         out_specs=[out_spec, out_spec],
         interpret=interpret,
         compiler_params=None if interpret else _CP,
-    )(jnp.asarray(rc_diag_table()), vlo, vhi)
+    )(jnp.asarray(rc_table()), vlo, vhi)
 
 
 # tile legality (divisor-of-R, multiple-of-8 sublane rule) is shared with

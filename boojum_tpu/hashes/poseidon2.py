@@ -161,7 +161,7 @@ def poseidon2_permutation_planes_xla(state_p):
     """Batched permutation on (..., 12) limb planes (XLA path)."""
     from . import pallas_poseidon2 as pp2
 
-    rc = jnp.asarray(pp2.rc_diag_table())
+    rc = jnp.asarray(pp2.rc_table())
     lo = jnp.moveaxis(state_p[0], -1, 0)
     hi = jnp.moveaxis(state_p[1], -1, 0)
     olo, ohi = pp2._permutation_planes_stacked(rc, lo, hi)

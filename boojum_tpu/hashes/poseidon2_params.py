@@ -13,8 +13,12 @@ NUM_FULL_ROUNDS_TOTAL = 8
 NUM_PARTIAL_ROUNDS = 22
 TOTAL_NUM_ROUNDS = 30
 
-# Poseidon2 internal-matrix diagonal (M_I = all-ones + diag(d)); entries are
-# powers of two (reference: state_generic_impl.rs:73 M_I_DIAGONAL_ELEMENTS_MINUS_ONE).
+# Poseidon2 internal-matrix diagonal: M_I = all-ones + diag(d), and these
+# entries ARE d (row i of M_I·x is sum(x) + d_i·x_i). The reference keeps the
+# same numbers under the name M_I_DIAGONAL_ELEMENTS_MINUS_ONE
+# (state_generic_impl.rs:73): "minus one" of M_I's own diagonal 1 + d_i.
+# Every entry is a power of two, chosen so that the product is a shift; the
+# limb kernel (pallas_poseidon2.py) derives its shift amounts from this list.
 M_I_DIAGONAL = [
     1 << 4, 1 << 14, 1 << 11, 1 << 8, 1 << 0, 1 << 5,
     1 << 2, 1 << 9, 1 << 13, 1 << 6, 1 << 3, 1 << 12,

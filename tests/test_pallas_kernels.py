@@ -18,6 +18,10 @@ slow_only = pytest.mark.slow
 from boojum_tpu.field import gl, limbs
 from boojum_tpu.field import goldilocks as gf
 from boojum_tpu.field import extension as ext
+from boojum_tpu.hashes import poseidon2_params as params
+
+# M_I's diagonal as exponents, as the kernel derives them
+DIAG_LOG2 = [d.bit_length() - 1 for d in params.M_I_DIAGONAL]
 
 
 def _rand(shape, seed=0):
@@ -61,6 +65,27 @@ class TestLimbOps:
             gf.mul(self.a64, jnp.uint64(c)),
         )
 
+    # the twelve exponents of M_I's diagonal (k = 0 among them) and both
+    # ends of the range the op takes
+    @pytest.mark.parametrize("k", DIAG_LOG2 + [1, 31])
+    def test_mul_pow2(self, k):
+        want = gf.mul(self.a64, jnp.uint64(1 << k))
+        self._eq(limbs.mul_pow2(self.a, k), want)
+        self._eq(
+            limbs.mul_pow2(self.a, k, plus=self.b), gf.add(want, self.b64)
+        )
+
+    def test_mul_pow2_per_row(self):
+        """One exponent per leading row, one reduction on the restack, the
+        broadcast addend folded in: the internal matrix's own call."""
+        rows = np.stack([np.roll(np.asarray(self.a64), i) for i in range(12)])
+        pows = np.array(params.M_I_DIAGONAL, dtype=np.uint64)[:, None]
+        want = gf.add(gf.mul(jnp.asarray(rows), jnp.asarray(pows)), self.b64)
+        got = limbs.mul_pow2(
+            limbs.split(jnp.asarray(rows)), tuple(DIAG_LOG2), plus=self.b
+        )
+        self._eq(got, want)
+
     def test_ext_mul(self):
         got = limbs.ext_mul((self.a, self.b), (self.b, self.a))
         want = ext.mul((self.a64, self.b64), (self.b64, self.a64))
@@ -71,15 +96,77 @@ class TestLimbOps:
         self._eq(self.a, self.a64)
 
 
+def _edge_states():
+    """Width-12 states made of EDGE values: every rotation, so that each
+    value meets each row of the internal matrix."""
+    return np.stack([np.roll(np.resize(EDGE, 12), i) for i in range(12)])
+
+
+def _host_permutation(states):
+    """Python integers only: none of the repo's device code."""
+    from boojum_tpu.hashes.poseidon2 import poseidon2_permutation_host
+
+    return np.array(
+        [poseidon2_permutation_host([int(x) for x in row]) for row in states],
+        dtype=np.uint64,
+    )
+
+
+def _count_eqns(jaxpr):
+    """Equations of a jaxpr, those of nested jaxprs (loop bodies, inner
+    jits) in place of the equation that holds them."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        inner = [
+            getattr(v, "jaxpr", v)
+            for p in eqn.params.values()
+            for v in (p if isinstance(p, (tuple, list)) else (p,))
+            if hasattr(getattr(v, "jaxpr", v), "eqns")
+        ]
+        n += sum(_count_eqns(j) for j in inner) if inner else 1
+    return n
+
+
 class TestPoseidon2Kernel:
     def test_permutation_interpret(self):
         from boojum_tpu.hashes import poseidon2 as p2
         from boojum_tpu.hashes import pallas_poseidon2 as pp2
 
-        state = jnp.asarray(_rand((256, 12), 20))
-        got = pp2.permutation(state, interpret=True)
-        want = p2.poseidon2_permutation_xla(state)
-        assert np.array_equal(np.asarray(got), np.asarray(want))
+        state = np.concatenate([_rand((244, 12), 20), _edge_states()])
+        got = np.asarray(pp2.permutation(jnp.asarray(state), interpret=True))
+        want = p2.poseidon2_permutation_xla(jnp.asarray(state))
+        assert np.array_equal(got, np.asarray(want))
+        # and against python integers, so that a fault shared by the limb
+        # and the u64 device code cannot pass
+        assert np.array_equal(got[-24:], _host_permutation(state[-24:]))
+
+    def test_permutation_planes_xla_known_answer(self):
+        """The limb XLA twin (the same round body as the kernel) against
+        the host permutation on edge-value and random states."""
+        from boojum_tpu.hashes import poseidon2 as p2
+
+        state = np.concatenate([_edge_states(), _rand((20, 12), 24)])
+        got = p2.poseidon2_permutation_planes_xla(
+            limbs.split(jnp.asarray(state))
+        )
+        assert np.array_equal(
+            np.asarray(limbs.join(got)), _host_permutation(state)
+        )
+
+    def test_round_body_traces_no_more_equations(self):
+        """The tracing bill: this body is inlined in a dozen kernels and
+        traced again by each in every process. Limits are the counts on
+        the tree before PR 25 (full multiply by the diagonal): 4803 for the
+        permutation, 582 for the internal matrix of one partial round."""
+        import jax
+        from boojum_tpu.hashes import pallas_poseidon2 as pp2
+
+        rc = jnp.asarray(pp2.rc_table())
+        lo = jnp.zeros((12, 8, 128), jnp.uint32)
+        perm = jax.make_jaxpr(pp2._permutation_planes_stacked)(rc, lo, lo)
+        assert _count_eqns(perm.jaxpr) <= 4803
+        mds = jax.make_jaxpr(pp2._internal_mds_planes)(lo, lo)
+        assert _count_eqns(mds.jaxpr) <= 582
 
     @slow_only
     def test_sponge_interpret(self):
