@@ -619,15 +619,6 @@ def main():
 
     asm = cs.into_assembly()
     print(f"trace_len={asm.trace_len}", file=sys.stderr, flush=True)
-    if asm.trace_len >= (1 << 19):
-        # at the 2^20 HBM ceiling, queueing all Q coset sweeps async lets
-        # neighbors' working sets overlap and OOM (round-3 finding) — the
-        # overlapped prover no longer barriers by default, so the bench
-        # opts in for big traces (export BOOJUM_TPU_SYNC_SWEEPS=0 to
-        # experiment without it)
-        os.environ.setdefault("BOOJUM_TPU_SYNC_SWEEPS", "1")
-        _log("large trace: defaulting BOOJUM_TPU_SYNC_SWEEPS=1")
-
     if "--build-artifacts" in sys.argv:
         # AOT build step: compile the whole dispatch surface (kernel
         # library + setup + one full prove) into a deployment bundle

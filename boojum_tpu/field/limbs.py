@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -130,11 +131,22 @@ def join_np(lo, hi) -> np.ndarray:
 # 32-bit building blocks
 # ---------------------------------------------------------------------------
 
+# The hot primitives below are traced ONCE per argument shape and inlined
+# from the cached jaxpr afterwards (`inline=True`: the enclosing jaxpr, and
+# with it every lowered module and Mosaic kernel, is the one plain Python
+# would have traced). Tracing them through `jnp`'s operators every time was
+# most of a process's set-up: the fused sweep's one Pallas body holds half
+# a million u32 equations, 110 of them a field multiply, and a fresh
+# process traces the whole kernel library before its first prove (245 s
+# for the Era geometry's library here, 88 s with this; PERF.md, PR 26).
+_once_per_shape = functools.partial(jax.jit, inline=True)
+
 
 def _b2u(x) -> jax.Array:
     return x.astype(_u32)
 
 
+@_once_per_shape
 def mul32_wide(a, b):
     """Full 32x32 -> 64-bit product as (lo, hi) uint32 pair.
 
@@ -205,12 +217,14 @@ def _canonicalize(lo, hi):
 # ---------------------------------------------------------------------------
 
 
+@_once_per_shape
 def add(a, b):
     lo, hi, c = add64(a, b)
     lo, hi = _plus_eps_where(lo, hi, c)
     return _canonicalize(lo, hi)
 
 
+@_once_per_shape
 def sub(a, b):
     lo, hi, br = sub64(a, b)
     return _minus_eps_where(lo, hi, br)
@@ -259,6 +273,7 @@ def _reduce96(lo, hi, p2):
     return _canonicalize(lo2, hi2)
 
 
+@_once_per_shape
 def reduce128(p0, p1, p2, p3):
     """(p3·2^96 + p2·2^64 + p1·2^32 + p0) mod p, canonical.
 
@@ -269,10 +284,12 @@ def reduce128(p0, p1, p2, p3):
     return _reduce96(lo, hi, p2)
 
 
+@_once_per_shape
 def mul(a, b):
     return reduce128(*mul_wide(a, b))
 
 
+@_once_per_shape
 def sqr(a):
     """a*a, sharing the cross product (12 VPU multiplies instead of 16)."""
     ll_lo, ll_hi = mul32_wide(a[0], a[0])
@@ -344,6 +361,7 @@ def ext_sub(a, b):
     return sub(a[0], b[0]), sub(a[1], b[1])
 
 
+@_once_per_shape
 def ext_mul(a, b):
     """(a0 + a1 w)(b0 + b1 w) = a0b0 + 7 a1b1 + (a0b1 + a1b0) w."""
     v0 = mul(a[0], b[0])

@@ -55,6 +55,16 @@ def range_check_byte(cs, v):
     cs.perform_lookup(xor_id, [v, cs.zero_var()])
 
 
+def range_check_byte_pairs(cs, byte_vars):
+    """Force every variable into [0,256), two a lookup: (a, b) is a key of
+    xor8 only if both are bytes (an odd one out pairs with zero)."""
+    xor_id = cs.get_table_id("xor8")
+    zero = cs.zero_var()
+    for i in range(0, len(byte_vars), 2):
+        pair = list(byte_vars[i : i + 2])
+        cs.perform_lookup(xor_id, pair + [zero] * (2 - len(pair)))
+
+
 def rotate_bytes_left(cs, word, r: int):
     """Rotate a little-endian byte-variable word left by r bits. The
     byte-aligned part is a free relabeling; the residual shift `rem` splits

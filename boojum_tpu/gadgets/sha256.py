@@ -356,9 +356,21 @@ def round_function(ctx: _Sha256Ctx, state, message_block, last_round):
     return le_chunks
 
 
-def allocate_u8_input(cs, data: bytes):
+def allocate_u8_input(cs, data: bytes, range_check: str = "trixor4"):
     """Allocate input bytes as range-checked u8 variables (the reference
-    bench allocates checked UInt8 witnesses, sha256/mod.rs:330)."""
+    bench allocates checked UInt8 witnesses, sha256/mod.rs:330). The check
+    rides a table the circuit has anyway: `"trixor4"`, SHA-256's width-4
+    chunk table (two 4-bit chunks a byte), or `"xor8"`, the 8-bit table of
+    the Keccak and Blake2s gadgets (two bytes a lookup), which is all a
+    geometry with width-3 lookups can hold."""
+    if range_check == "xor8":
+        from .byte_ops import ensure_xor8, range_check_byte_pairs
+
+        ensure_xor8(cs)
+        out = [cs.alloc_variable_with_value(byte) for byte in data]
+        range_check_byte_pairs(cs, out)
+        return out
+    assert range_check == "trixor4", range_check
     ctx = _Sha256Ctx(cs)
     out = []
     chunks_to_check = []

@@ -170,13 +170,18 @@ def _fft_body(p):
 # ---------------------------------------------------------------------------
 
 
-def _mxu_fft_p(p, inverse: bool):
+def _mxu_fft_p(p, inverse: bool, forward=None):
+    """`forward`: the two named programs of a forward transform above
+    2^MAX_LOG_N (`_two_program_forward`); the commits' LDE and the coset
+    evaluations pass their own, so a device trace can tell them apart."""
     from . import mxu_ntt
 
     n = p[0].shape[-1]
     log_n = n.bit_length() - 1
     if log_n > mxu_ntt.MAX_LOG_N:
-        return _hybrid_p(p, log_n, inverse)
+        if inverse:
+            return _hybrid_inv_p(p, log_n)
+        return _hybrid_fwd_p(p, log_n, forward or _NTT_FORWARD)
     ctx = mxu_ntt.get_mxu_ctx(log_n)
     lead = p[0].shape[:-1]
     flat = (p[0].reshape(-1, ctx.R, ctx.C), p[1].reshape(-1, ctx.R, ctx.C))
@@ -185,24 +190,81 @@ def _mxu_fft_p(p, inverse: bool):
     return out[0].reshape(lead + (n,)), out[1].reshape(lead + (n,))
 
 
-@partial(jax.jit, static_argnums=(1, 2))
-def _hybrid_p(p, log_n: int, inverse: bool):
-    """2^17..2^22: plane XLA outer radix-2 stages + per-block MXU kernels
-    (mxu_ntt._fft_hybrid/_ifft_hybrid twins)."""
+def forward_is_two_programs(n: int) -> bool:
+    """True where the forward transform of size n runs as two device
+    programs (`_hybrid_fwd_p`) and must not be traced into a caller's jit."""
+    from . import mxu_ntt
+
+    n = int(n)
+    return _mxu_ntt_ready(n, None) and n.bit_length() - 1 > mxu_ntt.MAX_LOG_N
+
+
+def _two_program_forward(prefix: str):
+    """(outer, mxu): the two jitted programs of `_hybrid_fwd_p`, named
+    `<prefix>_outer_p` and `<prefix>_mxu_p` so that a device trace (and
+    `benchmark/families.json`) gives each caller's time to its own layer.
+    Reshapes happen inside the programs: nothing runs between them."""
+
+    def outer(p, log_n: int):
+        """The outer radix-2 DIF stages on planes, cut into the
+        2^MAX_LOG_N blocks the MXU kernel transforms: (blocks, R, C)."""
+        from . import mxu_ntt
+
+        ctx = mxu_ntt.get_mxu_ctx(mxu_ntt.MAX_LOG_N)
+        p = dif_stages_p(
+            p, PlaneNTTContext(log_n), 0, log_n - mxu_ntt.MAX_LOG_N
+        )
+        return (
+            p[0].reshape(-1, ctx.R, ctx.C), p[1].reshape(-1, ctx.R, ctx.C)
+        )
+
+    def mxu(flat, shape: tuple):
+        from . import mxu_ntt
+
+        out = mxu_ntt._fft_planes(flat, mxu_ntt.MAX_LOG_N, False)
+        return out[0].reshape(shape), out[1].reshape(shape)
+
+    outer.__name__ = outer.__qualname__ = f"{prefix}_outer_p"
+    mxu.__name__ = mxu.__qualname__ = f"{prefix}_mxu_p"
+    return (
+        jax.jit(outer, static_argnums=(1,)), jax.jit(mxu, static_argnums=(1,))
+    )
+
+
+_NTT_FORWARD = _two_program_forward("_ntt_hybrid")
+# the commits' LDE keeps `lde_planes` in its programs' names at every size
+# (`mxu_ntt._lde_planes` at or below 2^MAX_LOG_N): one name to find it by
+_LDE_FORWARD = _two_program_forward("_lde_planes_hybrid")
+_COSET_EVAL_FORWARD = _two_program_forward("_coset_eval_hybrid")
+
+
+def _hybrid_fwd_p(p, log_n: int, programs):
+    """2^17..2^22, forward: plane XLA outer stages, then the per-block MXU
+    kernel, as TWO device programs and never one. Compiled into a single
+    program the pair does not come back on the v5e once the batch is more
+    than a few columns: (32, 2, 2^18) planes stalled for over 170 s where
+    the outer stages alone take 6.8 ms and the MXU kernel on their blocks
+    8.6 ms, and a batch of one returns in 2.5 ms (my chip run, PR 26). So
+    this function is not jitted and refuses to be traced into a caller's."""
+    if isinstance(p[0], jax.core.Tracer):
+        raise TypeError(
+            "the forward NTT above 2^16 runs as two device programs: call "
+            "it outside jit (limb_ntt.forward_is_two_programs says when)"
+        )
+    outer, mxu = programs
+    return mxu(outer(p, log_n), tuple(p[0].shape))
+
+
+@partial(jax.jit, static_argnums=(1,))
+def _hybrid_inv_p(p, log_n: int):
+    """2^17..2^22, inverse: per-block MXU kernels, then plane XLA outer
+    radix-2 stages (mxu_ntt._ifft_hybrid twin)."""
     from . import mxu_ntt
 
     n = 1 << log_n
     outer = log_n - mxu_ntt.MAX_LOG_N
     ctx = PlaneNTTContext(log_n)
     lead = p[0].shape[:-1]
-    if not inverse:
-        p = dif_stages_p(p, ctx, 0, outer)
-        blocks = (
-            p[0].reshape(lead + (1 << outer, 1 << mxu_ntt.MAX_LOG_N)),
-            p[1].reshape(lead + (1 << outer, 1 << mxu_ntt.MAX_LOG_N)),
-        )
-        out = _mxu_fft_p(blocks, False)
-        return out[0].reshape(lead + (n,)), out[1].reshape(lead + (n,))
     blocks = (
         p[0].reshape(lead + (1 << outer, 1 << mxu_ntt.MAX_LOG_N)),
         p[1].reshape(lead + (1 << outer, 1 << mxu_ntt.MAX_LOG_N)),
@@ -262,6 +324,49 @@ def _assemble_chunks_p(shape, produce, starts):
     return out_lo, out_hi
 
 
+@partial(jax.jit, static_argnums=(3,))
+def _coset_eval_scale_p(p, row_p, start, size: int):
+    """Columns [start, start + size) of `p`, each times the row `row_p`
+    (`start` is a device scalar: one program per chunk size)."""
+    return limbs.mul(
+        (
+            jax.lax.dynamic_slice_in_dim(p[0], start, size, axis=0),
+            jax.lax.dynamic_slice_in_dim(p[1], start, size, axis=0),
+        ),
+        (row_p[0][None], row_p[1][None]),
+    )
+
+
+def scaled_fft_chunks(B: int, n: int, chunk_bytes: int) -> dict:
+    """{first column: columns} of the chunks `scaled_fft_p` walks."""
+    per = max(1, int(chunk_bytes) // (n * 8))
+    return {i: min(per, B - i) for i in range(0, B, per)}
+
+
+def scaled_fft_p(p, row_p, chunk_bytes: int):
+    """(B, n) monomial planes times one (n,) scale row, then the forward
+    NTT: a coset evaluation. For sizes whose forward transform is two
+    programs (`forward_is_two_programs`): column chunks of at most
+    `chunk_bytes`, each its own scale / outer-stage / MXU dispatches."""
+    B, n = p[0].shape
+    chunks = scaled_fft_chunks(B, n, chunk_bytes)
+
+    def produce(i):
+        scaled = _coset_eval_scale_p(p, row_p, jnp.int32(i), chunks[i])
+        return _mxu_fft_p(scaled, False, _COSET_EVAL_FORWARD)
+
+    if len(chunks) == 1:
+        return produce(0)
+    return _assemble_chunks_p(p[0].shape, produce, chunks)
+
+
+@jax.jit
+def _lde_planes_scale_p(p, scale):
+    """(..., n) monomial planes times the (L, n) coset scale rows:
+    (..., L, n)."""
+    return limbs.mul((p[0][..., None, :], p[1][..., None, :]), scale)
+
+
 def monomial_from_values_p(p):
     """Values over H -> monomial coefficients, on planes (chunked)."""
     lo, hi = p
@@ -286,10 +391,9 @@ def _lde_one_p(p, lde_factor: int, coset: int):
         log_n = n.bit_length() - 1
         if log_n > mxu_ntt.MAX_LOG_N:
             scale = _lde_scale_planes(log_n, lde_factor, coset)
-            scaled = limbs.mul(
-                (p[0][..., None, :], p[1][..., None, :]), scale
+            return _mxu_fft_p(
+                _lde_planes_scale_p(p, scale), False, _LDE_FORWARD
             )
-            return _mxu_fft_p(scaled, False)
         ctx = mxu_ntt.get_mxu_ctx(log_n)
         lead = p[0].shape[:-1]
         flat = (
@@ -336,6 +440,27 @@ def lde_from_monomial_p(
 # ---------------------------------------------------------------------------
 
 
+def sdsp(*shape):
+    """A (lo, hi) pair of u32 ShapeDtypeStructs: one plane element."""
+    s = jax.ShapeDtypeStruct(shape, jnp.uint32)
+    return (s, s)
+
+
+def hybrid_fwd_kernel_specs(name: str, shape: tuple, log_n: int,
+                            programs) -> list:
+    """The two programs of `_hybrid_fwd_p` on planes of `shape` (last axis
+    2^log_n)."""
+    from . import mxu_ntt
+
+    ctx = mxu_ntt.get_mxu_ctx(mxu_ntt.MAX_LOG_N)
+    blocks = int(np.prod(shape)) >> mxu_ntt.MAX_LOG_N
+    outer, mxu = programs
+    return [
+        (f"{name}:outer", outer, (sdsp(*shape), log_n)),
+        (f"{name}:mxu", mxu, (sdsp(blocks, ctx.R, ctx.C), tuple(shape))),
+    ]
+
+
 def plane_ntt_kernel_specs(B: int, log_n: int, lde_factor: int | None = None,
                            coset: int = int(gl.MULTIPLICATIVE_GENERATOR),
                            mono: bool = True) -> list:
@@ -345,11 +470,6 @@ def plane_ntt_kernel_specs(B: int, log_n: int, lde_factor: int | None = None,
     from .ntt import chunk_shapes
 
     n = 1 << log_n
-
-    def sdsp(*shape):
-        s = jax.ShapeDtypeStruct(shape, jnp.uint32)
-        return (s, s)
-
     specs = []
     if mono:
         specs += [
@@ -371,10 +491,14 @@ def plane_ntt_kernel_specs(B: int, log_n: int, lde_factor: int | None = None,
         from . import mxu_ntt
 
         if log_n > mxu_ntt.MAX_LOG_N:
+            name = f"lde_hybrid_limbres_b{b}_n{n}_L{L}"
             specs.append((
-                f"lde_hybrid_limbres_b{b}_n{n}_L{L}", _hybrid_p,
-                (sdsp(b, L, n), log_n, False),
+                f"{name}:scale", _lde_planes_scale_p,
+                (sdsp(b, n), sdsp(L, n)),
             ))
+            specs += hybrid_fwd_kernel_specs(
+                name, (b, L, n), log_n, _LDE_FORWARD
+            )
             continue
         ctx = mxu_ntt.get_mxu_ctx(log_n)
         specs.append((

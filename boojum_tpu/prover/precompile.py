@@ -679,7 +679,7 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
             lk_cols, (_sdsp(n), _sdsp(width + 1, n)), bg8, R_args, width,
         )
         add(
-            "ext_binv_lookup_limbres", lop.ext_batch_inverse_jit,
+            "ext_binv_lookup_limbres", RES._lookup_denominators_inv_p,
             pairp(R_args + 1, n),
         )
     add(
@@ -709,10 +709,8 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
         ("wit", B_wit), ("setup", B_setup), ("s2", S), ("zs", 2)
     ):
         if smm is None:
-            add(
-                f"coset_eval_{tag}_limbres", RES._coset_eval_q_p,
-                _sdsp(B, n), _sdsp(Q, n), _i32(),
-            )
+            for nm, fn, args in RES.coset_eval_kernel_specs(tag, B, n, Q):
+                add(nm, fn, *args)
         else:
             add(
                 f"coset_eval_{tag}_limbres_sm", SS._coset_eval_fn_p(smm, B),

@@ -483,6 +483,42 @@ def _coset_eval_q(mono_stack, scale_q, c_arr):
     return _coset_eval(mono_stack, scale_row)
 
 
+# Share of the device memory still free at the start of round 3 that the
+# queued coset sweeps may fill; the rest stays for the quotient tail, the
+# allocator's fragmentation and whatever the next round prefetches.
+# Conservative where it has been measured: at 2^18 rows on 385 columns a
+# stride of 3 (this rule's), of 4 and no barrier at all read the same peak
+# and the same wall within 0.5 % (PERF.md, PR 26); 2^20 rows will tell.
+_SWEEP_QUEUE_SHARE = 0.5
+
+
+def _sweep_working_set_bytes(group_columns: int, n: int, chips: int = 1):
+    """Device bytes one coset of the round-3 sweep holds while it is
+    queued, per chip: its four group evaluations (the sweep's inputs, 8
+    bytes an element in either representation), counted twice because
+    each evaluation kernel also holds a scaled copy of its group while it
+    transforms it."""
+    return 2 * 8 * int(group_columns) * int(n) // max(1, int(chips))
+
+
+def _sweep_barrier_stride(Q: int, working_set_bytes: int) -> int:
+    """How many coset sweeps may be queued between two host barriers; 0
+    means no barrier at all. Chosen from what the device reports
+    (`memory_stats()`: the allocator's limit and what is in use now,
+    queued work included) and the sweep's working set, so that a trace
+    near the memory ceiling proves through a plain `prove()` call. A
+    backend that reports no limit (XLA:CPU) gets no barrier."""
+    memory = _metrics.device_memory_room()
+    if memory is None or working_set_bytes <= 0:
+        return 0
+    limit, in_use = memory
+    room = _SWEEP_QUEUE_SHARE * max(0, limit - in_use)
+    _metrics.gauge_max("quotient.sweep_room_bytes", room)
+    if Q * working_set_bytes <= room:
+        return 0
+    return max(1, int(room // working_set_bytes))
+
+
 def _coset_sweep_fn(
     assembly, selector_paths, non_residues, lk_ctx, sm_mesh=None
 ):
@@ -1381,7 +1417,7 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
             dens = RES._lookup_denominators_p(
                 lkcols, (tid_col, table_stack), lkbg_arr, R_args, lp.width
             )
-            lk_inv = lop.ext_batch_inverse_jit(dens)
+            lk_inv = RES._lookup_denominators_inv_p(dens)
         z_pp = RES._z_and_partials_p(num_all, den_inv_all)
         stack = RES.stage2_stack_fn_p(assembly, setup.selector_paths)
         s2_vals = stack(z_pp[0], z_pp[1], lk_inv, mult_dev, consts_dev)
@@ -1691,19 +1727,26 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
         sweep = _coset_sweep_fn(
             assembly, setup.selector_paths, setup.non_residues, lk_ctx
         )
-        # No default host barrier here (the old code block_until_ready'd
-        # every sweep at n >= 2^19): the dependent dispatches already
-        # order the work — each sweep consumes its own coset's four group
-        # evaluations and the quotient tail consumes every sweep output,
-        # so the device runs them in queue order with zero host stalls.
-        # BOOJUM_TPU_SYNC_SWEEPS=1 restores a per-coset barrier for
-        # HBM-constrained geometries where bounding the number of
-        # concurrently ENQUEUED sweep working sets matters more than
-        # keeping the host ahead of the device (the entry points that
-        # drive the 2^20 ceiling — bench.py at large traces,
-        # scripts/sha2_20_driver.py — set it themselves).
-        _sync_sweeps = _transfer.env_flag("BOOJUM_TPU_SYNC_SWEEPS", False)
+        # The dependent dispatches already order the work: each sweep
+        # consumes its own coset's four group evaluations and the quotient
+        # tail consumes every sweep output, so the device runs them in
+        # queue order with zero host stalls. What a host barrier between
+        # cosets bounds is MEMORY: a queued coset's buffers are allocated
+        # when it is enqueued, so Q cosets queued at once hold Q working
+        # sets (the 2^20 OOM of round 3). `_sweep_barrier_stride` chooses
+        # from what the device reports and the working set's size; the
+        # flight recording carries what it saw and how many barriers it put.
         _setup_eval_mono = _setup_mono_p if res else setup.setup_monomials
+        _sweep_ws = _sweep_working_set_bytes(
+            sum(
+                int((g[0] if res else g).shape[0])
+                for g in (wit_mono, _setup_eval_mono, s2_mono, zs_mono)
+            ),
+            n, 1 if sm_mesh is None else sm_mesh.size,
+        )
+        _barrier_stride = _sweep_barrier_stride(Q, _sweep_ws)
+        _metrics.gauge_max("quotient.sweep_working_set_bytes", _sweep_ws)
+        _metrics.count("quotient.sweep_barriers", 0)
         if sm_mesh is not None:
             # pad + column-shard the four monomial groups ONCE per round
             # (not per coset); each coset evaluation then runs the
@@ -1750,7 +1793,7 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
         elif res:
 
             def _eval_group(tag, mono_p, ci):
-                return RES._coset_eval_q_p(mono_p, scale_q, ci)
+                return RES.coset_eval_q_p(mono_p, scale_q, ci)
 
         else:
 
@@ -1790,7 +1833,10 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
                         lkb01 if lkb01 is not None else zero2,
                         lkg01 if lkg01 is not None else zero2,
                     )
-                if _sync_sweeps:
+                if _barrier_stride and c + 1 < Q and (
+                    (c + 1) % _barrier_stride == 0
+                ):
+                    _metrics.count("quotient.sweep_barriers")
                     _metrics.count("host.blocking_syncs")
                     jax.block_until_ready(t1c)
                 T_parts0.append(t0c)

@@ -413,6 +413,15 @@ def _lookup_denominators_p(
     )
 
 
+@jax.jit
+def _lookup_denominators_inv_p(dens):
+    """The batch inverse of `_lookup_denominators_p`'s output, as a program
+    with a name of its own: under `ext_batch_inverse`'s it shared a module
+    name with the copy permutation's and the DEEP inversions, and a device
+    trace could not give the lookup argument its stage-2 time."""
+    return lop.ext_batch_inverse(dens)
+
+
 def stage2_stack_fn_p(assembly, selector_paths):
     """Plane twin of prover._stage2_stack_fn, cached per assembly."""
     cached = getattr(assembly, "_stage2_stack_p_jit", None)
@@ -524,6 +533,49 @@ def _coset_eval_q_p(mono_p, scale_q_p, c_arr):
         jax.lax.dynamic_index_in_dim(scale_q_p[1], c_arr, 0, keepdims=False),
     )
     return _coset_eval_p(mono_p, row)
+
+
+@jax.jit
+def _coset_eval_row_p(scale_q_p, c_arr):
+    return (
+        jax.lax.dynamic_index_in_dim(scale_q_p[0], c_arr, 0, keepdims=False),
+        jax.lax.dynamic_index_in_dim(scale_q_p[1], c_arr, 0, keepdims=False),
+    )
+
+
+def coset_eval_q_p(mono_p, scale_q_p, c_arr):
+    """One group's evaluation on coset `c_arr` of the rate-Q domain: one
+    program (`_coset_eval_q_p`) up to 2^16 rows; above, where the forward
+    NTT is two programs that may not share one (limb_ntt._hybrid_fwd_p),
+    the scale row's pick and then scale / outer stages / MXU kernel per
+    column chunk, each its own dispatch."""
+    if not LN.forward_is_two_programs(mono_p[0].shape[-1]):
+        return _coset_eval_q_p(mono_p, scale_q_p, c_arr)
+    return LN.scaled_fft_p(
+        mono_p, _coset_eval_row_p(scale_q_p, c_arr), _SWEEP_EVAL_CHUNK
+    )
+
+
+def coset_eval_kernel_specs(tag: str, B: int, n: int, Q: int) -> list:
+    """(name, fn, args) of what `coset_eval_q_p` dispatches for a (B, n)
+    group: the one program, or the pieces of the two-program form."""
+    sdsp = LN.sdsp
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    name = f"coset_eval_{tag}_limbres"
+    if not LN.forward_is_two_programs(n):
+        return [(name, _coset_eval_q_p, (sdsp(B, n), sdsp(Q, n), i32))]
+    log_n = n.bit_length() - 1
+    specs = [(f"{name}:row", _coset_eval_row_p, (sdsp(Q, n), i32))]
+    sizes = LN.scaled_fft_chunks(B, n, _SWEEP_EVAL_CHUNK).values()
+    for b in sorted(set(sizes)):
+        specs.append((
+            f"{name}:scale_b{b}", LN._coset_eval_scale_p,
+            (sdsp(B, n), sdsp(n), i32, b),
+        ))
+        specs += LN.hybrid_fwd_kernel_specs(
+            f"{name}:fft_b{b}", (b, n), log_n, LN._COSET_EVAL_FORWARD
+        )
+    return specs
 
 
 @partial(jax.jit, static_argnums=(2, 3))

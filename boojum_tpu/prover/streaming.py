@@ -17,13 +17,16 @@ column blocks straight from the (always-resident) monomials:
   block at query time (one extra LDE pass each — FLOPs traded for the
   ~4 GB of residency the materialized path pins).
 
-Streaming engages when the committed-storage footprint would exceed
-BOOJUM_TPU_STREAM_LDE bytes (default 1.5 GiB; "1" forces on, "0" off) —
-small traces keep the materialized fast path.
+Streaming engages when the committed-storage footprint would exceed an
+eighth of the device's memory as its allocator reports it (1.5 GiB where
+the backend reports no limit, and never less); BOOJUM_TPU_STREAM_LDE
+overrides the choice ("1" forces on, "0" off, a number is a byte
+threshold) — small traces keep the materialized fast path.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -36,7 +39,34 @@ from ..ntt import lde_from_monomial
 COL_BLOCK = 32
 
 
+# Share of the device's memory the committed storages of one prove may take
+# before the commits stream: the round-3 working sets, the NTT temporaries
+# and the DEEP/FRI codewords need several times the storages beside them.
+# On a 16 GB chip that is 2 GB: the Era geometry at 2^18 rows (1.67 GB)
+# stays materialized, SHA-256 at 2^20 rows (4.4 GB) streams.
+STREAM_SHARE_OF_DEVICE = 1 / 8
+DEFAULT_STREAM_THRESHOLD = 1536 << 20
+
+
+@functools.lru_cache(maxsize=1)
+def _device_stream_threshold() -> float:
+    """Once a process: the kernel enumeration, generate_setup and every
+    prove must agree on which path runs."""
+    from ..utils.metrics import device_memory_room
+
+    memory = device_memory_room()
+    if memory is None:
+        return float(DEFAULT_STREAM_THRESHOLD)
+    return max(
+        float(DEFAULT_STREAM_THRESHOLD), STREAM_SHARE_OF_DEVICE * memory[0]
+    )
+
+
 def stream_threshold_bytes() -> float:
+    """BOOJUM_TPU_STREAM_LDE as an override ("1" forces streaming, "0"
+    forbids it, a number is a byte threshold); unset, the library chooses
+    from the device's memory: a share of what the allocator reports as its
+    limit, and 1.5 GiB where the backend reports none (XLA:CPU)."""
     v = os.environ.get("BOOJUM_TPU_STREAM_LDE", "").strip()
     if v == "0":
         return float("inf")
@@ -47,7 +77,7 @@ def stream_threshold_bytes() -> float:
             return float(v)  # explicit byte threshold
         except ValueError:
             pass
-    return float(1536 << 20)
+    return _device_stream_threshold()
 
 
 def use_streamed_lde(total_cols: int, domain_size: int) -> bool:

@@ -332,6 +332,23 @@ def live_buffer_census() -> tuple[int, int] | None:
         return None
 
 
+def device_memory_room() -> tuple[int, int] | None:
+    """(bytes_limit, bytes_in_use) of the first local device, as its
+    allocator reports them now (queued work included); None where the
+    backend reports no limit (XLA:CPU). What the prover's own memory
+    choices read: the streamed-commit threshold and the round-3 barrier."""
+    try:
+        import jax
+
+        stats = jax.local_devices()[0].memory_stats() or {}
+    except Exception:
+        return None
+    limit = int(stats.get("bytes_limit", 0) or 0)
+    if limit <= 0:
+        return None
+    return limit, int(stats.get("bytes_in_use", 0) or 0)
+
+
 def device_memory_stats() -> dict | None:
     """Aggregated device.memory_stats() over local devices: sums
     bytes_in_use, maxes peak_bytes_in_use. None/{} when the backend does

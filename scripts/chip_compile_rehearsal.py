@@ -3,8 +3,14 @@
 without the chip (the `on-chip-measurement` guide's third rehearsal).
 
     JAX_PLATFORMS=cpu python scripts/chip_compile_rehearsal.py \
-        [--sha-bytes 8192] [--fma-log-n N] [--skip K] [--only A,B] \
-        [--workers W] [--mesh | --force-xla]
+        [--sha-bytes 8192 | --fma-log-n N | --config FILE --traffic MIX] \
+        [--skip K] [--only A,B] [--workers W] [--mesh | --force-xla]
+
+`--config benchmark/configs/<name>.json --traffic <mix>` compiles a
+benchmark configuration's own library: the circuit comes from the
+configuration's builder under `benchmark/circuits/` with the mix's request
+(seed 0: the library depends on shapes, not on the witness) and the
+`ProofConfig` from the file, exactly as `benchmark/system.py` makes them.
 
 What the chip's compiler would refuse (VMEM limit, unaligned slice, SMEM
 table size) it refuses here, at no chip time. Nothing runs: a compile that
@@ -35,10 +41,38 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 
+def benchmark_cell(config_file: str, traffic: str):
+    """(assembly, ProofConfig) of a benchmark configuration under a traffic
+    mix, through the harness's own `BoojumSystem.synthesize`."""
+    import json
+
+    from benchmark.system import BoojumSystem
+
+    if not traffic:
+        raise SystemExit("--config needs --traffic <mix>")
+    bench_dir = os.path.dirname(os.path.dirname(os.path.abspath(config_file)))
+    with open(config_file) as f:
+        config = json.load(f)
+    with open(os.path.join(bench_dir, "traffic", f"{traffic}.json")) as f:
+        mix = json.load(f)
+    system = BoojumSystem()
+    system.synthesize(
+        {"config": config, "traffic": mix, "bench_dir": bench_dir}, seed=0
+    )
+    return system.asm, system.cfg
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--sha-bytes", type=int, default=8192)
     ap.add_argument("--fma-log-n", type=int, default=0)
+    ap.add_argument("--config", default="",
+                    help="a benchmark configuration file (with --traffic)")
+    ap.add_argument("--traffic", default="",
+                    help="a traffic mix's name under benchmark/traffic/")
+    ap.add_argument("--hbm-gib", type=float, default=15.75,
+                    help="device memory of the described chip: the library "
+                    "takes its streamed-commit threshold from it")
     ap.add_argument("--skip", type=int, default=0)
     ap.add_argument("--only", default="")
     ap.add_argument("--workers", type=int, default=os.cpu_count() or 4)
@@ -47,6 +81,15 @@ def main():
     a = ap.parse_args()
 
     jax.config.update("jax_enable_compilation_cache", False)
+    # the attached backend is the CPU, which reports no memory limit: say
+    # what the described chip would report, so that the library enumerates
+    # the commit path (materialized or streamed) it would choose there
+    from boojum_tpu.prover import streaming
+
+    os.environ.setdefault("BOOJUM_TPU_STREAM_LDE", str(int(max(
+        streaming.DEFAULT_STREAM_THRESHOLD,
+        streaming.STREAM_SHARE_OF_DEVICE * a.hbm_gib * (1 << 30),
+    ))))
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     jax.default_backend = lambda: "tpu"
 
@@ -54,17 +97,20 @@ def main():
     from boojum_tpu.prover import ProofConfig, enumerate_kernels
     from boojum_tpu.prover.precompile import trim_host_heap
 
-    if a.fma_log_n:
-        cs = examples.build_fma_bench_circuit(a.fma_log_n)
-        lde = 4
+    if a.config:
+        asm, cfg = benchmark_cell(a.config, a.traffic)
     else:
-        cs = examples.build_sha256_bench_circuit(a.sha_bytes)
-        lde = 8
-    cfg = ProofConfig(
-        fri_lde_factor=lde, merkle_tree_cap_size=16, num_queries=50,
-        pow_bits=0, fri_final_degree=16,
-    )
-    asm = cs.into_assembly()
+        if a.fma_log_n:
+            cs = examples.build_fma_bench_circuit(a.fma_log_n)
+            lde = 4
+        else:
+            cs = examples.build_sha256_bench_circuit(a.sha_bytes)
+            lde = 8
+        cfg = ProofConfig(
+            fri_lde_factor=lde, merkle_tree_cap_size=16, num_queries=50,
+            pow_bits=0, fri_final_degree=16,
+        )
+        asm = cs.into_assembly()
     mesh_shape = None
     if a.mesh:
         import numpy as np

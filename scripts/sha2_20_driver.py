@@ -14,12 +14,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 CKPT = os.environ.get("SHA20_CKPT", "/tmp/sha2_20_asm.pkl")
 
-# the 2^20 geometry runs at the HBM ceiling: queueing all Q coset sweeps
-# async lets neighbors' working sets overlap and OOM (the round-3 finding),
-# so THIS driver opts into the per-coset barrier the overlapped prover no
-# longer applies by default (export =0 to experiment without it)
-os.environ.setdefault("BOOJUM_TPU_SYNC_SWEEPS", "1")
-
 # persist compiles (the 2^20 graphs take minutes each); importing bench
 # applies the package's compile-cache rule with persist-everything
 # thresholds as an import side effect

@@ -2,6 +2,8 @@
 vectors + satisfiability (reference test model: gadgets/keccak256/mod.rs:136).
 """
 
+import pytest
+
 from boojum_tpu.cs.implementations import ConstraintSystem
 from boojum_tpu.cs.types import CSGeometry, LookupParameters
 from boojum_tpu.gadgets import allocate_u8_input
@@ -34,7 +36,8 @@ def test_host_keccak_known_vectors():
 
 def build_keccak_circuit(data: bytes):
     cs = ConstraintSystem(GEOM, 1 << 18, lookup_params=LOOKUP)
-    inp = allocate_u8_input(cs, data)
+    # the range check rides the gadget's own xor8 table, two bytes a lookup
+    inp = allocate_u8_input(cs, data, range_check="xor8")
     digest = keccak256(cs, inp)
     return cs, digest
 
@@ -56,3 +59,23 @@ def test_keccak256_satisfiable():
     cs, digest = build_keccak_circuit(data)
     asm = cs.into_assembly()
     assert check_if_satisfied(asm, verbose=True)
+
+
+def test_input_range_check_uses_only_the_keccak_tables():
+    """With `range_check="xor8"` the circuit holds the gadget's nine tables
+    and none of SHA-256's: what a geometry with width-3 lookups needs."""
+    cs, _ = build_keccak_circuit(b"abc")
+    assert sorted(t.name for t in cs.lookup_tables) == sorted(
+        ["xor8", "and8"] + [f"byte_split_at{k}" for k in range(1, 8)]
+    )
+    assert max(t.width for t in cs.lookup_tables) == 3
+
+
+@pytest.mark.parametrize("range_check", ["xor8", "trixor4"])
+def test_input_allocation_refuses_a_value_that_is_no_byte(range_check):
+    cs = ConstraintSystem(GEOM, 1 << 18, lookup_params=LOOKUP)
+    # a lookup miss: KeyError from the Python resolver, RuntimeError from
+    # the native one; trixor4's recomposition fails as an AssertionError
+    with pytest.raises((KeyError, AssertionError, RuntimeError)):
+        allocate_u8_input(cs, [7, 256], range_check=range_check)
+        cs.into_assembly()
