@@ -91,6 +91,32 @@ def _next_pow2(x: int) -> int:
     return c
 
 
+def _round3_eval_plan(sds, groups, N: int, L: int, Q: int, smm):
+    """What round 3 dispatches for its committed groups, by the prover's
+    own rule (prover.coset_is_committed) on a stand-in for what the prove
+    will hold of each commitment: `groups` is (tag, B, streamed) and `sds`
+    makes the (B, N) shape struct of a materialized storage; a streamed
+    commit holds none, and the shard_map proves transform every group
+    (prover._prove_impl). Returns (picked, transformed): the storages
+    `_coset_eval_pick` reads together on the first min(L, Q) cosets, and
+    the (tag, B) that still run a transform on some coset (all of them
+    where Q > L, plus the shifted z)."""
+    from .prover import coset_is_committed
+
+    held = {
+        t: None if (streamed or smm is not None) else sds(B, N)
+        for t, B, streamed in groups
+    }
+    picked = tuple(
+        o for o in held.values() if coset_is_committed(0, L, Q, o)
+    )
+    transformed = [
+        (t, B) for t, B, _st in groups
+        if not all(coset_is_committed(c, L, Q, held[t]) for c in range(Q))
+    ]
+    return picked, transformed + [("zs", 2)]
+
+
 def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
     """The shape-keyed kernel library for a fused prove of `assembly`
     under `config` — meshless, or per-chip shard_map when a shard_map
@@ -319,9 +345,15 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
     )
     capA = _next_pow2(total_alpha_terms)
     add("zshift", P._zshift_fused, _sds(2, n), _sds())
-    for tag, B in (
-        ("wit", B_wit), ("setup", B_setup), ("s2", S), ("zs", 2)
-    ):
+    picked, transformed = _round3_eval_plan(
+        _sds,
+        (("wit", B_wit, stream), ("setup", B_setup, stream_setup),
+         ("s2", S, stream)),
+        N, L, Q, smm,
+    )
+    if picked:
+        add("coset_eval_pick", P._coset_eval_pick, picked, _i32(), n)
+    for tag, B in transformed:
         if smm is None:
             add(f"coset_eval_{tag}", P._coset_eval_q,
                 _sds(B, n), _sds(Q, n), _i32())
@@ -705,9 +737,15 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
     )
     capA = _next_pow2(total_alpha_terms)
     add("zshift_limbres", RES._zshift_p, _sdsp(2, n), _sdsp(n))
-    for tag, B in (
-        ("wit", B_wit), ("setup", B_setup), ("s2", S), ("zs", 2)
-    ):
+    picked, transformed = _round3_eval_plan(
+        _sdsp,
+        (("wit", B_wit, stream), ("setup", B_setup, stream_setup),
+         ("s2", S, stream)),
+        N, L, Q, smm,
+    )
+    if picked:
+        add("coset_eval_pick_limbres", P._coset_eval_pick, picked, _i32(), n)
+    for tag, B in transformed:
         if smm is None:
             for nm, fn, args in RES.coset_eval_kernel_specs(tag, B, n, Q):
                 add(nm, fn, *args)

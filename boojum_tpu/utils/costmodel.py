@@ -431,6 +431,12 @@ def kernel_cost(name: str, args, mesh_devices: int = 1) -> dict:
             "ici", {"flops": 0.0, "hbm_bytes": float(in_bytes * 2)},
             ici=float(in_bytes) * (D - 1),
         )
+    if base.startswith("coset_eval_pick"):
+        # round 3's read of the committed cosets: one coset of each
+        # (B, L*n) storage copied out, no arithmetic
+        # (the dominant operand's last axis, `n` here, is L*n rows)
+        picked = _arg_bytes(args[0]) * float(args[2]) / n
+        return fam("transfer", {"flops": 0.0, "hbm_bytes": 2.0 * picked})
     if base.startswith("coset_eval"):
         return fam("ntt", _acc(ntt_cost(B, n), {"flops": _flops(B * n, 0),
                                                 "hbm_bytes": 0.0}))
@@ -714,11 +720,18 @@ def stage_costs(
         _acc(r2, binv_cost((sb.lookup_subargs + 1) * n))
     _acc(r2, commit(float(sb.S)))
     stages["round2_stage2_commit"] = r2
-    # round 3: Q coset evals of every oracle + the fused sweep + interp
-    # + quotient commit (LDE only; monomials come from the interp)
+    # round 3: the coset evals + the fused sweep + interp + quotient
+    # commit (LDE only; monomials come from the interp). Meshless, the
+    # three committed groups are read from their commitments on the first
+    # min(L, Q) cosets (prover.coset_is_committed; a copy) and transformed
+    # on the rest; the shifted z transforms on all Q. The shard_map mesh
+    # transforms everything (a streamed commit too: not modeled here).
     r3 = {"flops": 0.0, "hbm_bytes": 0.0, "ici_bytes": 0.0}
-    evaled = float(sb.B_wit + sb.B_setup + sb.S + 2)
-    _acc(r3, ntt_cost(evaled, n), mult=Q)
+    committed = float(sb.B_wit + sb.B_setup + sb.S)
+    reused = min(L, Q) if D == 1 else 0.0
+    _acc(r3, ntt_cost(committed, n), mult=Q - reused)
+    _acc(r3, {"flops": 0.0, "hbm_bytes": 2 * committed * n * 8}, mult=reused)
+    _acc(r3, ntt_cost(2.0, n), mult=Q)
     _acc(r3, sweep_cost(Q * n, terms))
     # quotient interpolation (inverse-vandermonde, per-elem calibrated)
     _acc(r3, {"flops": 2 * Q * n * 350.0, "hbm_bytes": 2 * Q * n * 320.0})

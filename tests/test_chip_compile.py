@@ -198,3 +198,25 @@ def test_fused_limb_coset_sweep(one_chip, monkeypatch):
     args = jax.tree.map(place, spec.args)
     assert args[0][0].shape == (93, 1 << LOG_N)
     _compile(spec.fn, *args)
+
+
+@pytest.mark.parametrize("label,widths,log_n,L", [
+    ("sha256-lde8 2^16", (93, 105, 46), 16, 8),
+    ("keccak256-era 2^18", (155, 166, 62), 18, 2),
+])
+def test_round3_pick_of_the_committed_cosets(one_chip, label, widths, log_n, L):
+    """ISSUE 27: one coset of the three committed storages read in one
+    program, at the cells' widths: the chip's compiler takes the dynamic
+    slice along the row axis of the (B, L n) planes and writes exactly the
+    coset's bytes (no relayout of the storage, nothing held besides)."""
+    from boojum_tpu.prover import prover as P
+
+    n = 1 << log_n
+    oracles = tuple(_pair(one_chip, B, L * n) for B in widths)
+    c = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = P._coset_eval_pick.lower(oracles, c, n).compile()
+    mem = compiled.memory_analysis()
+    # the planes' (8, 128) tiling pads each group's columns to eights
+    tiled = sum(-(-B // 8) * 8 for B in widths) * n * 8
+    assert mem.output_size_in_bytes - tiled < 1 << 16, label
+    assert mem.temp_size_in_bytes == 0, label

@@ -322,3 +322,32 @@ def test_forward_ntt_above_2_16_refuses_to_be_traced_into_one_program():
         jax.jit(lambda q: LN._hybrid_fwd_p(q, 17, LN._LDE_FORWARD))(p)
     # off a TPU the MXU transform is not in use and nothing changes
     assert not LN.forward_is_two_programs(1 << 18)
+
+
+@pytest.mark.parametrize("resident", [False, True])
+def test_era_library_lists_the_pick_beside_the_transforms(
+    monkeypatch, small_assembly, resident
+):
+    """ISSUE 27: at LDE 2 under an 8-coset quotient, cosets 0 and 1 of
+    round 3 are read from the commitments and cosets 2-7 transformed: the
+    library lists the pick over the three committed storages AND the four
+    groups' transforms, and each of them lowers."""
+    from boojum_tpu.prover.precompile import enumerate_kernels
+    from boojum_tpu.prover.shape_key import shape_bucket
+
+    monkeypatch.setenv("BOOJUM_TPU_LIMB_RESIDENT", "1" if resident else "0")
+    sfx = "_limbres" if resident else ""
+    cfg = _proof_config()
+    specs = {s.name: s for s in enumerate_kernels(small_assembly, cfg)}
+    evals = sorted(n for n in specs if n.startswith("coset_eval_"))
+    assert evals == sorted(
+        f"coset_eval_{tag}{sfx}" for tag in ("pick", "wit", "setup", "s2", "zs")
+    ), evals
+    oracles, _c, n = specs[f"coset_eval_pick{sfx}"].args
+    widths = [(o[0] if resident else o).shape for o in oracles]
+    N = n * cfg.fri_lde_factor
+    sb = shape_bucket(small_assembly, cfg)
+    assert sb.B_wit == 155 and sb.S == 62
+    assert widths == [(sb.B_wit, N), (sb.B_setup, N), (sb.S, N)], widths
+    for name in evals:
+        specs[name].fn.lower(*specs[name].args)

@@ -10,6 +10,8 @@ per kernel with monotonic timestamps. The parity of the split pipelines
 with the graphs they replaced is tests/test_split_graph_parity.py.
 """
 
+import pytest
+
 from boojum_tpu.cs.implementations import ConstraintSystem
 from boojum_tpu.cs.types import CSGeometry, LookupParameters
 from boojum_tpu.prover import ProofConfig
@@ -63,6 +65,31 @@ def test_sha_geometry_enumeration_lowers_with_ledger():
     assert all(e["compile_s"] == 0.0 for e in ledger.entries)
     summary = ledger.summary()
     assert summary["num_kernels"] == len(specs)
+
+
+@pytest.mark.parametrize("resident", [False, True])
+def test_sha_geometry_enumerates_the_pick_not_the_transforms(
+    monkeypatch, resident
+):
+    """ISSUE 27: at LDE 8 under an 8-coset quotient every coset of round 3
+    is a committed one, so the witness, setup and stage-2 transforms are
+    never dispatched: the library lists the pick (which lowers) and the
+    shifted z's transform alone."""
+    from boojum_tpu.prover.precompile import enumerate_kernels
+
+    monkeypatch.setenv("BOOJUM_TPU_LIMB_RESIDENT", "1" if resident else "0")
+    sfx = "_limbres" if resident else ""
+    specs = {s.name: s for s in enumerate_kernels(_sha_assembly(), SHA_CONFIG)}
+    evals = sorted(n for n in specs if n.startswith("coset_eval_"))
+    assert evals == [f"coset_eval_pick{sfx}", f"coset_eval_zs{sfx}"], evals
+    pick = specs[f"coset_eval_pick{sfx}"]
+    oracles, _c, n = pick.args
+    N = n * SHA_CONFIG.fri_lde_factor
+    shapes = [
+        (o[0] if resident else o).shape for o in oracles
+    ]
+    assert len(shapes) == 3 and all(s[1] == N for s in shapes), shapes
+    assert "dynamic_slice" in pick.fn.lower(*pick.args).as_text()
 
 
 def test_limb_sweep_kernels_enumerate_and_lower(monkeypatch):

@@ -103,3 +103,30 @@ def test_streamed_lde_proof_byte_identical(monkeypatch):
     streamed = prove(asm, setup, cfg)
     assert streamed.to_json() == baseline.to_json()
     assert verify(setup.vk, streamed, asm.gates)
+
+
+@pytest.mark.parametrize("L", [2, 8])
+def test_committed_cosets_are_read_and_the_bytes_keep(L, monkeypatch):
+    """ISSUE 27: on the first min(L, Q) cosets round 3 reads the witness,
+    setup and stage-2 evaluations from the commitments (L < Q: the Era
+    split; L = Q: every coset). Same proof bytes and checkpoint stream as
+    with every evaluation transformed from the monomials, and the flight
+    recording counts which was which."""
+    from boojum_tpu.prover import prover as P
+    from proving import checkpoint_stream, prove_recorded
+
+    cfg = ProofConfig(fri_lde_factor=L, num_queries=10, fri_final_degree=8)
+    asm = _fma_circuit().into_assembly()
+    parts = (asm, generate_setup(asm, cfg), cfg)
+    Q = parts[1].vk.effective_quotient_degree()
+    assert Q == 8
+    proof, rep = prove_recorded("reuse", parts=parts)
+    counters = rep["metrics"]["counters"]
+    assert counters["quotient.coset_evals_reused"] == 3 * min(L, Q)
+    assert counters["ntt.coset_evals"] == 4 * Q - 3 * min(L, Q)
+    monkeypatch.setattr(P, "coset_is_committed", lambda *a: False)
+    plain, plain_rep = prove_recorded("transform", parts=parts)
+    assert plain_rep["metrics"]["counters"]["ntt.coset_evals"] == 4 * Q
+    assert plain.to_json() == proof.to_json()
+    assert checkpoint_stream(plain_rep) == checkpoint_stream(rep)
+    assert verify(parts[1].vk, proof, asm.gates)
