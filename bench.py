@@ -386,13 +386,9 @@ def _emit(status):
         # attribute any wall-clock delta to the limb-resident pipeline
         # (or its absence) straight from the line
         try:
-            from boojum_tpu.prover.pallas_sweep import (
-                limb_resident_enabled,
-                limb_sweep_enabled,
-            )
+            from boojum_tpu.utils.pallas_util import resolve_variant
 
-            out["limb_resident"] = bool(limb_resident_enabled())
-            out["limb_sweep"] = bool(limb_sweep_enabled())
+            out["limb_resident"] = resolve_variant().planes
         except Exception:
             pass
         # machine/software identity (ISSUE 12): the same block the AOT

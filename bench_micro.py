@@ -148,8 +148,9 @@ def sweep_section(backend):
     kernels at bench scale."""
     from boojum_tpu.cs.gates import FmaGate
     from boojum_tpu.cs.types import CSGeometry
+    from boojum_tpu.field import limbs
     from boojum_tpu.prover import pallas_sweep as ps
-    from boojum_tpu.prover.fri import _fold_once_jit
+    from boojum_tpu.prover.fri import _ch_table_np, _fold_once_jit
     from boojum_tpu.prover.stages import (
         _build_gate_sweep,
         _cp_quotient_core,
@@ -165,9 +166,13 @@ def sweep_section(backend):
     def rnd(*s):
         return jnp.asarray(rng.integers(0, gl.P, s, dtype=np.uint64))
 
-    def compare(name, u64_fn, limb_fn, args, elems):
+    def to_p(x):
+        """u64 arrays (nested as the u64 cores take them) -> plane pairs."""
+        return jax.tree.map(limbs.split, x)
+
+    def compare(name, u64_fn, limb_fn, args, limb_args, elems):
         dt_u64 = timed_call(jax.jit(u64_fn), args, reps)
-        dt_limb = timed_call(jax.jit(limb_fn), args, reps)
+        dt_limb = timed_call(jax.jit(limb_fn), limb_args, reps)
         emit(
             f"sweep_{name}_limb_elems_per_s",
             int(elems / dt_limb),
@@ -189,8 +194,9 @@ def sweep_section(backend):
     compare(
         "gate_terms",
         lambda c, k, x, y: u64_gate(c, None, k, x, y),
-        lambda c, k, x, y: limb_gate(c, None, k, x, y),
-        (copy, const, a0, a1), 8 * n,
+        lambda c, k, tb: limb_gate(c, None, k, tb),
+        (copy, const, a0, a1),
+        (to_p(copy), to_p(const), ps._pack_table(a0, a1)), 8 * n,
     )
 
     # copy-permutation quotient
@@ -208,7 +214,7 @@ def sweep_section(backend):
         "cp_quotient",
         lambda *a: _cp_quotient_core(*a, chunks, ks),
         lambda *a: ps.cp_quotient(*a, chunks, ks),
-        cp_args, C * n,
+        cp_args, to_p(cp_args[:7]) + cp_args[7:], C * n,
     )
 
     # lookup quotient (specialized, SHA-bench width)
@@ -223,7 +229,7 @@ def sweep_section(backend):
         "lookup_quotient",
         lambda *a: _lookup_quotient_core(*a, R, w),
         lambda *a: ps.lookup_quotient(*a, R, w),
-        lk_args, R * w * n,
+        lk_args, to_p(lk_args[:6]) + lk_args[6:], R * w * n,
     )
 
     # FRI fold
@@ -232,18 +238,20 @@ def sweep_section(backend):
     compare(
         "fri_fold",
         lambda v, ch, ix: _fold_once_jit(v, ch, ix),
-        lambda v, ch, ix: ps.fri_fold(v, ch, ix),
-        fold_args, m,
+        lambda v, tb, ix: ps.fri_fold_planes(v, tb, ix),
+        fold_args,
+        (to_p(fold_args[0]), jnp.asarray(_ch_table_np((3, 5))),
+         to_p(fold_args[2])),
+        m,
     )
 
 
 def resident_section(backend):
     """ISSUE 10 satellite: per-kernel boundary-CONVERTING vs limb-RESIDENT
     microbench — iNTT, LDE, leaf sponge, gate-terms sweep, FRI fold chain.
-    The converting leg is what each kernel paid before residency (u64 in /
-    u64 out: either emulated-u64 math or the limb kernel plus its
-    boundary split/join); the resident leg consumes and produces (lo, hi)
-    u32 planes end-to-end. Same JSON-line format as the PR 4 `sweep`
+    The converting leg is the u64 kernel (u64 in / u64 out: emulated-u64
+    math, for the commit kernels behind their boundary split/join); the
+    resident leg consumes and produces (lo, hi) u32 planes end-to-end. Same JSON-line format as the PR 4 `sweep`
     section. On non-TPU backends the Pallas legs run in interpret mode
     (correctness smoke more than a perf number)."""
     from boojum_tpu.field import limbs
@@ -306,10 +314,10 @@ def resident_section(backend):
         (leaves,), (leaves_p,), int(leaves.shape[0]) * 16,
     )
 
-    # gate-terms sweep (the fused limb kernel: boundary split/join vs
-    # plane-resident in/out; same in-kernel core)
+    # gate-terms sweep (the u64 XLA sweep vs the fused limb kernel)
     from boojum_tpu.cs.gates import FmaGate
     from boojum_tpu.cs.types import CSGeometry
+    from boojum_tpu.prover.stages import _build_gate_sweep
 
     geom = CSGeometry(8, 0, 6, 4)
     gates, paths = (FmaGate.instance(),), ((),)
@@ -318,17 +326,20 @@ def resident_section(backend):
     a0 = [int(v) for v in np.asarray(rnd(n_terms))]
     a1 = [int(v) for v in np.asarray(rnd(n_terms))]
     gate = ps.gate_terms_fn(gates, paths, geom)
+    gate_u64 = _build_gate_sweep(gates, paths, geom)
     table = jnp.asarray(RES.sc_table_np(a0, a1))
     compare(
         "gate_terms",
-        lambda c, k: gate(c, None, k, jnp.asarray(np.array(a0, np.uint64)),
-                          jnp.asarray(np.array(a1, np.uint64))),
-        lambda c, k: gate.planes(c, None, k, table),
+        lambda c, k: gate_u64(
+            c, None, k, jnp.asarray(np.array(a0, np.uint64)),
+            jnp.asarray(np.array(a1, np.uint64)),
+        ),
+        lambda c, k: gate(c, None, k, table),
         (copy, const), (limbs.split(copy), limbs.split(const)), 8 * n,
     )
 
-    # FRI fold chain (k=3): the converting chain pays a split+join per
-    # fold; the resident chain stays planes across all three
+    # FRI fold chain (k=3): the u64 chain vs the chain that stays planes
+    # across all three folds
     m = 2 * n
     log_m = m.bit_length() - 1
     c0, c1 = rnd(m), rnd(m)
@@ -340,7 +351,7 @@ def resident_section(backend):
     c0p, c1p = limbs.split(c0), limbs.split(c1)
     compare(
         "fri_fold_k3",
-        lambda a, b: _fri_fold_fn(3, True, None)(a, b, ch01, tabs_u),
+        lambda a, b: _fri_fold_fn(3, None)(a, b, ch01, tabs_u),
         lambda a, b: _fri_fold_fn_p(3, None)(a, b, tb, tabs_p),
         (c0, c1), (c0p, c1p), m,
     )
@@ -447,7 +458,7 @@ def field_section(backend):
     table = jnp.asarray(RES.sc_table_np(a0, a1))
     compare(
         "gate_terms",
-        lambda c, k: gate.planes(c, None, k, table), (copy_p, const_p),
+        lambda c, k: gate(c, None, k, table), (copy_p, const_p),
         lambda w, al, cp, lt, zh, bi: K.coset_sweep_terms_bb(
             w, al, cp, lt, zh, bi, Lf
         ),
@@ -563,8 +574,7 @@ def mesh_section(backend):
 
     with prover_mesh(mesh):
         dt_g = timed_call(jax.jit(_lde_leaf), (mono_g,))
-    use_limb = SS.leaf_limb_ok(B, N // SS.mesh_devices(mesh))
-    lde_fn = SS._lde_pivot_leaf_fn(mesh, L, B, use_limb)
+    lde_fn = SS._lde_pivot_leaf_fn(mesh, L, B)
     dt_s = timed_call(lde_fn, (mono_p,))
     emit_pair("leaf_sponge", dt_g, dt_s, N * B)
 
@@ -579,7 +589,7 @@ def mesh_section(backend):
     c1g = jax.device_put(c1, col_sh)
     with prover_mesh(mesh):
         dt_g = timed_call(
-            _fri_fold_fn(3, False, None), (c0g, c1g, ch01, tabs)
+            _fri_fold_fn(3, None), (c0g, c1g, ch01, tabs)
         )
     if SS.fold_shards_ok(m, 3, mesh):
         # both sides fold the same pre-sharded c0g/c1g; only the fold
@@ -587,7 +597,7 @@ def mesh_section(backend):
         # sharded, the meshless graph above took them from host)
         tabs_s = tuple(jax.device_put(t, col_sh) for t in tabs)
         dt_s = timed_call(
-            _fri_fold_fn(3, False, mesh), (c0g, c1g, ch01, tabs_s)
+            _fri_fold_fn(3, mesh), (c0g, c1g, ch01, tabs_s)
         )
         emit_pair("fri_fold_k3", dt_g, dt_s, m)
 

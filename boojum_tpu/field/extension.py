@@ -74,6 +74,23 @@ def batch_inverse(a):
     return (gf.mul(a[0], dinv), gf.neg(gf.mul(a[1], dinv)))
 
 
+@jax.jit
+def prefix_product(a):
+    """Inclusive ext prefix product along the last axis (log-doubling; same
+    rationale as gf.prefix_product — associative_scan's graph explodes XLA
+    compile time for wide combine fns)."""
+    n = a[0].shape[-1]
+    shift = 1
+    while shift < n:
+        shifted = (
+            jnp.concatenate([jnp.ones((shift,), jnp.uint64), a[0][:-shift]]),
+            jnp.concatenate([jnp.zeros((shift,), jnp.uint64), a[1][:-shift]]),
+        )
+        a = mul(a, shifted)
+        shift *= 2
+    return a
+
+
 def pow_const(a, e: int):
     result = None
     base = a

@@ -16,8 +16,8 @@ same phase structure as `poseidon2.py`). The internal matrix's diagonal is
 powers of two: its product is a static shift per row and one short reduction
 (`limbs.mul_pow2`), no field multiply and no table row.
 
-Used by `poseidon2.py:poseidon2_permutation` when running on TPU (env
-BOOJUM_TPU_PALLAS=0 disables); bit-parity with the XLA path is asserted in
+Used by `poseidon2.py:poseidon2_permutation` when running on TPU
+(`pallas_util.force_xla()` pins the XLA twin); bit-parity with the XLA path is asserted in
 tests/test_pallas_kernels.py (interpret mode on CPU + real kernels on TPU).
 """
 

@@ -90,12 +90,9 @@ def _mxu_ntt_ready(n: int, ctx) -> bool:
 
     Default-ON on TPU (the kernel moves the multiply work onto the systolic
     array and beats the staged-XLA emulated-u64 path; parity is exact);
-    BOOJUM_TPU_MXU_NTT=0 opts out."""
+    `pallas_util.force_xla()` asks for the XLA reference."""
     from ..utils.pallas_util import pallas_enabled
-    from ..utils.transfer import env_flag
 
-    if not env_flag("BOOJUM_TPU_MXU_NTT", True):
-        return False
     if not pallas_enabled():
         return False
     from . import mxu_ntt

@@ -65,10 +65,6 @@ def mesh_from_shape(shape) -> Mesh:
     return Mesh(grid, axis_names=_AXES)
 
 
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 # ---------------------------------------------------------------------------
 # ICI accounting — the explicit collectives' byte/time bill
 # ---------------------------------------------------------------------------
@@ -171,22 +167,8 @@ def _mono_fn(mesh: Mesh):
     )
 
 
-def leaf_limb_ok(width: int, rows_local: int) -> bool:
-    """Whether the fused Poseidon2 limb sponge can take a local
-    (rows_local, width) leaf block: 128-lane row tiling and the kernel's
-    VMEM width cap (hashes/poseidon2.leaf_hash mirrors the cap)."""
-    from ..prover.pallas_sweep import limb_sweep_enabled
-
-    return (
-        limb_sweep_enabled()
-        and rows_local % 128 == 0
-        and rows_local > 0
-        and width <= 1024
-    )
-
-
 @lru_cache(maxsize=None)
-def _lde_pivot_leaf_fn(mesh: Mesh, L: int, B_real: int, use_limb: bool):
+def _lde_pivot_leaf_fn(mesh: Mesh, L: int, B_real: int):
     """Rate-L LDE of the local monomial stripe, the explicit col->row
     all_to_all pivot, and the per-chip leaf sponge — one shard_map graph.
 
@@ -195,8 +177,6 @@ def _lde_pivot_leaf_fn(mesh: Mesh, L: int, B_real: int, use_limb: bool):
     BEFORE the sponge (absorption sees exactly the committed columns)."""
     from ..hashes.poseidon2 import leaf_hash_xla
     from ..ntt import lde_from_monomial
-
-    interp = _interp()
 
     def body(mono_blk):
         b = mono_blk.shape[0]
@@ -209,13 +189,7 @@ def _lde_pivot_leaf_fn(mesh: Mesh, L: int, B_real: int, use_limb: bool):
             flat, _AXES, split_axis=1, concat_axis=0, tiled=True
         )
         leaves = piv.T[:, :B_real]  # (N/D, B): rows of real columns
-        if use_limb:
-            from ..hashes import pallas_poseidon2 as pp2
-
-            dig = pp2.sponge_hash(leaves, interpret=interp)
-        else:
-            dig = leaf_hash_xla(leaves)
-        return lde, dig
+        return lde, leaf_hash_xla(leaves)
 
     return jax.jit(
         shard_map(
@@ -347,16 +321,12 @@ def commit_from_mono_sm(mono, L: int, cap_size: int, mesh: Mesh):
     Returns (lde (B, L, n), layers) — same contract as the meshless
     lde_from_monomial + commit_layers_device pair, bit-identical values."""
     B, n = int(mono.shape[0]), int(mono.shape[-1])
-    D = mesh_devices(mesh)
     N = n * L
-    use_limb = leaf_limb_ok(B, N // D)
     mono_p = pad_cols_sharded(mono, mesh)
-    fn = _lde_pivot_leaf_fn(mesh, L, B, use_limb)
+    fn = _lde_pivot_leaf_fn(mesh, L, B)
     with _pivot_timer():
         lde_p, digests = fn(mono_p)
     _ici_all_to_all(int(mono_p.shape[0]) * N * 8, mesh)
-    if use_limb:
-        _metrics.count("merkle.limb_leaf_sponges")
     _metrics.count("merkle.sm_commits")
     lde = lde_p[:B] if lde_p.shape[0] != B else lde_p
     return lde, node_layers_sm(digests, cap_size, mesh)

@@ -18,7 +18,6 @@ Merkle caps, transcript inputs and FRI final polys are tiny and replicated.
 from __future__ import annotations
 
 import logging
-import os
 from functools import partial
 
 import jax
@@ -44,38 +43,17 @@ def active_mesh() -> Mesh | None:
     return _ACTIVE_MESH[0]
 
 
-def mesh_mode() -> str | None:
-    """How the active mesh executes: None (no mesh), "shard_map" (each chip
-    runs the native kernels on its local shard, collectives written
-    explicitly — parallel/shard_sweep.py), or "gspmd" (the legacy implicit
-    path: NamedSharding constraints, XLA inserts the collectives).
+def shard_map_mesh(variant=None) -> Mesh | None:
+    """The active mesh when it executes via shard_map (each chip runs the
+    native kernels on its local shard, collectives written explicitly:
+    parallel/shard_sweep.py), else None. `variant` is the prove's resolved
+    record (utils/pallas_util.resolve_variant); without one the resolver
+    is asked."""
+    if variant is None:
+        from ..utils.pallas_util import resolve_variant
 
-    BOOJUM_TPU_MESH_MODE=shard_map|gspmd forces a mode. Unset defaults to
-    shard_map on EVERY topology, including multi-process (DCN-spanning)
-    meshes under jax.distributed: the explicit collectives ride the same
-    all_gather/all_to_all primitives across hosts, the de-mesh fallbacks
-    are addressable-safe (shard_sweep.demesh gathers non-addressable
-    arrays per host), and the cross-host byte bill lands in the dcn.*
-    gauges. gspmd remains the forced legacy escape hatch."""
-    m = active_mesh()
-    if m is None:
-        return None
-    v = os.environ.get("BOOJUM_TPU_MESH_MODE", "").strip().lower()
-    if v in ("shard_map", "sm"):
-        return "shard_map"
-    if v == "gspmd":
-        return "gspmd"
-    if v:
-        raise ValueError(
-            f"BOOJUM_TPU_MESH_MODE={v!r}: use shard_map or gspmd"
-        )
-    return "shard_map"
-
-
-def shard_map_mesh() -> Mesh | None:
-    """The active mesh when it executes via shard_map, else None — the
-    single dispatch predicate the prover/fri/streaming kernels key on."""
-    return active_mesh() if mesh_mode() == "shard_map" else None
+        variant = resolve_variant()
+    return active_mesh() if variant.mesh == "shard_map" else None
 
 
 class prover_mesh:
@@ -246,10 +224,8 @@ def _num_den_products(copy_vals, sigma_vals, non_residues, beta, gamma):
 
 def _z_from_ratio(ratio):
     """Exclusive prefix product of the per-row ratio (shared log-doubling
-    scan — see prover.stages._ext_prefix_prod)."""
-    from ..prover.stages import _ext_prefix_prod
-
-    incl = _ext_prefix_prod(ratio)
+    scan, field/extension.prefix_product)."""
+    incl = ext_f.prefix_product(ratio)
     one = jnp.ones((1,), jnp.uint64)
     zero = jnp.zeros((1,), jnp.uint64)
     return (

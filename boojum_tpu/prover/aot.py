@@ -153,27 +153,24 @@ def _mesh_shape_list(mesh_shape) -> list | None:
 
 
 def variant_fingerprint(mesh_shape=None) -> dict:
-    """The env-flag variant that decides WHICH kernel set
-    `precompile.enumerate_kernels` derives — resolved the same way the
-    enumeration resolves it, so build and load can never disagree by
-    parsing flags differently."""
-    from ..field.spec import active_field
-    from ..utils import transfer as _transfer
-    from .pallas_sweep import limb_resident_enabled, limb_sweep_enabled
+    """The variant that decides WHICH kernel set
+    `precompile.enumerate_kernels` derives: the resolved KernelVariant's
+    own dict (utils/pallas_util.resolve_variant — the record the
+    enumeration and the prove read, so build and load can never disagree)
+    plus the mesh shape and the streamed-commit threshold."""
+    from ..utils.pallas_util import resolve_variant
     from .streaming import stream_threshold_bytes
 
     thresh = stream_threshold_bytes()
+    variant = (
+        resolve_variant() if mesh_shape is None
+        else resolve_variant(mesh_shape)
+    )
     return {
-        # the field backend selects a DISJOINT kernel set (`_bb` names,
-        # ISSUE 19) — a goldilocks bundle must never satisfy a babybear
-        # load or vice versa
-        "field": active_field(),
-        "overlap": bool(_transfer.overlap_enabled()),
-        "limb_sweep": bool(limb_sweep_enabled()),
-        # the resident variant is a DISJOINT kernel set (`*_limbres`
-        # ledger names); it must never share a bundle with the
-        # converting set
-        "limb_resident": bool(limb_resident_enabled()),
+        # field, representation and mesh mode each select a DISJOINT
+        # kernel set (`_bb`, `*_limbres`, `_sm` names): a bundle of one
+        # must never satisfy a load of another
+        **variant.as_dict(),
         "mesh_shape": _mesh_shape_list(mesh_shape),
         # inf is not JSON — the "streaming forced off" sentinel string is
         "stream_lde_bytes": (
@@ -938,19 +935,6 @@ def load_and_warm(
 # ---------------------------------------------------------------------------
 
 
-def _would_shard_map(mesh) -> bool:
-    """Whether `prove(mesh=...)` will execute via shard_map — replicated
-    from parallel.sharding.mesh_mode WITHOUT needing the mesh active.
-    shard_map is the default on every topology (including multi-process
-    jax.distributed meshes); gspmd only when forced by env."""
-    if mesh is None:
-        return False
-    v = os.environ.get("BOOJUM_TPU_MESH_MODE", "").strip().lower()
-    if v == "gspmd":
-        return False
-    return True
-
-
 _PROVE_ATTEMPTED: set[tuple] = set()
 
 
@@ -986,9 +970,11 @@ def maybe_load_for_prove(assembly, config, mesh=None) -> dict | None:
     root = aot_dir()
     if root is None:
         return None
-    if mesh is not None and not _would_shard_map(mesh):
-        # the legacy GSPMD path partitions its own sequenced graphs —
-        # not the enumerated kernel set a bundle holds; nothing to load
+    from ..utils.pallas_util import resolve_variant
+
+    if not resolve_variant(mesh).fused:
+        # the GSPMD path partitions its own sequenced graphs — not the
+        # enumerated kernel set a bundle holds; nothing to load
         return None
     mesh_shape = _mesh_shape_list(mesh) if mesh is not None else None
     if _attempt_key(root, assembly, config, mesh_shape) in _PROVE_ATTEMPTED:

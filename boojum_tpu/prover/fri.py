@@ -72,15 +72,6 @@ def _fold_once_jit(values, ch, inv_x_pairs):
     return (gf.mul(t[0], inv2), gf.mul(t[1], inv2))
 
 
-@jax.jit
-def _fold_once_limb_jit(values, ch, inv_x_pairs):
-    """The limb-domain fold kernel (pallas_sweep.fri_fold) under its own
-    top-level jit — the unfused path's counterpart of _fold_once_jit."""
-    from .pallas_sweep import fri_fold
-
-    return fri_fold(values, ch, inv_x_pairs)
-
-
 @lru_cache(maxsize=4)
 def fold_challenge_tables_p(log_full: int, num_rounds: int):
     """Limb-resident twin of fold_challenge_tables: per-round 1/x PLANE
@@ -123,13 +114,8 @@ def fold_once(values, challenge, inv_x_pairs):
     """values: ext pair over round-r domain (brev layout); returns N/2 ext.
 
     f'(x^2) = (f(x)+f(-x))/2 + ch·(f(x)-f(-x))/(2x). Jitted core with the
-    challenge as an array argument (new challenges never retrace). With the
-    limb sweep on (BOOJUM_TPU_LIMB_SWEEP, prover/pallas_sweep.py) the
-    butterfly runs on u32 limb planes — bit-identical output."""
-    from .pallas_sweep import limb_sweep_enabled
-
-    fn = _fold_once_limb_jit if limb_sweep_enabled() else _fold_once_jit
-    return fn(values, ext_scalar(challenge), inv_x_pairs)
+    challenge as an array argument (new challenges never retrace)."""
+    return _fold_once_jit(values, ext_scalar(challenge), inv_x_pairs)
 
 
 def commit_codeword(
@@ -196,21 +182,13 @@ def _fri_commit_fn(k: int, cap: int):
 
 
 @lru_cache(maxsize=None)
-def _fri_fold_fn(k: int, limb: bool = False, mesh=None):
+def _fri_fold_fn(k: int, mesh=None):
     """Fused k-fold for one schedule entry (sub-challenges by squaring).
-    With `limb`, each fold runs the u32-limb Pallas kernel
-    (pallas_sweep.fri_fold) instead of the emulated-u64 butterfly —
-    bit-identical outputs, so the two variants share nothing but math.
     With `mesh` (a shard_map mesh, parallel/shard_sweep.py) the whole
     k-fold chain runs per chip on row shards of the bit-reversed codeword:
     fold pairs are adjacent, so as long as every intermediate local size
     stays even (fri_prove guards divisibility) no fold ever communicates
     — the only collective in FRI is the cap gather at commit time."""
-
-    if limb:
-        from .pallas_sweep import fri_fold as fold
-    else:
-        fold = _fold_once_jit
 
     if mesh is not None:
         from jax.experimental.shard_map import shard_map
@@ -222,7 +200,7 @@ def _fri_fold_fn(k: int, limb: bool = False, mesh=None):
             cur = (c0, c1)
             sub = (ch01[0], ch01[1])
             for j in range(k):
-                cur = fold(cur, sub, tabs[j])
+                cur = _fold_once_jit(cur, sub, tabs[j])
                 sub = ext_f.mul(sub, sub)
             return cur
 
@@ -243,7 +221,7 @@ def _fri_fold_fn(k: int, limb: bool = False, mesh=None):
         cur = (c0, c1)
         sub = (ch01[0], ch01[1])
         for j in range(k):
-            cur = fold(cur, sub, tables[j])
+            cur = _fold_once_jit(cur, sub, tables[j])
             sub = ext_f.mul(sub, sub)
         return cur
 
@@ -341,15 +319,17 @@ def _fri_final_p(c0, c1, shift_inv: int):
     return m0, m1
 
 
-def fri_kernel_specs(base_degree: int, config, mesh=None) -> list:
+def fri_kernel_specs(
+    base_degree: int, config, planes: bool, smm=None
+) -> list:
     """(name, jitted_fn, args) triples for every top-level executable a
-    fused `fri_prove` dispatches for this (base_degree, config) — the
+    fused `fri_prove` dispatches for this (base_degree, config) in the
+    given representation (`planes`: KernelVariant.planes) and shard_map
+    mesh (`smm`, None = per-chip graphs not wanted) — the
     per-schedule-entry commit and fold graphs plus the final
     interpolation — so prover/precompile.py can compile them concurrently
     before the first prove. Mirrors the schedule/shape walk of fri_prove;
     args are ShapeDtypeStructs (no device memory)."""
-
-    from .pallas_sweep import limb_resident_enabled, limb_sweep_enabled
 
     def sds(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.uint64)
@@ -369,22 +349,15 @@ def fri_kernel_specs(base_degree: int, config, mesh=None) -> list:
     cur = N
     fold_round = 0
     cap = config.merkle_tree_cap_size
-    # enumerate the fold variant this process will actually dispatch (the
-    # overlap-mode idiom in prover/precompile.py) — compiling the other
-    # would be minutes of pure waste. Under a shard_map mesh
-    # that is the per-chip fold chain, ledger-tagged `_sm`; under limb
-    # residency the PLANE chain, ledger-tagged `_limbres`.
-    from ..parallel.sharding import shard_map_mesh
+    # only the fold variant the prove dispatches: under a shard_map mesh
+    # the per-chip fold chain, ledger-tagged `_sm`; on planes the PLANE
+    # chain, ledger-tagged `_limbres`
     from ..parallel.shard_sweep import fold_shards_ok
 
-    limb = limb_sweep_enabled()
-    resident = limb_resident_enabled()
-    smm = mesh if mesh is not None else shard_map_mesh()
-    fold_tag = "_limbres" if resident else ("_limb" if limb else "")
     for k in schedule:
         mesh_k = smm if smm is not None and fold_shards_ok(cur, k, smm) \
             else None
-        if resident:
+        if planes:
             ext_p = (sdsp(cur), sdsp(cur))
             if mesh_k is not None:
                 from ..parallel.shard_sweep import _fri_leaf_fn_p
@@ -404,7 +377,7 @@ def fri_kernel_specs(base_degree: int, config, mesh=None) -> list:
                 sdsp(1 << (log_full - fold_round - j - 1)) for j in range(k)
             )
             specs.append((
-                f"fri_fold{fold_tag}_k{k}_n{cur}"
+                f"fri_fold_limbres_k{k}_n{cur}"
                 + ("_sm" if mesh_k is not None else ""),
                 _fri_fold_fn_p(k, mesh_k),
                 ext_p + (jax.ShapeDtypeStruct((4, 1), jnp.uint32), tables),
@@ -430,15 +403,15 @@ def fri_kernel_specs(base_degree: int, config, mesh=None) -> list:
             sds(1 << (log_full - fold_round - j - 1)) for j in range(k)
         )
         specs.append((
-            f"fri_fold{fold_tag}_k{k}_n{cur}"
+            f"fri_fold_k{k}_n{cur}"
             + ("_sm" if mesh_k is not None else ""),
-            _fri_fold_fn(k, limb, mesh_k),
+            _fri_fold_fn(k, mesh_k),
             (sds(cur), sds(cur), sds(2), tables),
         ))
         fold_round += k
         cur >>= k
     shift_inv = gl.inv(gl.pow_(gl.MULTIPLICATIVE_GENERATOR, 1 << num_folds))
-    if resident:
+    if planes:
         specs.append((
             f"fri_final_limbres_n{cur}", _fri_final_p,
             (sdsp(cur), sdsp(cur), shift_inv),
@@ -452,20 +425,20 @@ def fri_kernel_specs(base_degree: int, config, mesh=None) -> list:
 
 
 def fri_prove(
-    codeword, transcript, config, base_degree: int, fused: bool = False
+    codeword, transcript, config, base_degree: int, variant
 ) -> FriOracles:
-    """codeword: ext pair over full LDE domain (brev layout).
+    """codeword: ext pair over full LDE domain (brev layout). `variant` is
+    the prove's resolved KernelVariant (utils/pallas_util.py).
 
     Protocol per schedule entry k: commit the current codeword with 2^k
     points per leaf -> absorb cap -> draw ONE challenge -> fold k times with
     challenges ch, ch^2, ch^4, ... -> next entry. Then interpolate the final
-    monomials and absorb them. With `fused`, each entry is two dispatches
-    (commit graph, then fold graph — the challenge only exists after the
-    cap is absorbed).
+    monomials and absorb them. On the fused rounds each entry is two
+    dispatches (commit graph, then fold graph — the challenge only exists
+    after the cap is absorbed).
     """
-    from .pallas_sweep import limb_sweep_enabled
-
     out = FriOracles()
+    fused = variant.fused
     # a resident codeword arrives as an ext PLANE pair ((lo,hi),(lo,hi))
     # straight from the DEEP accumulation (ISSUE 10) and stays planes
     # through every commit and fold; only the final monomials (and caps,
@@ -485,11 +458,10 @@ def fri_prove(
         tables = fold_challenge_tables_p(log_full, num_folds)
     else:
         tables = fold_challenge_tables(log_full, num_folds)
-    limb = limb_sweep_enabled()
     from ..parallel.sharding import shard_map_mesh
     from ..parallel.shard_sweep import fold_shards_ok
 
-    smm = shard_map_mesh()
+    smm = shard_map_mesh(variant)
     if smm is not None and len(_arr0.devices()) <= 1:
         # streamed proves de-mesh their round-5 inputs (the DEEP sources
         # regenerate blocks inside plain jits), so the codeword arrives
@@ -500,7 +472,7 @@ def fri_prove(
     cur = codeword
     fold_round = 0
     for r, k in enumerate(schedule):
-        with _span(f"fri_oracle_{r}", k=k, limb=limb, resident=resident):
+        with _span(f"fri_oracle_{r}", k=k, resident=resident):
             # per-chip commit + fold chain while every intermediate local
             # size stays even; deep tails are pulled onto one device and
             # take the meshless graphs (the arrays are small there, and a
@@ -559,8 +531,6 @@ def fri_prove(
             _checkpoint(5, f"fri_challenge_{r}", ch)
             out.challenges.append(ch)
             _metrics.count("fri.folds", k)
-            if limb:
-                _metrics.count("fri.limb_folds", k)
             if resident:
                 _metrics.count("fri.resident_folds", k)
                 if mesh_k is not None:
@@ -575,7 +545,7 @@ def fri_prove(
                 ch01 = jnp.asarray(np.array([ch[0], ch[1]], dtype=np.uint64))
                 if mesh_k is not None:
                     _metrics.count("fri.sm_folds", k)
-                cur = _fri_fold_fn(k, limb, mesh_k)(
+                cur = _fri_fold_fn(k, mesh_k)(
                     cur[0], cur[1], ch01,
                     tuple(tables[fold_round : fold_round + k]),
                 )

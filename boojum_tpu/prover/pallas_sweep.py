@@ -1,40 +1,41 @@
 """Limb-domain quotient sweep + FRI fold as fused Pallas TPU kernels.
 
-ISSUE 4 tentpole. The quotient-stage cores (`stages._build_gate_sweep`,
-`_cp_quotient_core`, `_lookup_quotient_core` / `_lookup_quotient_core_general`)
-and the FRI fold (`fri._fold_once_jit`) historically computed in
-`field/goldilocks.py`'s XLA-emulated uint64 — the representation Mosaic
-rejects and XLA cannot fuse across kernel boundaries. This module evaluates
-the SAME math on `(lo, hi)` uint32 limb pairs (`field/limbs.py` +
-`field/limb_ops.py`), tiled over VMEM column blocks:
+The quotient-stage cores (`stages._build_gate_sweep`, `_cp_quotient_core`,
+`_lookup_quotient_core` / `_lookup_quotient_core_general`) and the FRI fold
+(`fri._fold_once_jit`) compute in `field/goldilocks.py`'s XLA-emulated
+uint64 — the representation Mosaic rejects and XLA cannot fuse across
+kernel boundaries. This module evaluates the SAME math on `(lo, hi)` uint32
+limb planes (`field/limbs.py` + `field/limb_ops.py`), tiled over VMEM
+column blocks:
 
 - `build_coset_terms(...)`: ONE fused kernel per assembly structure that
   evaluates, per quotient-coset block, the gate-terms contribution, the
   copy-permutation terms, the lookup terms and the 1/Z_H multiply — the
-  limb counterpart of `prover._coset_sweep_fn`'s body. Trace columns and
-  challenges are array arguments (new challenges never retrace); challenge
-  scalars and alpha/γ-power tables ride SMEM; packed gate programs replay
-  from SMEM op tables under `fori_loop` (constant graph size).
-- `fri_fold(...)`: one fold round f'(x^2) = (f(x)+f(-x))/2 + ch·(f(x)-f(-x))/(2x)
-  on deinterleaved even/odd limb planes.
+  plane counterpart of `prover._u64_sweep_core`. Trace columns and the
+  challenge table are array arguments (new challenges never retrace);
+  challenge scalars and alpha/γ-power tables ride SMEM; packed gate
+  programs replay from SMEM op tables under `fori_loop` (constant graph
+  size).
+- `fri_fold_planes(...)`: one fold round f'(x^2) = (f(x)+f(-x))/2 +
+  ch·(f(x)-f(-x))/(2x) on deinterleaved even/odd limb planes.
 - standalone `cp_quotient` / `lookup_quotient` / `lookup_quotient_general`
   / `gate_terms_fn` wrappers over the same in-kernel cores, for per-kernel
   parity tests and `bench_micro.py`'s u64-vs-limb sweep section.
 
-Layout: a `(B, n)` uint64 column stack becomes two `(B, R, 128)` uint32
-planes (R = n/128); the grid walks R in sublane tiles, so every field op is
-an elementwise VPU op over `(B, T, 128)` tiles resident in VMEM. u64↔limb
-conversion happens ONLY at these call boundaries — field ops are exact
-mod p and keep values canonical, so outputs (and therefore digests,
+Layout: a `(B, n)` column stack is two `(B, R, 128)` uint32 planes
+(R = n/128); the grid walks R in sublane tiles, so every field op is an
+elementwise VPU op over `(B, T, 128)` tiles resident in VMEM. Planes in,
+planes out: nothing here converts from or to uint64 (the plane pipeline,
+prover/resident.py, keeps planes from the witness upload to the query
+joins; the tests split and join around a kernel). Field ops are exact mod
+p and keep values canonical, so outputs (and therefore digests,
 checkpoints and proof bytes) are bit-identical to the u64 path
-(`BOOJUM_TPU_LIMB_SWEEP=0` restores it; tests/test_limb_sweep.py pins
-parity per kernel and end-to-end).
+(tests/test_limb_sweep.py pins parity per kernel).
 
-Dispatch: default ON where the kernels are native (TPU backend, no active
-prover mesh — pallas_call cannot partition under a NamedSharding); on other
-backends `BOOJUM_TPU_LIMB_SWEEP=1` opts in via interpret mode (how the CPU
-tier-1 parity tests run). Shapes whose domain is not a multiple of 128
-lanes (deep FRI fold tails) run the same limb cores as plain XLA ops.
+Which representation a prove runs is `utils/pallas_util.resolve_variant`'s
+decision; off the TPU the kernels run in interpret mode. Shapes whose
+domain is not a multiple of 128 lanes (deep FRI fold tails) run the same
+limb cores as plain XLA ops.
 """
 
 from __future__ import annotations
@@ -53,12 +54,7 @@ from ..field import gl
 from ..field import limb_ops as lop
 from ..field import limbs
 from ..utils import metrics as _metrics
-from ..utils.pallas_util import (
-    _FORCE_XLA,
-    imap32,
-    pick_tile,
-    tpu_compiler_params,
-)
+from ..utils.pallas_util import imap32, pick_tile, tpu_compiler_params
 
 _LANE = 128
 _INV2_PAIR = limbs.const_pair((gl.P + 1) // 2)
@@ -68,77 +64,6 @@ _INV2_PAIR = limbs.const_pair((gl.P + 1) // 2)
 _CP = tpu_compiler_params(128 * 1024 * 1024)
 
 
-def limb_sweep_enabled() -> bool:
-    """True when the limb-domain sweep kernels should be dispatched.
-
-    Default ON where they are native: TPU backend, no GSPMD-mode prover
-    mesh, no BOOJUM_TPU_LIMB_SWEEP opt-out / force_xla override. Under an
-    active mesh the answer depends on HOW the mesh executes
-    (parallel/sharding.mesh_mode): the shard_map path hands each chip its
-    local block, so pallas_call never sees a sharded operand and the limb
-    kernels stay on; the legacy GSPMD path cannot partition a pallas_call
-    and keeps them off. On non-TPU backends the kernels run in interpret
-    mode and are OPT-IN (truthy BOOJUM_TPU_LIMB_SWEEP) — the u64 path
-    stays the CPU default so tier-1 wall-clock is unchanged. The knob
-    parses through transfer.env_flag_opt's spelling set (0/false/off/no,
-    1/true/on/yes; junk raises — a typo must never silently pick a
-    mode)."""
-    from ..utils.transfer import env_flag_opt
-
-    backend = jax.default_backend()
-    # the backend-dependent default makes the knob tri-state: unset means
-    # "native backends only"
-    explicit = env_flag_opt("BOOJUM_TPU_LIMB_SWEEP")
-    if explicit is False:
-        return False
-    if _FORCE_XLA[0]:
-        return False
-    from ..parallel.sharding import active_mesh, mesh_mode
-
-    if active_mesh() is not None and mesh_mode() != "shard_map":
-        return False
-    if backend == "tpu":
-        return True
-    # an explicit limb-RESIDENT opt-in implies the limb kernels: the
-    # resident pipeline has no u64 kernel set to fall back to
-    return explicit is True or env_flag_opt("BOOJUM_TPU_LIMB_RESIDENT") is True
-
-
-def limb_resident_enabled() -> bool:
-    """True when (lo, hi) u32 limb planes are the CANONICAL on-device
-    representation for the whole prove (ISSUE 10): witness columns enter
-    as planes at H2D, stay planes through iNTT/LDE, sponges, the quotient
-    sweep, DEEP and FRI, and `limbs.join` survives only at the API edge
-    (transcript absorbs, query openings, proof serialization).
-
-    BOOJUM_TPU_LIMB_RESIDENT: default ON where the limb sweep is native
-    (TPU backend — meshless or shard_map); `=0` restores the u64-resident
-    path bit-for-bit; `=1` opts in elsewhere (CPU runs the same plane
-    pipeline with interpret-mode/XLA limb kernels — how the tier-1 parity
-    tests run). Residency requires the limb kernel family, so every
-    limb_sweep_enabled() veto (GSPMD mesh, force_xla, LIMB_SWEEP=0)
-    also disables it.
-
-    BOOJUM_TPU_FIELD=babybear vetoes residency unconditionally (ISSUE
-    19): the (lo, hi) planes ARE the Goldilocks 64-bit representation —
-    a 31-bit BabyBear element is one bare u32 lane with no planes to be
-    resident in, and the dispatcher routes to the disjoint `_bb` kernel
-    set instead (prover/bb_kernels.py)."""
-    from ..field.spec import is_babybear
-    from ..utils.transfer import env_flag_opt
-
-    if is_babybear():
-        return False
-    explicit = env_flag_opt("BOOJUM_TPU_LIMB_RESIDENT")
-    if explicit is False:
-        return False
-    if not limb_sweep_enabled():
-        return False
-    if explicit is True:
-        return True
-    return jax.default_backend() == "tpu"
-
-
 def _interpret() -> bool:
     # interpret mode because the backend IS the CPU, never because the
     # backend failed to start: that error propagates
@@ -146,7 +71,7 @@ def _interpret() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Generic tiled dispatch: u64 stacks in, u64 ext columns out
+# Generic tiled dispatch: plane stacks in, ext plane columns out
 # ---------------------------------------------------------------------------
 
 
@@ -174,62 +99,52 @@ def _sc_ext(tb, j, like):
     )
 
 
-def _in_planes(x, shape):
-    """An input stack as reshaped planes: a (lo, hi) plane pair passes
-    through (the resident path — NO conversion), a u64 array splits at
-    this call boundary (the converting path)."""
-    if isinstance(x, tuple):
-        return x[0].reshape(shape), x[1].reshape(shape)
-    return limbs.split(x.reshape(shape))
+def _stack_p(rows):
+    """Base plane pairs of one shape -> one stacked (B, ...) plane pair."""
+    return (
+        jnp.stack([r[0] for r in rows]), jnp.stack([r[1] for r in rows])
+    )
 
 
-def _in_rows(x) -> int:
-    return int((x[0] if isinstance(x, tuple) else x).shape[0])
-
-
-def _in_n(x) -> int:
-    return int((x[0] if isinstance(x, tuple) else x).shape[-1])
+def _row1(p):
+    """An (n,) plane pair as a one-row (1, n) stack."""
+    return p[0][None], p[1][None]
 
 
 def _tiled_ext_call(
-    body, ins, table, extra_tables=(), num_ext_out=1, interpret=None,
-    planes_out=False,
+    body, ins, table, extra_tables=(), num_ext_out=1, interpret=None
 ):
     """Run `body` over limb planes of the column stacks `ins`.
 
-    ins: list of (B_i, n) uint64 arrays OR (lo, hi) u32 plane pairs (the
-    limb-resident path — plane inputs enter the kernel with no conversion
-    at all). table: (4, S) uint32 scalar table (SMEM). extra_tables: int32
-    2-D tables (SMEM; packed gate programs). body(table, tables, pairs)
-    receives pairs[i] = (lo, hi) uint32 arrays of block shape (B_i, T, 128)
-    and returns `num_ext_out` ext limb elements of shape (T, 128). Returns
-    that many (c0, c1) uint64 (n,) pairs — or, with `planes_out`, ext limb
-    pairs ((lo, hi), (lo, hi)) of (n,) planes (resident callers keep the
-    output resident; `limbs.join` never runs).
+    ins: list of (lo, hi) u32 plane pairs of shape (B_i, n). table: (4, S)
+    uint32 scalar table (SMEM). extra_tables: int32 2-D tables (SMEM;
+    packed gate programs). body(table, tables, pairs) receives pairs[i] =
+    (lo, hi) uint32 arrays of block shape (B_i, T, 128) and returns
+    `num_ext_out` ext limb elements of shape (T, 128). Returns that many
+    ext limb pairs ((lo, hi), (lo, hi)) of (n,) planes.
 
     Domains that don't tile (n % 128 != 0) run `body` directly on
     (B_i, 1, n) planes — same code, plain XLA."""
-    n = _in_n(ins[0])
+    n = int(ins[0][0].shape[-1])
     if interpret is None:
         interpret = _interpret()
     extra_tables = tuple(jnp.asarray(t) for t in extra_tables)
+
+    def _planes(x, shape):
+        return x[0].reshape(shape), x[1].reshape(shape)
+
     if n % _LANE != 0:
-        pairs = [_in_planes(x, (_in_rows(x), 1, n)) for x in ins]
+        pairs = [_planes(x, (int(x[0].shape[0]), 1, n)) for x in ins]
         outs = body(table, extra_tables, pairs)
-        if planes_out:
-            return tuple(
-                (
-                    (c0[0].reshape(n), c0[1].reshape(n)),
-                    (c1[0].reshape(n), c1[1].reshape(n)),
-                )
-                for (c0, c1) in outs
-            )
         return tuple(
-            (limbs.join(c0).reshape(n), limbs.join(c1).reshape(n))
+            (
+                (c0[0].reshape(n), c0[1].reshape(n)),
+                (c1[0].reshape(n), c1[1].reshape(n)),
+            )
             for (c0, c1) in outs
         )
     R = n // _LANE
-    total_rows = sum(_in_rows(x) for x in ins) + 2 * num_ext_out
+    total_rows = sum(int(x[0].shape[0]) for x in ins) + 2 * num_ext_out
     budget_rows = max(8, (4 << 20) // max(total_rows * _LANE * 8, 1))
     tile = pick_tile(R, budget_rows)
     grid = (R // tile,)
@@ -245,8 +160,8 @@ def _tiled_ext_call(
         in_specs.append(_smem_spec(t))
         args.append(t)
     for x in ins:
-        B = _in_rows(x)
-        lo, hi = _in_planes(x, (B, R, _LANE))
+        B = int(x[0].shape[0])
+        lo, hi = _planes(x, (B, R, _LANE))
         spec = pl.BlockSpec(
             (B, tile, _LANE),
             imap32(lambda r: (0, r, 0)),
@@ -287,23 +202,13 @@ def _tiled_ext_call(
         interpret=interpret,
         compiler_params=None if interpret else _CP,
     )(*args)
-    outs = []
-    for k in range(num_ext_out):
-        if planes_out:
-            outs.append(
-                (
-                    (planes[4 * k].reshape(n), planes[4 * k + 1].reshape(n)),
-                    (
-                        planes[4 * k + 2].reshape(n),
-                        planes[4 * k + 3].reshape(n),
-                    ),
-                )
-            )
-            continue
-        c0 = limbs.join((planes[4 * k], planes[4 * k + 1])).reshape(n)
-        c1 = limbs.join((planes[4 * k + 2], planes[4 * k + 3])).reshape(n)
-        outs.append((c0, c1))
-    return tuple(outs)
+    return tuple(
+        (
+            (planes[4 * k].reshape(n), planes[4 * k + 1].reshape(n)),
+            (planes[4 * k + 2].reshape(n), planes[4 * k + 3].reshape(n)),
+        )
+        for k in range(num_ext_out)
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -532,9 +437,9 @@ def build_coset_terms(gates, selector_paths, geometry, lk_ctx, non_residues):
     copy-permutation terms + lookup terms + 1/Z_H, per quotient-coset
     block. Alpha-power consumption order matches the u64 body exactly
     (gates, then cp, then lookups) — same per-TERM challenge sequence the
-    verifier replays. Returns call(wit_v, setup_v, s2_v, zs_v, xs_sl,
-    l0_sl, zhinv_sl, ap0, ap1, beta01, gamma01, lkb01, lkg01) -> (t0, t1)
-    uint64 arrays, traceable inside the outer per-coset jit."""
+    verifier replays. Returns call(wit_p, setup_p, s2_p, zs_p, xs_p, l0_p,
+    zh_p, table) -> (t0, t1) plane pairs, traceable inside the outer
+    per-coset jit."""
     from .stages import gate_sweep_plan
 
     (
@@ -604,64 +509,28 @@ def build_coset_terms(gates, selector_paths, geometry, lk_ctx, non_residues):
             acc = lop.ext_add(acc, lk)
         return ((limbs.mul(acc[0], zh), limbs.mul(acc[1], zh)),)
 
-    def call(
-        wit_v, setup_v, s2_v, zs_v, xs_sl, l0_sl, zhinv_sl,
-        ap0, ap1, beta01, gamma01, lkb01, lkg01,
-    ):
-        A = int(ap0.shape[0])
-        cols0 = [ap0, beta01[:1], gamma01[:1], lkb01[:1], lkg01[:1]]
-        cols1 = [ap1, beta01[1:], gamma01[1:], lkb01[1:], lkg01[1:]]
-        if lookups:
-            from .stages import _ext_powers_traced
-
-            gpow = _ext_powers_traced((lkg01[0], lkg01[1]), width + 1)
-            cols0.append(jnp.stack([p[0] for p in gpow]))
-            cols1.append(jnp.stack([p[1] for p in gpow]))
-            # beta' rides right after the γ powers (see _lookup_terms)
-            cols0.append(lkb01[:1])
-            cols1.append(lkb01[1:])
-        table = _pack_table(
-            jnp.concatenate(cols0), jnp.concatenate(cols1)
-        )
-        (out,) = _tiled_ext_call(
-            partial(body, A=A),
-            [
-                wit_v, setup_v, s2_v, zs_v,
-                xs_sl[None], l0_sl[None], zhinv_sl[None],
-            ],
-            table,
-            extra_tables=tabs_static,
-        )
-        return out
-
-    # scalar-table column count past the alpha block (call's layout):
-    # [beta, gamma, lkb, lkg] + with lookups [gpow(width+1), beta']
+    # scalar-table columns past the alpha block: [beta, gamma, lkb, lkg]
+    # and, with lookups, [γ'^0..γ'^width, beta'] (beta' rides right after
+    # the γ powers, see _lookup_terms)
     _extra_cols = 4 + ((width + 2) if lookups else 0)
 
-    def call_planes(
-        wit_p, setup_p, s2_p, zs_p, xs_p, l0_p, zh_p, table
-    ):
-        """The RESIDENT entry (ISSUE 10): every oracle stack arrives as a
-        (lo, hi) u32 plane pair and the terms come back as an ext plane
-        pair — no u64 exists anywhere in the round. `table` is the (4, S)
-        u32 scalar table prebuilt on HOST from the transcript challenges
-        (prover/resident.py builds it in `call`'s exact column layout)."""
+    def call(wit_p, setup_p, s2_p, zs_p, xs_p, l0_p, zh_p, table):
+        """Every oracle stack arrives as a (lo, hi) u32 plane pair and the
+        terms come back as an ext plane pair. `table` is the (4, S) u32
+        scalar table built on HOST from the transcript challenges
+        (resident.sweep_table_np, in the column layout above)."""
         A = int(table.shape[1]) - _extra_cols
         (out,) = _tiled_ext_call(
             partial(body, A=A),
             [
                 wit_p, setup_p, s2_p, zs_p,
-                (xs_p[0][None], xs_p[1][None]),
-                (l0_p[0][None], l0_p[1][None]),
-                (zh_p[0][None], zh_p[1][None]),
+                _row1(xs_p), _row1(l0_p), _row1(zh_p),
             ],
             table,
             extra_tables=tabs_static,
-            planes_out=True,
         )
         return out
 
-    call.planes = call_planes
     return call
 
 
@@ -671,16 +540,17 @@ def build_coset_terms(gates, selector_paths, geometry, lk_ctx, non_residues):
 
 
 def cp_quotient(
-    z_lde, z_shift_lde, partial_ldes, copy_lde, sigma_lde, xs_lde, l0_lde,
+    z_p, z_shift_p, partials_p, copy_p, sigma_p, xs_p, l0_p,
     b, g, a0, a1, chunks, non_residues, interpret=None,
 ):
-    """Limb twin of stages._cp_quotient_core (same args, uint64 in/out)."""
-    num_partials = len(partial_ldes)
-    s2_rows = [z_lde[0], z_lde[1]]
-    for p in partial_ldes:
+    """Plane twin of stages._cp_quotient_core: the same arguments with
+    every column (or (C, n) column stack) a (lo, hi) plane pair; ext
+    columns are pairs of those. `b`, `g`, `a0`, `a1` stay uint64 scalars
+    (the small challenge table is packed here)."""
+    num_partials = len(partials_p)
+    s2_rows = [z_p[0], z_p[1]]
+    for p in partials_p:
         s2_rows += [p[0], p[1]]
-    s2_stack = jnp.stack(s2_rows)
-    zs_stack = jnp.stack([z_shift_lde[0], z_shift_lde[1]])
     A = int(a0.shape[0])
     bc0, bc1 = _ext_scalar_cols(b)
     gc0, gc1 = _ext_scalar_cols(g)
@@ -691,11 +561,11 @@ def cp_quotient(
     non_residues = tuple(int(k) for k in non_residues)
 
     def body(tb, _tabs, pairs):
-        s2_p, zs_p, copy_p, sigma_p, xs_p, l0_p = pairs
-        like = xs_p[0][0]
+        s2_p, zs_p, copy_pp, sigma_pp, xs_pp, l0_pp = pairs
+        like = xs_pp[0][0]
         acc = _cp_terms(
-            tb, like, s2_p, zs_p, copy_p, sigma_p,
-            _row(xs_p, 0), _row(l0_p, 0),
+            tb, like, s2_p, zs_p, copy_pp, sigma_pp,
+            _row(xs_pp, 0), _row(l0_pp, 0),
             a_col=0, beta_col=A, gamma_col=A + 1,
             chunks=chunks, non_residues=non_residues,
             num_partials=num_partials,
@@ -704,7 +574,10 @@ def cp_quotient(
 
     (out,) = _tiled_ext_call(
         body,
-        [s2_stack, zs_stack, copy_lde, sigma_lde, xs_lde[None], l0_lde[None]],
+        [
+            _stack_p(s2_rows), _stack_p([z_shift_p[0], z_shift_p[1]]),
+            copy_p, sigma_p, _row1(xs_p), _row1(l0_p),
+        ],
         table,
         interpret=interpret,
     )
@@ -712,15 +585,13 @@ def cp_quotient(
 
 
 def _lookup_quotient_shared(
-    a_ldes, b_lde, cols_lde, tid_lde, table_ldes, mult_lde, sel_lde,
+    a_ps, b_p, cols_p, tid_p, table_p, mult_p, sel_p,
     b, g, a0, a1, num_subargs, width, general, interpret,
 ):
     s2_rows = []
-    for a in a_ldes:
+    for a in a_ps:
         s2_rows += [a[0], a[1]]
-    s2_rows += [b_lde[0], b_lde[1]]
-    s2_stack = jnp.stack(s2_rows)
-    gpow = None
+    s2_rows += [b_p[0], b_p[1]]
     from .stages import _ext_powers_traced
 
     gpow = _ext_powers_traced(g, width + 1)
@@ -730,21 +601,21 @@ def _lookup_quotient_shared(
         jnp.concatenate([a0] + [jnp.reshape(p[0], (1,)) for p in gpow] + [bc0]),
         jnp.concatenate([a1] + [jnp.reshape(p[1], (1,)) for p in gpow] + [bc1]),
     )
-    ins = [s2_stack, cols_lde, tid_lde[None], table_ldes, mult_lde[None]]
+    ins = [_stack_p(s2_rows), cols_p, _row1(tid_p), table_p, _row1(mult_p)]
     if general:
-        ins.append(sel_lde[None])
+        ins.append(_row1(sel_p))
 
     def body(tb, _tabs, pairs):
         if general:
-            s2_p, cols_p, tid_p, table_p, mult_p, sel_p = pairs
-            sel = _row(sel_p, 0)
+            s2_pp, cols_pp, tid_pp, table_pp, mult_pp, sel_pp = pairs
+            sel = _row(sel_pp, 0)
         else:
-            s2_p, cols_p, tid_p, table_p, mult_p = pairs
+            s2_pp, cols_pp, tid_pp, table_pp, mult_pp = pairs
             sel = None
-        like = tid_p[0][0]
+        like = tid_pp[0][0]
         acc = _lookup_terms(
-            tb, like, s2_p, cols_p, _row(tid_p, 0), table_p,
-            _row(mult_p, 0), sel,
+            tb, like, s2_pp, cols_pp, _row(tid_pp, 0), table_pp,
+            _row(mult_pp, 0), sel,
             a_col=0, gpow_col=A, ab_off=0,
             num_subargs=num_subargs, width=width, general=general,
         )
@@ -755,32 +626,34 @@ def _lookup_quotient_shared(
 
 
 def lookup_quotient(
-    a_ldes, b_lde, lookup_lde_cols, table_id_lde, table_ldes, mult_lde,
+    a_ps, b_p, lookup_cols_p, table_id_p, table_p, mult_p,
     b, g, a0, a1, num_repetitions, width, interpret=None,
 ):
-    """Limb twin of stages._lookup_quotient_core."""
+    """Plane twin of stages._lookup_quotient_core (columns as plane
+    pairs, challenges as uint64 scalars)."""
     return _lookup_quotient_shared(
-        a_ldes, b_lde, lookup_lde_cols, table_id_lde, table_ldes, mult_lde,
+        a_ps, b_p, lookup_cols_p, table_id_p, table_p, mult_p,
         None, b, g, a0, a1, int(num_repetitions), int(width),
         general=False, interpret=interpret,
     )
 
 
 def lookup_quotient_general(
-    a_ldes, b_lde, gen_lde_cols, tid_lde, table_ldes, mult_lde, sel_lde,
+    a_ps, b_p, gen_cols_p, tid_p, table_p, mult_p, sel_p,
     b, g, a0, a1, num_subargs, width, interpret=None,
 ):
-    """Limb twin of stages._lookup_quotient_core_general."""
+    """Plane twin of stages._lookup_quotient_core_general."""
     return _lookup_quotient_shared(
-        a_ldes, b_lde, gen_lde_cols, tid_lde, table_ldes, mult_lde,
-        sel_lde, b, g, a0, a1, int(num_subargs), int(width),
+        a_ps, b_p, gen_cols_p, tid_p, table_p, mult_p,
+        sel_p, b, g, a0, a1, int(num_subargs), int(width),
         general=True, interpret=interpret,
     )
 
 
 def gate_terms_fn(gates, selector_paths, geometry, interpret=None):
-    """Limb twin of stages._build_gate_sweep: returns fn(copy_lde_flat,
-    wit_lde_flat, const_lde_flat, a0, a1) -> ext pair."""
+    """Plane twin of stages._build_gate_sweep: returns fn(copy_p, wit_p,
+    const_p, table) -> ext plane pair over plane stacks and a prebuilt
+    (4, S) u32 alpha table (`_pack_table(a0, a1)`)."""
     from .stages import gate_sweep_plan
 
     plan = gate_sweep_plan(
@@ -788,33 +661,7 @@ def gate_terms_fn(gates, selector_paths, geometry, interpret=None):
     )
     tabs_static = _packed_tables(plan)
 
-    def fn(copy_lde_flat, wit_lde_flat, const_lde_flat, a0, a1):
-        table = _pack_table(a0, a1)
-        ins = [copy_lde_flat]
-        has_wit = wit_lde_flat is not None
-        if has_wit:
-            ins.append(wit_lde_flat)
-        ins.append(const_lde_flat)
-
-        def body(tb, tabs, pairs):
-            if has_wit:
-                copy_p, wit_p, const_p = pairs
-            else:
-                copy_p, const_p = pairs
-                wit_p = None
-            like = copy_p[0][0]
-            acc, _t = _gate_terms(
-                tb, tabs, like, copy_p, wit_p, const_p, plan, a_col=0
-            )
-            return (acc,)
-
-        (out,) = _tiled_ext_call(
-            body, ins, table, extra_tables=tabs_static, interpret=interpret
-        )
-        return out
-
-    def fn_planes(copy_p, wit_p, const_p, table):
-        """Resident entry: plane stacks + a prebuilt (4, S) u32 table."""
+    def fn(copy_p, wit_p, const_p, table):
         ins = [copy_p]
         has_wit = wit_p is not None
         if has_wit:
@@ -834,12 +681,10 @@ def gate_terms_fn(gates, selector_paths, geometry, interpret=None):
             return (acc,)
 
         (out,) = _tiled_ext_call(
-            body, ins, table, extra_tables=tabs_static,
-            interpret=interpret, planes_out=True,
+            body, ins, table, extra_tables=tabs_static, interpret=interpret
         )
         return out
 
-    fn.planes = fn_planes
     return fn
 
 
@@ -867,43 +712,20 @@ def _fold_body(tb, _tabs, pairs):
     )
 
 
-def fri_fold(values, ch, inv_x_pairs, interpret=None):
-    """Limb twin of fri._fold_once_jit: one fold round over the
-    bit-reversed codeword (pairs adjacent). `values` is an ext pair over
-    the round domain, `ch` an ext pair of uint64 scalars; returns the
-    half-size ext pair. The even/odd deinterleave happens outside the
-    kernel (one strided XLA slice) so the kernel body is fully
-    elementwise."""
-    quad = jnp.stack(
-        [
-            values[0][0::2], values[1][0::2],
-            values[0][1::2], values[1][1::2],
-        ]
-    )
-    c0, c1 = _ext_scalar_cols(ch)
-    table = _pack_table(c0, c1)
-    (out,) = _tiled_ext_call(
-        _fold_body, [quad, inv_x_pairs[None]], table, interpret=interpret
-    )
-    return out
-
-
 def fri_fold_planes(values_p, table, inv_x_p, interpret=None):
-    """Resident FRI fold (ISSUE 10): `values_p` is an ext plane pair over
-    the round domain, `table` the (4, 1) u32 challenge table, `inv_x_p` the
-    1/x plane pair at pair positions. Returns the half-size ext plane pair
-    — the fold CHAIN stays resident across rounds, where the converting
-    `fri_fold` paid a split+join per fold."""
+    """Plane twin of fri._fold_once_jit: one fold round over the
+    bit-reversed codeword (pairs adjacent). `values_p` is an ext plane pair
+    over the round domain, `table` the (4, 1) u32 challenge table,
+    `inv_x_p` the 1/x plane pair at pair positions. Returns the half-size
+    ext plane pair, so a fold CHAIN stays on planes across rounds. The
+    even/odd deinterleave happens outside the kernel (one strided XLA
+    slice) so the kernel body is fully elementwise."""
     c0p, c1p = values_p
     quad = (
         jnp.stack([c0p[0][0::2], c1p[0][0::2], c0p[0][1::2], c1p[0][1::2]]),
         jnp.stack([c0p[1][0::2], c1p[1][0::2], c0p[1][1::2], c1p[1][1::2]]),
     )
     (out,) = _tiled_ext_call(
-        _fold_body,
-        [quad, (inv_x_p[0][None], inv_x_p[1][None])],
-        table,
-        interpret=interpret,
-        planes_out=True,
+        _fold_body, [quad, _row1(inv_x_p)], table, interpret=interpret
     )
     return out

@@ -124,9 +124,10 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
 
     `mesh_shape`: a ('col','row') device-count pair like (2, 4), or an
     already-built Mesh — enumerates the `_sm` kernel variants (per-chip
-    iNTT/LDE + pivot + leaf sponge, coset_sweep_terms[_limb]_sm,
-    fri_fold[_limb]_k*_sm) for that mesh without one being active. Only
-    the variant this process will dispatch is enumerated, so the compile
+    iNTT/LDE + pivot + leaf sponge, coset_sweep_terms[_limbres]_sm,
+    fri_fold[_limbres]_k*_sm) for that mesh without one being active.
+    Only the variant a prove on that mesh will dispatch
+    (utils/pallas_util.resolve_variant) is enumerated, so the compile
     ledger records exactly the dispatched set.
 
     Derivations mirror prover._prove_impl / setup.generate_setup; only
@@ -151,38 +152,31 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
     from .streaming import (
         COL_BLOCK,
         _absorb_cols,
-        _absorb_lde_block,
         _lde_block_cols,
         use_streamed_lde,
     )
     from . import prover as P
     from ..parallel import shard_sweep as SS
-    from ..parallel.sharding import shard_map_mesh
+    from ..parallel.sharding import active_mesh
     from ..utils import transfer as _transfer
+    from ..utils.pallas_util import resolve_variant
 
     if mesh_shape is None:
-        smm = shard_map_mesh()
+        mesh = active_mesh()
     elif isinstance(mesh_shape, (tuple, list)):
-        smm = SS.mesh_from_shape(mesh_shape)
+        mesh = SS.mesh_from_shape(mesh_shape)
     else:
-        smm = mesh_shape  # an already-built Mesh
+        mesh = mesh_shape  # an already-built Mesh
+    # the same record a prove on `mesh` resolves (it also keys
+    # prover/aot.py's bundles): three DISJOINT kernel sets — the
+    # plane-free `_bb` set of the BabyBear field (prover/bb_kernels.py),
+    # the plane set (`*_limbres` ledger names) and the u64 set below
+    variant = resolve_variant(mesh)
+    smm = mesh if variant.mesh == "shard_map" else None
     D = SS.mesh_devices(smm) if smm is not None else 1
-
-    # field backend (ISSUE 19): BOOJUM_TPU_FIELD=babybear dispatches the
-    # plane-free `_bb` kernel set (prover/bb_kernels.py) — a third
-    # DISJOINT variant beside u64 and limb-resident, selected before
-    # either (the field also rides prover/aot.py's variant fingerprint)
-    from ..field.spec import is_babybear
-
-    if is_babybear():
+    if variant.field == "babybear":
         return _enumerate_babybear(assembly, config)
-
-    # limb residency (ISSUE 10): the resident prove dispatches a DISJOINT
-    # plane-kernel set (`*_limbres` ledger names) — enumerate exactly that
-    # set, never both (the variant also rides prover/aot.py's bundle key)
-    from .pallas_sweep import limb_resident_enabled
-
-    if limb_resident_enabled():
+    if variant.planes:
         return _enumerate_resident(assembly, config, smm, D)
 
     # ONE derivation of every shape-keyed quantity, shared with the
@@ -253,10 +247,9 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
             for i in range(0, B, COL_BLOCK):
                 absorb_blocks.add(min(COL_BLOCK, B - i))
         else:
-            use_limb = SS.leaf_limb_ok(B, N // D)
             add(
                 f"{tag}:lde_pivot_leaf_sm",
-                SS._lde_pivot_leaf_fn(smm, L, B, use_limb), _sds(Bp, n),
+                SS._lde_pivot_leaf_fn(smm, L, B), _sds(Bp, n),
             )
 
     commit_specs("wit", B_wit, stream)
@@ -265,12 +258,8 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
     # _quotient_interp rather than monomial_from_values — no imono kernel
     commit_specs("q", B_q, False, mono=False)
     commit_specs("setup", B_setup, stream_setup)
-    # streamed-commit kernels follow the dispatch mode this process will
-    # actually use: the double-buffered split pair with BOOJUM_TPU_OVERLAP
-    # on (the default), the fused block graph with it off — compiling the
-    # other mode's variant would be minutes of pure waste. The shard_map streamed commit always absorbs through the
-    # split _absorb_cols (streaming.double_buffered_absorb).
-    overlap = _transfer.overlap_enabled()
+    # streamed commits are double-buffered: the block LDE (on the mesh
+    # with its pivot) and the absorb are separate dispatches
     for b in sorted(absorb_blocks):
         if smm is not None:
             add(
@@ -278,15 +267,9 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
                 SS._lde_pivot_cols_fn(smm, L, b),
                 _sds(SS.padded_cols(b, D), n),
             )
-            add(f"absorb_cols_b{b}", _absorb_cols, _sds(N, 12), _sds(N, b))
-        elif overlap:
-            add(f"lde_block_cols_b{b}", _lde_block_cols, _sds(b, n), L)
-            add(f"absorb_cols_b{b}", _absorb_cols, _sds(N, 12), _sds(N, b))
         else:
-            add(
-                f"absorb_lde_block_b{b}",
-                _absorb_lde_block, _sds(N, 12), _sds(b, n), L,
-            )
+            add(f"lde_block_cols_b{b}", _lde_block_cols, _sds(b, n), L)
+        add(f"absorb_cols_b{b}", _absorb_cols, _sds(N, 12), _sds(N, b))
     if smm is None:
         add("node_layers", node_layers_device, _sds(N, 4), cap)
     else:
@@ -301,16 +284,15 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
                 "node_gather_sm", SS._all_gather_fn(smm, 2), _sds(gather, 4)
             )
 
-    if overlap:
-        # the chunked witness upload's on-device concatenate
-        wit_groups = [Cg] + ([LC] if LC else []) + ([W] if W else []) \
-            + ([1] if M else [])
-        upload_parts = _transfer.upload_chunk_shapes(wit_groups, n)
-        if len(upload_parts) > 1:
-            add(
-                "witness_upload_concat", _transfer._concat_jit(),
-                *[_sds(b, n) for b in upload_parts],
-            )
+    # the chunked witness upload's on-device concatenate
+    wit_groups = [Cg] + ([LC] if LC else []) + ([W] if W else []) \
+        + ([1] if M else [])
+    upload_parts = _transfer.upload_chunk_shapes(wit_groups, n)
+    if len(upload_parts) > 1:
+        add(
+            "witness_upload_concat", _transfer._concat_jit(),
+            *[_sds(b, n) for b in upload_parts],
+        )
 
     # ---- round 2: chunk products, inversions, prefix product, stack ------
     sc = (_sds(), _sds())
@@ -368,21 +350,11 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
         total_alpha_terms, Cg, Ct, W, K, M,
         tuple(mk_path) if mk_path is not None else None,
     )
-    # the sweep factory dispatches the representation this process will
-    # actually use (u64 XLA body vs the fused u32-limb Pallas kernel —
-    # BOOJUM_TPU_LIMB_SWEEP); the ledger name carries the variant so a
-    # compile-bill regression is attributable to the right kernel
-    from .pallas_sweep import limb_sweep_enabled
-
     sweep = P._coset_sweep_fn(
-        assembly, selector_paths, non_residues, lk_ctx, sm_mesh=smm
+        assembly, selector_paths, non_residues, lk_ctx, False, smm
     )
-    sweep_name = (
-        "coset_sweep_terms_limb" if limb_sweep_enabled()
-        else "coset_sweep_terms"
-    ) + ("_sm" if smm is not None else "")
     add(
-        sweep_name, sweep,
+        "coset_sweep_terms" + ("_sm" if smm is not None else ""), sweep,
         _sds(B_wit, n), _sds(B_setup, n), _sds(S, n), _sds(2, n), _i32(),
         _sds(Q * n), _sds(Q * n), _sds(Q * n), _sds(capA), _sds(capA),
         _sds(2), _sds(2), _sds(2), _sds(2),
@@ -458,7 +430,7 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
             pair(2), pair(num_lk), _sds(num_pi), _sds(2 + num_lk + num_pi),
             _sds(2 + num_lk + num_pi),
         )
-    for nm, fn, args in fri_kernel_specs(n, config, mesh=smm):
+    for nm, fn, args in fri_kernel_specs(n, config, False, smm):
         add(nm, fn, *args)
 
     # ---- cached domain tables (built once per geometry, but their batch
@@ -661,8 +633,6 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
                 _sdsp(SS.padded_cols(b, D), n),
             )
         else:
-            # the resident streamed commit dispatches the split pair in
-            # BOTH overlap modes (streaming.streamed_leaf_digests_blocks_p)
             add(
                 f"lde_block_cols_limbres_b{b}", _lde_block_cols_p,
                 _sdsp(b, n), L,
@@ -682,15 +652,14 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
                 "node_gather_limbres_sm", SS._all_gather_fn(smm, 2),
                 _u32(gather, 4),
             )
-    if _transfer.overlap_enabled():
-        wit_groups = [Cg] + ([LC] if LC else []) + ([W] if W else []) \
-            + ([1] if M else [])
-        upload_parts = _transfer.upload_chunk_shapes(wit_groups, n)
-        if len(upload_parts) > 1:
-            add(
-                "witness_upload_concat_limbres", _transfer._concat_jit(),
-                *[_u32(b, n) for b in upload_parts],
-            )
+    wit_groups = [Cg] + ([LC] if LC else []) + ([W] if W else []) \
+        + ([1] if M else [])
+    upload_parts = _transfer.upload_chunk_shapes(wit_groups, n)
+    if len(upload_parts) > 1:
+        add(
+            "witness_upload_concat_limbres", _transfer._concat_jit(),
+            *[_u32(b, n) for b in upload_parts],
+        )
 
     # ---- round 2 plane twins ---------------------------------------------
     chunks_t = tuple(tuple(c) for c in chunks)
@@ -763,7 +732,7 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
         tuple(mk_path) if mk_path is not None else None,
     )
     sweep = P._coset_sweep_fn(
-        assembly, selector_paths, non_residues, lk_ctx, sm_mesh=smm
+        assembly, selector_paths, non_residues, lk_ctx, True, smm
     )
     S_cols = capA + 4 + ((width + 2) if lookups else 0)
     add(
@@ -839,7 +808,7 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
             pairp(2), pairp(num_lk), _sdsp(num_pi),
             _sdsp(2 + num_lk + num_pi), _sdsp(2 + num_lk + num_pi),
         )
-    for nm, fn, args in fri_kernel_specs(n, config, mesh=smm):
+    for nm, fn, args in fri_kernel_specs(n, config, True, smm):
         add(nm, fn, *args)
 
     # ---- cached plane domain tables' inversions --------------------------

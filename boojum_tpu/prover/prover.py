@@ -338,7 +338,7 @@ def _dev_cached(obj, name: str, build):
     return cache[name]
 
 
-def _commit_pipeline(values, L: int, cap: int, stream: bool):
+def _commit_pipeline(values, L: int, cap: int, stream: bool, sm_mesh=None):
     """values over H (B, n) -> (mono, lde | None, tree layers).
 
     (Flight recorder: one `commit_pipeline` span per oracle, NTT/Merkle
@@ -357,16 +357,14 @@ def _commit_pipeline(values, L: int, cap: int, stream: bool):
     absorbed per column block (streaming.streamed_leaf_digests_blocks),
     one reusable (COL_BLOCK, n) graph for every block of every oracle.
 
-    Under a shard_map mesh the whole pipeline delegates to
+    Under a shard_map mesh (`sm_mesh`) the whole pipeline delegates to
     parallel/shard_sweep.commit_pipeline_sm: per-chip iNTT/LDE, the
-    explicit all_to_all layout pivot, per-chip leaf sponges (the fused
-    limb kernel where native) and an explicit cap all_gather — same
-    return contract, bit-identical digests."""
+    explicit all_to_all layout pivot, per-chip leaf sponges and an
+    explicit cap all_gather — same return contract, bit-identical
+    digests."""
     from ..merkle import commit_layers_device, node_layers_device
-    from ..parallel.sharding import shard_map_mesh
     from .streaming import streamed_leaf_digests_blocks
 
-    sm_mesh = shard_map_mesh()
     if sm_mesh is not None:
         from ..parallel.shard_sweep import commit_pipeline_sm
 
@@ -581,7 +579,8 @@ def _sweep_barrier_stride(Q: int, working_set_bytes: int) -> int:
 
 
 def _coset_sweep_fn(
-    assembly, selector_paths, non_residues, lk_ctx, sm_mesh=None
+    assembly, selector_paths, non_residues, lk_ctx, planes: bool,
+    sm_mesh=None,
 ):
     """Assembly-cached fused per-coset quotient TERMS graph: gate sweep +
     copy-permutation + lookup terms + 1/Z_H over already-evaluated coset
@@ -595,39 +594,29 @@ def _coset_sweep_fn(
     paths) — never the assembly/setup objects, so re-witnessed clones can
     inherit it without pinning the original's witness buffers.
 
-    Variants, cached separately per assembly keyed (limb, shard_map mesh)
-    — the flags can flip between proves in one process; parity tests do
-    exactly that. The per-coset CORE (everything after the xs/L0/1-Z_H
-    coset slicing) is one function with one signature for both
-    representations: the u64 XLA body or the fused u32-limb Pallas kernel
-    (pallas_sweep.build_coset_terms, BOOJUM_TPU_LIMB_SWEEP). Meshless, the
+    Cached per assembly keyed (planes, shard_map mesh): the variant can
+    differ between proves in one process; parity tests do exactly that.
+    `planes` is the prove's representation (KernelVariant.planes). The
+    per-coset CORE (everything after the xs/L0/1-Z_H coset slicing) is
+    the u64 XLA body (`_u64_sweep_core`) or the fused u32-limb Pallas
+    kernel on planes (pallas_sweep.build_coset_terms). Meshless, the
     core runs under a plain jit; under a shard_map mesh it runs per chip
-    on row shards (parallel/shard_sweep.sweep_shard_map — the terms are
-    pointwise across the domain, so sharding rows changes no value)."""
-    from .pallas_sweep import (
-        build_coset_terms,
-        limb_resident_enabled,
-        limb_sweep_enabled,
-    )
-    from ..parallel.sharding import shard_map_mesh
-
-    limb = limb_sweep_enabled()
-    resident = limb_resident_enabled()
-    if sm_mesh is None:
-        sm_mesh = shard_map_mesh()
+    on row shards (parallel/shard_sweep.sweep_shard_map[_p] — the terms
+    are pointwise across the domain, so sharding rows changes no value)."""
     cache = getattr(assembly, "_coset_sweep_cache", None)
     if not isinstance(cache, dict):
         cache = {}
         assembly._coset_sweep_cache = cache
-    key = (limb, resident, sm_mesh)
+    key = (planes, sm_mesh)
     if key in cache:
         return cache[key]
 
-    (lookups, lk_mode, R_args, width, num_partials, chunks,
-     total_alpha_terms, Cg, Ct, W, K, M, mk_path) = lk_ctx
     non_residues = tuple(int(k) for k in non_residues)
+    if planes:
+        # plane stacks in, plane terms out, the challenge/alpha scalar
+        # table host-built (resident.sweep_table_np)
+        from .pallas_sweep import build_coset_terms
 
-    if limb:
         core = build_coset_terms(
             tuple(assembly.gates),
             tuple(tuple(p) for p in selector_paths),
@@ -638,39 +627,33 @@ def _coset_sweep_fn(
             assembly, selector_paths, non_residues, lk_ctx
         )
 
-    if resident:
-        # the RESIDENT sweep: plane stacks in, plane terms out, the
-        # challenge/alpha scalar table host-built (resident.sweep_table_np)
-        core_p = core.planes
-        if sm_mesh is not None:
-            from ..parallel.shard_sweep import sweep_shard_map_p
+    if sm_mesh is not None:
+        from ..parallel.shard_sweep import sweep_shard_map, sweep_shard_map_p
 
-            fn = sweep_shard_map_p(core_p, sm_mesh)
-        else:
+        fn = (sweep_shard_map_p if planes else sweep_shard_map)(
+            core, sm_mesh
+        )
+    elif planes:
 
-            def body_p(
-                wit_p, setup_p, s2_p, zs_p, c_arr,
-                xs_q_p, l0_q_p, zhinv_q_p, table,
-            ):
-                n = wit_p[0].shape[-1]
-                start = c_arr * n
+        def body_p(
+            wit_p, setup_p, s2_p, zs_p, c_arr,
+            xs_q_p, l0_q_p, zhinv_q_p, table,
+        ):
+            n = wit_p[0].shape[-1]
+            start = c_arr * n
 
-                def _sl(p):
-                    return (
-                        jax.lax.dynamic_slice_in_dim(p[0], start, n),
-                        jax.lax.dynamic_slice_in_dim(p[1], start, n),
-                    )
-
-                return core_p(
-                    wit_p, setup_p, s2_p, zs_p,
-                    _sl(xs_q_p), _sl(l0_q_p), _sl(zhinv_q_p), table,
+            def _sl(p):
+                return (
+                    jax.lax.dynamic_slice_in_dim(p[0], start, n),
+                    jax.lax.dynamic_slice_in_dim(p[1], start, n),
                 )
 
-            fn = jax.jit(body_p)
-    elif sm_mesh is not None:
-        from ..parallel.shard_sweep import sweep_shard_map
+            return core(
+                wit_p, setup_p, s2_p, zs_p,
+                _sl(xs_q_p), _sl(l0_q_p), _sl(zhinv_q_p), table,
+            )
 
-        fn = sweep_shard_map(core, sm_mesh)
+        fn = jax.jit(body_p)
     else:
 
         def body(
@@ -693,8 +676,7 @@ def _coset_sweep_fn(
 
 
 def _u64_sweep_core(assembly, selector_paths, non_residues, lk_ctx):
-    """The emulated-u64 per-coset terms core, signature-identical to the
-    limb kernel (pallas_sweep.build_coset_terms): consumes pre-sliced
+    """The emulated-u64 per-coset terms core: consumes pre-sliced
     xs/L0/1-Z_H coset rows so the same core serves the meshless jit and
     the per-chip shard_map body."""
     from .stages import _build_gate_sweep
@@ -775,19 +757,6 @@ def _u64_sweep_core(assembly, selector_paths, non_residues, lk_ctx):
         return gf.mul(acc[0], zhinv_sl), gf.mul(acc[1], zhinv_sl)
 
     return core
-
-
-def _gspmd_demesh_ok() -> bool:
-    """Whether the GSPMD u64-miscompile hardening (rounds 4-5 de-mesh,
-    replicated query gathers) can apply: always, on every topology.
-    PR 5 gated this to single-process meshes because the de-mesh pull
-    onto one device needed every mesh device addressable; shard_sweep's
-    demesh is now addressable-safe (non-addressable arrays gather to
-    every host via multihost_utils.process_allgather, billed to the
-    dcn.* gauges, then land on the local device), so the hardening
-    holds across jax.distributed too — each host runs the identical
-    single-device rounds 4-5 graph over the identical gathered data."""
-    return True
 
 
 @partial(jax.jit, static_argnums=(2, 3))
@@ -932,7 +901,7 @@ def _prefetch_challenge_independent(
     assembly, setup, config, *, log_n, L, Q, n, lookups, lk_mode,
     resident=False,
 ):
-    """Round-0 prefetch (BOOJUM_TPU_OVERLAP): every device input and
+    """Round-0 prefetch: every device input and
     cached domain/twiddle table that rounds 2-5 consume and that depends
     on NO transcript challenge is enqueued here, while the setup-cap
     absorb and the witness commit keep the host busy. Pure enqueue +
@@ -1065,8 +1034,8 @@ def _deep_round5_prep(
     """The DEEP-challenge-INDEPENDENT half of round 5: the 1/(x-z),
     1/(x-z*omega) denominator inversion, the shifted/lookup single-column
     regens, and the public-input denominators all depend only on z (drawn
-    at the end of round 3) and on committed data — so with overlap on the
-    prover dispatches them DURING the round-4 evaluation pull's flight
+    at the end of round 3) and on committed data — so the fused rounds
+    dispatch them DURING the round-4 evaluation pull's flight
     window instead of serially after the DEEP challenge. Returns the prep
     dict the round-5 body consumes; issuing it earlier or later changes
     nothing that crosses the transcript."""
@@ -1195,8 +1164,12 @@ def _prove_entry(assembly, setup, config: ProofConfig, mesh) -> Proof:
     import os
 
     from ..parallel.sharding import prover_mesh
+    from ..utils.pallas_util import resolve_variant
 
     clock = _StageClock()
+    # THE dispatch decision, once a prove: representation, kernel family,
+    # mesh mode, field (the mesh is not active yet, so it is handed over)
+    variant = resolve_variant(mesh)
     _metrics.count("prover.proves")
     with _span("prove", trace_len=assembly.trace_len):
         # measured-traffic baseline BEFORE any of this prove's work: on
@@ -1215,9 +1188,7 @@ def _prove_entry(assembly, setup, config: ProofConfig, mesh) -> Proof:
 
             _aot.maybe_load_for_prove(assembly, config, mesh)
         try:
-            from ..field.spec import is_babybear
-
-            if is_babybear():
+            if variant.field == "babybear":
                 # ISSUE 20: the BabyBear field backend drives the REAL
                 # prover pipeline — same rounds, checkpoints and clock
                 # stages, every kernel the plane-free u32 twin
@@ -1226,9 +1197,11 @@ def _prove_entry(assembly, setup, config: ProofConfig, mesh) -> Proof:
                 proof = prove_full_babybear(assembly, setup, config, clock)
             elif mesh is not None:
                 with prover_mesh(mesh):
-                    proof = _prove_impl(assembly, setup, config, clock)
+                    proof = _prove_impl(
+                        assembly, setup, config, clock, variant
+                    )
             else:
-                proof = _prove_impl(assembly, setup, config, clock)
+                proof = _prove_impl(assembly, setup, config, clock, variant)
             clock.stop()
             # roofline attribution (ISSUE 12): every stage span is
             # closed now — join the analytic cost model with this
@@ -1245,7 +1218,9 @@ def _prove_entry(assembly, setup, config: ProofConfig, mesh) -> Proof:
             clock.stop()
 
 
-def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
+def _prove_impl(
+    assembly, setup, config: ProofConfig, clock, variant
+) -> Proof:
     n = assembly.trace_len
     log_n = n.bit_length() - 1
     L = config.fri_lde_factor
@@ -1268,25 +1243,25 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
     lp = assembly.lookup_params
     TW = (lp.width + 1) if lookups else 0  # table setup columns
 
-    from ..parallel.sharding import active_mesh, shard_cols, shard_map_mesh
+    from ..parallel.sharding import shard_cols, shard_map_mesh
 
-    # Mesh execution comes in two flavors (parallel/sharding.mesh_mode):
-    # the shard_map path runs the FUSED round graphs with per-chip native
-    # kernels and explicit collectives (parallel/shard_sweep.py), so it
-    # shares the fused control flow below; the legacy GSPMD path keeps the
-    # sequenced branches (its smaller jits are what GSPMD partitions).
-    sm_mesh = shard_map_mesh()
-    fused = active_mesh() is None or sm_mesh is not None
-    # Limb residency (ISSUE 10): with BOOJUM_TPU_LIMB_RESIDENT on, every
-    # fused-round graph below runs its plane twin (prover/resident.py) —
-    # (lo, hi) u32 planes are the canonical device representation from the
-    # H2D witness split to the query-phase host joins, and the interior
-    # u64<->limb conversions of the converting path never trace
-    # (limb.splits/limb.joins stay 0; tests/test_limb_resident.py).
-    from .pallas_sweep import limb_resident_enabled
+    # `variant` (utils/pallas_util.KernelVariant) is this prove's resolved
+    # dispatch. Mesh execution comes in two flavors: the shard_map path
+    # runs the FUSED round graphs with per-chip native kernels and
+    # explicit collectives (parallel/shard_sweep.py), so it shares the
+    # fused control flow below; the GSPMD path keeps the sequenced
+    # branches (its smaller jits are what GSPMD partitions).
+    sm_mesh = shard_map_mesh(variant)
+    fused = variant.fused
+    # The plane representation (ISSUE 10): every fused-round graph below
+    # runs its plane twin (prover/resident.py) — (lo, hi) u32 planes are
+    # the canonical device representation from the H2D witness split to
+    # the query-phase host joins, and no interior u64<->limb conversion
+    # traces (limb.splits/limb.joins stay 0; tests/test_limb_resident.py).
+    # A GSPMD prove is never on planes, so `res` implies `fused`.
     from . import resident as RES
 
-    res = fused and limb_resident_enabled()
+    res = variant.planes
     _wit_key = "witness_planes" if res else "witness_cols"
 
     def _shard_cols_r(x):
@@ -1316,8 +1291,7 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
             host_cols.append(np.asarray(assembly.wit_cols_values))
         if M:
             host_cols.append(np.asarray(assembly.multiplicities)[None, :])
-        # chunked async device_put with overlap on, one synchronous
-        # jnp.asarray(np.concatenate) with it off — identical bytes.
+        # chunked async device_put, joined by one on-device concatenate.
         # Resident mode splits once on HOST and uploads u32 planes (the
         # residency contract's H2D edge).
         return _transfer.chunked_upload(host_cols, planes=res)
@@ -1336,12 +1310,13 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
     Q_est = setup.vk.effective_quotient_degree()
     total_cols = (Ct + W + M) + (Ct + K + TW) + S_est + 2 * Q_est
     stream = fused and use_streamed_lde(total_cols, N)
-    overlap = fused and _transfer.overlap_enabled()
-    if overlap:
+    if fused:
         # dispatch everything challenge-independent — witness H2D chunks,
         # the sigma/table uploads, domain/twiddle/FRI caches — while the
-        # setup-cap absorb below runs on host. Enqueue-only: transcript
-        # order (and every byte absorbed) is exactly the sequenced order.
+        # setup-cap absorb below runs on host. Enqueue-only: nothing is
+        # absorbed, so transcript order and bytes are untouched. (The
+        # GSPMD rounds keep their own order: they upload where they
+        # consume.)
         import os as _os0
 
         with _span("overlap_prefetch"):
@@ -1385,10 +1360,9 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
             )
         else:
             wit_mono, wit_lde, layers = _commit_pipeline(
-                witness_cols, L, cap, stream
+                witness_cols, L, cap, stream, sm_mesh
             )
-        if overlap:
-            _prefetch_r(layers[-1])  # cap d2h rides the queue
+        _prefetch_r(layers[-1])  # cap d2h rides the queue
         wit_tree = _tree_r(layers)
     else:
         wit_mono = monomial_from_values(witness_cols)
@@ -1486,8 +1460,7 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
             s2_vals, L, cap, stream, sm_mesh
         )
         del s2_vals
-        if overlap:
-            _prefetch_r(layers[-1])
+        _prefetch_r(layers[-1])
         s2_tree = _tree_r(layers)
         num_all = den_all = den_inv_all = lk_inv = dens = mult_dev = None
         z_pp = None
@@ -1572,10 +1545,11 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
         z_pp = _z_and_partials(num_all, den_inv_all)
         stack = _stage2_stack_fn(assembly, setup.selector_paths)
         s2_vals = stack(z_pp[0], z_pp[1], lk_inv, mult_dev, consts_dev)
-        s2_mono, s2_lde, layers = _commit_pipeline(s2_vals, L, cap, stream)
+        s2_mono, s2_lde, layers = _commit_pipeline(
+            s2_vals, L, cap, stream, sm_mesh
+        )
         del s2_vals
-        if overlap:
-            _transfer.prefetch_async(layers[-1])
+        _transfer.prefetch_async(layers[-1])
         s2_tree = _tree_from_layers(layers, cap)
         # the chunk numerator/denominator ext stacks, the z/partials and
         # the lookup denominators total ~2 GB at 2^20 rows and are dead
@@ -1787,9 +1761,6 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
             total_alpha_terms, Cg, Ct, W, K, M,
             tuple(mk_path) if mk_path is not None else None,
         )
-        from .pallas_sweep import limb_sweep_enabled
-
-        _limb_sweep = limb_sweep_enabled()
         if res:
             # the alpha/γ-power scalar table is host-built; no device u64
             # challenge arrays exist in the resident round
@@ -1805,7 +1776,8 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
             ap = AlphaPows(alpha, total_alpha_terms)
             zero2 = jnp.zeros((2,), jnp.uint64)
         sweep = _coset_sweep_fn(
-            assembly, setup.selector_paths, setup.non_residues, lk_ctx
+            assembly, setup.selector_paths, setup.non_residues, lk_ctx,
+            res, sm_mesh,
         )
         # The dependent dispatches already order the work: each sweep
         # consumes its own coset's four group evaluations and the quotient
@@ -1886,18 +1858,15 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
         }
         T_parts0, T_parts1 = [], []
         with _span(
-            "round3_coset_sweeps", cosets=Q, limb=_limb_sweep,
+            "round3_coset_sweeps", cosets=Q,
             resident=res, sm=sm_mesh is not None,
         ):
             for c in range(Q):
                 ci = jnp.int32(c)
                 _metrics.count("quotient.coset_sweeps")
-                if _limb_sweep:
-                    # flight-recorder surface: the limb-kernel dispatch
-                    # count makes "which representation ran" auditable
-                    # per report
-                    _metrics.count("quotient.limb_coset_sweeps")
                 if res:
+                    # flight-recorder surface: makes "which representation
+                    # ran" auditable per report
                     _metrics.count("quotient.resident_coset_sweeps")
                 wit_v, setup_v, s2_v, zs_v = _coset_group_evals(
                     _group_mono, _group_oracle, c, ci, L, Q, n, _eval_group
@@ -1956,8 +1925,7 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
                 tuple(T_parts0), tuple(T_parts1), Q, n, L, cap
             )
         del T_parts0, T_parts1
-        if overlap:
-            _prefetch_r(layers[-1])
+        _prefetch_r(layers[-1])
         q_tree = _tree_r(layers)
     else:
         T_parts0, T_parts1 = [], []
@@ -2053,7 +2021,7 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
     # ---- round 4: evaluations at z (and z*omega, 0) ----------------------
     clock.start("round4_evaluations")
     _setup_mono = setup.setup_monomials
-    if active_mesh() is not None and sm_mesh is None and _gspmd_demesh_ok():
+    if not fused:
         # GSPMD only: the partitioner's u64 miscompile (see the round-5
         # de-mesh below) can also land on the z-evaluation contraction
         # over the sharded monomial stacks — pull them onto one device
@@ -2080,7 +2048,6 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
         all_mono = jnp.concatenate([wit_mono, _setup_mono, s2_mono, q_mono])
         B = all_mono.shape[0]
     zw = ext_f.mul_by_base_s(z_chal, omega)
-    deep_prep = None
     if res:
         # evaluations compute on planes; the pull fetches u32 planes and
         # u64 reassembles ON HOST (the transcript absorb edge)
@@ -2096,15 +2063,14 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
         if lookups:
             pulls += [s2_mono[0][:, 0], s2_mono[1][:, 0]]
         fetch = _transfer.start_fetch(pulls, label="round4_evals")
-        if overlap:
-            with _span("deep_prep_overlap"):
-                deep_prep = RES.deep_round5_prep_p(
-                    assembly, log_n=log_n, L=L, N=N, lookups=lookups,
-                    num_partials=num_partials, R_args=R_args,
-                    s2_mono_p=s2_mono, wit_mono_p=wit_mono,
-                    s2_lde_flat_p=s2_lde_flat, wit_lde_all_p=wit_lde_all,
-                    xs_lde_p=xs_lde, z_tb=z_tb, zw_tb=zw_tb, omega=omega,
-                )
+        with _span("deep_prep_overlap"):
+            deep_prep = RES.deep_round5_prep_p(
+                assembly, log_n=log_n, L=L, N=N, lookups=lookups,
+                num_partials=num_partials, R_args=R_args,
+                s2_mono_p=s2_mono, wit_mono_p=wit_mono,
+                s2_lde_flat_p=s2_lde_flat, wit_lde_all_p=wit_lde_all,
+                xs_lde_p=xs_lde, z_tb=z_tb, zw_tb=zw_tb, omega=omega,
+            )
         got = fetch.wait()
         from ..field.limbs import join_np as _join_np
 
@@ -2125,18 +2091,17 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
         if lookups:
             pulls.append(s2_mono[:, 0])
         fetch = _transfer.start_fetch(pulls, label="round4_evals")
-        if overlap:
-            # the DEEP-challenge-independent half of round 5 (denominator
-            # inversions, single-column regens, public-input denoms)
-            # dispatches inside the pull's flight window
-            with _span("deep_prep_overlap"):
-                deep_prep = _deep_round5_prep(
-                    assembly, log_n=log_n, L=L, N=N, lookups=lookups,
-                    num_partials=num_partials, R_args=R_args,
-                    s2_mono=s2_mono, wit_mono=wit_mono,
-                    s2_lde_flat=s2_lde_flat, wit_lde_all=wit_lde_all,
-                    xs_lde=xs_lde, z01=z01, zw01=zw01, omega=omega,
-                )
+        # the DEEP-challenge-independent half of round 5 (denominator
+        # inversions, single-column regens, public-input denoms)
+        # dispatches inside the pull's flight window
+        with _span("deep_prep_overlap"):
+            deep_prep = _deep_round5_prep(
+                assembly, log_n=log_n, L=L, N=N, lookups=lookups,
+                num_partials=num_partials, R_args=R_args,
+                s2_mono=s2_mono, wit_mono=wit_mono,
+                s2_lde_flat=s2_lde_flat, wit_lde_all=wit_lde_all,
+                xs_lde=xs_lde, z01=z01, zw01=zw01, omega=omega,
+            )
         got = fetch.wait()
         ev0, ev1, evw0, evw1 = got[:4]
         s2_mono_host = got[4] if lookups else None
@@ -2184,11 +2149,7 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
     def _col(src, i):
         return src.column(i) if isinstance(src, MonomialSource) else src[i]
 
-    if (
-        active_mesh() is not None
-        and shard_map_mesh() is None
-        and _gspmd_demesh_ok()
-    ):
+    if not fused:
         # GSPMD only: XLA's SPMD partitioner miscompiles the u64 round-5
         # math over mesh-sharded operands (first divergence of the whole
         # prove lands on fri_cap_0 — the h/t codeword itself comes out
@@ -2206,8 +2167,6 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
         s2_lde_flat = _demesh(s2_lde_flat)
         q_lde = _demesh(q_lde)
         xs_lde = _demesh(xs_lde)
-        if deep_prep is not None:
-            deep_prep = {k: _demesh(v) for k, v in deep_prep.items()}
 
     deep_sources = [
         wit_lde_all,
@@ -2241,14 +2200,6 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
         y1s = RES.host_planes(
             np.array([v[1] for v in values_at_z], dtype=np.uint64)
         )
-        if deep_prep is None:
-            deep_prep = RES.deep_round5_prep_p(
-                assembly, log_n=log_n, L=L, N=N, lookups=lookups,
-                num_partials=num_partials, R_args=R_args,
-                s2_mono_p=s2_mono, wit_mono_p=wit_mono,
-                s2_lde_flat_p=s2_lde_flat, wit_lde_all_p=wit_lde_all,
-                xs_lde_p=xs_lde, z_tb=z_tb, zw_tb=zw_tb, omega=omega,
-            )
         inv_xz = deep_prep["inv_xz"]
         inv_xzw = deep_prep["inv_xzw"]
         E = 2 + num_lk + num_pi
@@ -2313,16 +2264,7 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
         # the challenge-independent prep — 1/(x-z), 1/(x-z*omega) (one
         # build + ONE batched inversion), single-column regens for the
         # remaining terms, public-input denominators — was dispatched
-        # during the round-4 evaluation pull with overlap on; compute it
-        # here (the sequenced order) otherwise
-        if deep_prep is None:
-            deep_prep = _deep_round5_prep(
-                assembly, log_n=log_n, L=L, N=N, lookups=lookups,
-                num_partials=num_partials, R_args=R_args,
-                s2_mono=s2_mono, wit_mono=wit_mono,
-                s2_lde_flat=s2_lde_flat, wit_lde_all=wit_lde_all,
-                xs_lde=xs_lde, z01=z01, zw01=zw01, omega=omega,
-            )
+        # during the round-4 evaluation pull
         inv_xz = deep_prep["inv_xz"]
         inv_xzw = deep_prep["inv_xzw"]
         ch0e, ch1e = deep_pows.take(2 + num_lk + num_pi)
@@ -2435,7 +2377,7 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
                 h = ext_f.add(h, (gf.mul(term_base, ch[0]), gf.mul(term_base, ch[1])))
 
     _sync_point(h, "deep_codeword")
-    fri = fri_prove(h, t, config, base_degree=n, fused=fused)
+    fri = fri_prove(h, t, config, n, variant)
     pow_nonce = pow_grind(t, config.pow_bits)
     _checkpoint(5, "pow_nonce", [pow_nonce])
 
@@ -2543,11 +2485,7 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
 
     # ONE fused gather dispatch + ONE host transfer
     arrs_, idxs_, axes_ = zip(*plans)
-    if (
-        active_mesh() is not None
-        and shard_map_mesh() is None
-        and _gspmd_demesh_ok()
-    ):
+    if not fused:
         # GSPMD only: XLA's SPMD partitioner miscompiles u64 gathers over
         # partially-replicated operands (replica values get SUMMED — 2x
         # leaf values observed on the forced-8-device CPU mesh, alongside
@@ -2567,9 +2505,11 @@ def _prove_impl(assembly, setup, config: ProofConfig, clock) -> Proof:
 
             arrs_ = tuple(_demesh_g(a) for a in arrs_)
         else:
+            from ..parallel.sharding import active_mesh
+
             _rep = NamedSharding(active_mesh(), PartitionSpec())
             arrs_ = tuple(jax.device_put(a, _rep) for a in arrs_)
-    elif shard_map_mesh() is not None and any(
+    elif sm_mesh is not None and any(
         len(a.devices()) <= 1 for a in arrs_
     ):
         # streamed sm proves mix placements here: commit-phase node

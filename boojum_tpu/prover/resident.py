@@ -24,18 +24,17 @@ conversions plus the per-setup-object `limbs.edge("ingest:*")` splits of
 data that was born u64 before residency — sigma/setup oracles and their
 committed tree).
 
-Dispatch: `pallas_sweep.limb_resident_enabled()` — BOOJUM_TPU_LIMB_RESIDENT
-default ON where the limb sweep is native (TPU), `=0` restores the
-u64-resident path bit-for-bit, `=1` opts in on CPU (tier-1 parity tests).
+Dispatch: `utils/pallas_util.resolve_variant().planes` — the TPU's
+representation (meshless or shard_map); BOOJUM_TPU_LIMB_RESIDENT `=0`
+keeps the u64 path, `=1` opts in on CPU (how the parity tests reach it).
 
 Field note (ISSUE 19): limb residency is a Goldilocks-only concern — the
 planes exist because Goldilocks elements are 64-bit and Mosaic has no
 64-bit integer datapath. Under `BOOJUM_TPU_FIELD=babybear` every element
 already fits one u32 lane, so there is nothing to split: the dispatcher
 (`precompile.enumerate_kernels`) selects the plane-free `_bb` kernel twins
-(prover/bb_kernels.py) before the limb-residency check, and
-`limb_resident_enabled()` itself returns False under babybear. No module
-here participates in a BabyBear prove.
+(prover/bb_kernels.py) first, and the resolver never answers `planes`
+under babybear. No module here participates in a BabyBear prove.
 """
 
 from __future__ import annotations
@@ -108,7 +107,7 @@ def _next_pow2(x: int) -> int:
 def sweep_table_np(alpha, total_alpha_terms, beta, gamma, lkb, lkg,
                    lookups: bool, width: int) -> np.ndarray:
     """The (4, S) u32 scalar table of the resident sweep, in EXACTLY the
-    column layout of pallas_sweep.build_coset_terms' u64 `call` ([alpha
+    column layout pallas_sweep.build_coset_terms' `call` reads ([alpha
     powers (pow2 cap) | beta | gamma | lkb | lkg | gpow(width+1) | lkb']),
     built from the host transcript challenges — the alpha/γ-power tables
     never exist as device u64."""
@@ -307,7 +306,7 @@ def _all_chunk_num_den_p(copy_p, sigma_p, ks_p, xs_bg, chunks):
 
 def _ext_prefix_prod_p(a):
     """Inclusive ext prefix product along the last axis on planes
-    (stages._ext_prefix_prod_xla twin)."""
+    (field/extension.prefix_product twin)."""
     n = a[0][0].shape[-1]
     shift = 1
     while shift < n:

@@ -130,7 +130,7 @@ def _z_and_partials(num_all, den_inv_all):
     full = (ratios[0][0], ratios[1][0])
     for j in range(1, K):
         full = ext_f.mul(full, (ratios[0][j], ratios[1][j]))
-    incl = _ext_prefix_prod(full)
+    incl = ext_f.prefix_product(full)
     one = jnp.ones((1,), jnp.uint64)
     zero = jnp.zeros((1,), jnp.uint64)
     z = (
@@ -146,30 +146,6 @@ def _z_and_partials(num_all, den_inv_all):
     if parts0:
         return z, (jnp.stack(parts0), jnp.stack(parts1))
     return z, (jnp.zeros((0,) + z[0].shape, jnp.uint64),) * 2
-
-
-def _ext_prefix_prod(a):
-    """Inclusive ext prefix product along the last axis (log-doubling XLA;
-    see goldilocks.batch_inverse for why the Pallas block-scan was
-    retired)."""
-    return _ext_prefix_prod_xla(a)
-
-
-@jax.jit
-def _ext_prefix_prod_xla(a):
-    """Inclusive ext prefix product along the last axis (log-doubling; same
-    rationale as gf.prefix_product — associative_scan's graph explodes XLA
-    compile time for wide combine fns)."""
-    n = a[0].shape[-1]
-    shift = 1
-    while shift < n:
-        shifted = (
-            jnp.concatenate([jnp.ones((shift,), jnp.uint64), a[0][:-shift]]),
-            jnp.concatenate([jnp.zeros((shift,), jnp.uint64), a[1][:-shift]]),
-        )
-        a = ext_f.mul(a, shifted)
-        shift *= 2
-    return a
 
 
 def compute_copy_permutation_stage2(

@@ -162,24 +162,16 @@ def streamed_leaf_digests_blocks(mono, L: int):
     bill, ISSUE 1). The per-block dynamic_slice start rides as an array
     argument, so block index never enters a cache key.
 
-    With BOOJUM_TPU_OVERLAP (default on) the commit is DOUBLE-BUFFERED:
+    The commit is DOUBLE-BUFFERED:
     the LDE transform and the carried-sponge absorb are separate
     dispatches, and block b+1's transform is enqueued before block b's
     absorb — the transforms carry no data dependence on the sponge chain,
     so the device pipelines them instead of draining between blocks. The
     absorb order (and therefore every digest) is unchanged."""
-    from ..utils.transfer import overlap_enabled
-
     assert COL_BLOCK % 8 == 0
     n = mono.shape[-1]
     B = mono.shape[0]
     state = jnp.zeros((n * L, 12), jnp.uint64)
-    if not overlap_enabled():
-        for i in range(0, B, COL_BLOCK):
-            b = min(COL_BLOCK, B - i)
-            blk = jax.lax.dynamic_slice_in_dim(mono, i, b, axis=0)
-            state = _absorb_lde_block(state, blk, L)
-        return state[:, :4]
 
     def _lde(i):
         b = min(COL_BLOCK, B - i)
@@ -222,10 +214,9 @@ from functools import partial as _partial
 
 @_partial(jax.jit, static_argnums=(1,))
 def _lde_block_cols(mono_blk, L: int):
-    """One column block's rate-L leaf columns (N, b): the LDE half of
-    `_absorb_lde_block`, split out so the double-buffered commit can
-    dispatch block b+1's transform while block b absorbs. Keyed (b, n, L)
-    like the fused form."""
+    """One column block's rate-L leaf columns (N, b): its own dispatch,
+    so the double-buffered commit can enqueue block b+1's transform while
+    block b absorbs. Keyed (b, n, L)."""
     b = mono_blk.shape[0]
     lde = lde_from_monomial(mono_blk, L)
     return lde.reshape(b, -1).T  # (N, b)
@@ -233,32 +224,11 @@ def _lde_block_cols(mono_blk, L: int):
 
 @jax.jit
 def _absorb_cols(state, cols):
-    """Absorb an (N, b) leaf-column block into the carried sponge state —
-    the absorb half of `_absorb_lde_block`, identical math (full 8-column
-    chunks in order, trailing partial chunk zero-pads per the sponge
-    finalize rule)."""
-    b = cols.shape[1]
-    for k in range(b // 8):
-        state = _sponge_absorb8(state, cols[:, 8 * k : 8 * k + 8])
-    rem = b % 8
-    if rem:
-        pad = jnp.zeros((cols.shape[0], 8 - rem), jnp.uint64)
-        state = _sponge_absorb8(
-            state, jnp.concatenate([cols[:, b - rem :], pad], axis=1)
-        )
-    return state
-
-
-@_partial(jax.jit, static_argnums=(2,))
-def _absorb_lde_block(state, mono_blk, L: int):
-    """Absorb one column block's rate-L values into the carried sponge
-    state: LDE-transform the (b, n) monomial block, transpose to rows and
-    absorb 8 columns at a time. A trailing partial chunk (only ever the
+    """Absorb an (N, b) leaf-column block into the carried sponge state:
+    full 8-column chunks in order; a trailing partial chunk (only ever the
     final block of an oracle — COL_BLOCK is a multiple of the sponge rate)
     zero-pads per the sponge finalize rule, matching leaf_hash exactly."""
-    b = mono_blk.shape[0]
-    lde = lde_from_monomial(mono_blk, L)
-    cols = lde.reshape(b, -1).T  # (N, b)
+    b = cols.shape[1]
     for k in range(b // 8):
         state = _sponge_absorb8(state, cols[:, 8 * k : 8 * k + 8])
     rem = b % 8
@@ -363,9 +333,7 @@ def _lde_block_cols_p(mono_blk_p, L: int):
 
 def streamed_leaf_digests_blocks_p(mono_p, L: int):
     """Plane twin of streamed_leaf_digests_blocks: (N, 4) digest planes,
-    double-buffered under BOOJUM_TPU_OVERLAP exactly like the u64 form."""
-    from ..utils.transfer import overlap_enabled
-
+    double-buffered exactly like the u64 form."""
     assert COL_BLOCK % 8 == 0
     n = mono_p[0].shape[-1]
     B = mono_p[0].shape[0]
@@ -380,12 +348,6 @@ def streamed_leaf_digests_blocks_p(mono_p, L: int):
             jax.lax.dynamic_slice_in_dim(mono_p[0], i, b, axis=0),
             jax.lax.dynamic_slice_in_dim(mono_p[1], i, b, axis=0),
         )
-
-    if not overlap_enabled():
-        for i in range(0, B, COL_BLOCK):
-            cols = _lde_block_cols_p(_blk(i), L)
-            state = _absorb_cols_p(state, cols)
-        return state[0][:, :4], state[1][:, :4]
 
     state = double_buffered_absorb(
         state,

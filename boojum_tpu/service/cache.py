@@ -107,21 +107,22 @@ class DeviceCacheManager:
         (twiddles for rates L and Q, domain constants, FRI fold tables) —
         idempotent and enqueue-only, exactly the set the prover's round-0
         prefetch touches. Returns True when this call did the warming."""
-        from ..field.spec import active_field, is_babybear
+        from ..utils.pallas_util import resolve_variant
 
+        variant = resolve_variant()
         key = (
             bucket.log_n, bucket.lde_factor, bucket.quotient_degree,
             bucket.fri_final_degree, bucket.fri_schedule, bucket.lookups,
             # field backend (ISSUE 20): a geometry warmed under goldilocks
             # holds u64 twiddles — the same bucket under babybear needs
             # its own u32 table set, so the field is part of the key
-            active_field(),
+            variant.field,
         )
         with self._lock:
             if key in self._warmed_geometries:
                 return False
             self._warmed_geometries.add(key)
-        if is_babybear():
+        if variant.field == "babybear":
             # the babybear full prover (prover/prover_bb.py) consumes the
             # plane-free u32 table set — bb_ntt twiddles/scale tables at
             # trace size and both full-domain rates, the coset domain
@@ -153,9 +154,7 @@ class DeviceCacheManager:
             if num_rounds >= 1:
                 BK.fri_fold_tables_bb(log_full, shift, num_rounds)
             return True
-        from ..prover.pallas_sweep import limb_resident_enabled
-
-        if limb_resident_enabled():
+        if variant.planes:
             # the resident prove consumes the PLANE table set (ISSUE 10)
             # — warm exactly what it will touch, nothing u64
             from ..prover import resident as RES

@@ -5,7 +5,7 @@ answers "how long SHOULD it have taken": an analytic cost sheet — field
 muls/adds folded into XLA-flop equivalents, HBM bytes moved, ICI bytes
 crossed — for every executable `prover/precompile.enumerate_kernels`
 emits, parameterized on `ShapeBucket` geometry and the active variant
-flags (limb_sweep / limb_resident / mesh / streamed). Joined with the
+(representation / mesh / streamed). Joined with the
 measured span walls and the `ici.*` / `transfer.*` gauges, it stamps a
 validated `cost` record on every ProveReport line: achieved GFLOP/s and
 GB/s per stage, the roofline regime (compute- vs memory-bound, from
@@ -516,7 +516,7 @@ def kernel_cost(name: str, args, mesh_devices: int = 1) -> dict:
 
 def _kernel_cost_bb(base: str, name: str, args) -> dict:
     """Analytic cost of one `_bb` kernel dispatch. Same families as the
-    Goldilocks routing so the roofline and model_check aggregate them
+    Goldilocks routing so the roofline aggregates them
     together; every entry additionally carries field="babybear" and
     elem_bytes=4 so a report consumer can attribute the byte halving."""
     eb = BB_ELEM_BYTES
@@ -944,69 +944,7 @@ def build_cost_record(
         record["attributed_kernels"] = sorted(
             name for name in ledger_costs if name in (sheet or {})
         )
-        record["model_check"] = model_check(
-            sheet or {}, ledger_costs
-        )
     return record
-
-
-def model_check(sheet: dict, ledger_costs: dict) -> dict:
-    """Aggregate analytic-vs-XLA agreement over the kernels present in
-    BOTH the analytic sheet and the ledger's captured actuals. Ratios
-    are analytic/actual; the documented tolerance band is pinned by
-    tests/test_costmodel.py and BASELINE.md."""
-    a_flops = x_flops = a_bytes = x_bytes = 0.0
-    covered = 0
-    fams: dict = {}
-    for name, actual in ledger_costs.items():
-        ent = sheet.get(name)
-        if not ent or not isinstance(actual, dict):
-            continue
-        xf = actual.get("flops")
-        xb = actual.get("bytes_accessed")
-        if not isinstance(xf, (int, float)) or not isinstance(
-            xb, (int, float)
-        ):
-            continue
-        covered += 1
-        a_flops += float(ent.get("flops", 0.0))
-        x_flops += float(xf)
-        a_bytes += float(ent.get("hbm_bytes", 0.0))
-        x_bytes += float(xb)
-        slot = fams.setdefault(
-            ent.get("family", "fallback"),
-            {"kernels": 0, "af": 0.0, "xf": 0.0, "ab": 0.0, "xb": 0.0},
-        )
-        slot["kernels"] += 1
-        slot["af"] += float(ent.get("flops", 0.0))
-        slot["xf"] += float(xf)
-        slot["ab"] += float(ent.get("hbm_bytes", 0.0))
-        slot["xb"] += float(xb)
-    out = {
-        "covered_kernels": covered,
-        "ledger_kernels": len(ledger_costs),
-        "analytic_flops": round(a_flops, 1),
-        "xla_flops": round(x_flops, 1),
-        "analytic_hbm_bytes": round(a_bytes, 1),
-        "xla_bytes_accessed": round(x_bytes, 1),
-    }
-    if x_flops > 0 and a_flops > 0:
-        out["flops_ratio"] = round(a_flops / x_flops, 4)
-    if x_bytes > 0 and a_bytes > 0:
-        out["bytes_ratio"] = round(a_bytes / x_bytes, 4)
-    out["families"] = {
-        fam: {
-            "kernels": s["kernels"],
-            "flops_ratio": (
-                round(s["af"] / s["xf"], 4) if s["xf"] > 0 else None
-            ),
-            "bytes_ratio": (
-                round(s["ab"] / s["xb"], 4) if s["xb"] > 0 else None
-            ),
-        }
-        for fam, s in sorted(fams.items())
-    }
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1170,9 +1108,10 @@ def attach_cost_record(
         mesh_shape = None
         dcn_frac = 0.0
         if mesh is not None:
-            from ..prover.aot import _mesh_shape_list, _would_shard_map
+            from ..prover.aot import _mesh_shape_list
+            from .pallas_util import resolve_variant
 
-            if _would_shard_map(mesh):
+            if resolve_variant(mesh).mesh == "shard_map":
                 mesh_shape = _mesh_shape_list(mesh)
                 try:
                     from ..parallel.multihost import dcn_fraction

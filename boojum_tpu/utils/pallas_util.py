@@ -1,4 +1,6 @@
-"""Shared helpers for Pallas TPU kernels.
+"""The kernel-variant decision (`resolve_variant`, `force_xla`,
+`local_operands`, `pallas_enabled`) and shared helpers for Pallas TPU
+kernels.
 
 The framework enables `jax_enable_x64` globally (the field is 64-bit), which
 makes BlockSpec index maps trace as i64 — Mosaic only legalizes i32 index
@@ -8,6 +10,7 @@ back to int32.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -50,25 +53,99 @@ class local_operands:
         return False
 
 
-def pallas_enabled(opt_in_env: str | None = None) -> bool:
-    """True when the fused TPU kernels should be used.
+@dataclasses.dataclass(frozen=True)
+class KernelVariant:
+    """Which kernel set a prove dispatches. Resolved in ONE place
+    (`resolve_variant`) from what the code observes; `_prove_entry`
+    resolves it once a prove and hands it down, `enumerate_kernels`, the
+    AOT bundle key and the service cache read the same record.
 
-    Requires the TPU backend, no active prover mesh (the sharded pipeline
-    keeps plain XLA ops so GSPMD can partition them — pallas_call does not
-    split under a NamedSharding; shard_map bodies announce their per-chip
-    blocks via `local_operands` and keep the kernels), and no
-    BOOJUM_TPU_PALLAS=0 override. With `opt_in_env`, additionally requires
-    that env var to be "1" (used by kernels that currently trail the XLA
-    path and are opt-in)."""
-    if opt_in_env is not None and os.environ.get(opt_in_env, "0") != "1":
-        return False
-    if _FORCE_XLA[0]:
-        return False
-    from .transfer import env_flag
+    representation: "u64" (emulated-uint64 words, plain XLA graphs: the
+        CPU default, the tests' reference, every GSPMD and BabyBear
+        prove) or "planes" ((lo, hi) u32 limb planes end to end,
+        prover/resident.py: the TPU default).
+    pallas: the native Pallas/MXU kernels rather than their XLA twins
+        (TPU backend, no `force_xla`, not a GSPMD mesh). `pallas_enabled`
+        is its trace-time form for the layers below the prover.
+    mesh: None, "gspmd" (sequenced rounds, NamedSharding constraints) or
+        "shard_map" (fused rounds, per-chip kernels, explicit collectives).
+    field: "goldilocks" or "babybear" (field/spec.py)."""
 
-    if not env_flag("BOOJUM_TPU_PALLAS", True):
-        return False
-    if jax.default_backend() != "tpu":
+    representation: str
+    pallas: bool
+    mesh: str | None
+    field: str
+
+    @property
+    def planes(self) -> bool:
+        return self.representation == "planes"
+
+    @property
+    def fused(self) -> bool:
+        """The fused round graphs (meshless and shard_map); the GSPMD
+        rounds stay sequenced: its smaller jits are what GSPMD
+        partitions."""
+        return self.mesh != "gspmd"
+
+    def as_dict(self) -> dict:
+        return dataclasses.asdict(self)
+
+
+_ACTIVE = object()
+
+
+def resolve_variant(mesh=_ACTIVE) -> KernelVariant:
+    """THE dispatch decision. Observes the backend, `mesh` (default: the
+    mesh `prover_mesh` activated; pass one to ask what `prove(mesh=...)`
+    would run without activating it), the active field and `force_xla`,
+    plus two overrides: BOOJUM_TPU_MESH_MODE=shard_map|gspmd (unset:
+    shard_map on every topology) and BOOJUM_TPU_LIMB_RESIDENT (unset:
+    planes on a TPU, u64 elsewhere; =1 runs the plane pipeline on a CPU
+    with interpret-mode / XLA limb kernels, which is how the tests reach
+    it; =0 keeps u64 words). An unparsable override raises: a typo never
+    picks a mode silently."""
+    from ..field.spec import active_field
+    from .transfer import env_flag_opt
+
+    if mesh is _ACTIVE:
+        from ..parallel.sharding import active_mesh
+
+        mesh = active_mesh()
+    mode = None
+    if mesh is not None:
+        mode = os.environ.get("BOOJUM_TPU_MESH_MODE", "").strip().lower()
+        mode = {"": "shard_map", "sm": "shard_map"}.get(mode, mode)
+        if mode not in ("shard_map", "gspmd"):
+            raise ValueError(
+                f"BOOJUM_TPU_MESH_MODE={mode!r}: use shard_map or gspmd"
+            )
+    field = active_field()
+    on_tpu = jax.default_backend() == "tpu"
+    # GSPMD cannot partition a pallas_call, and the planes have no XLA-
+    # partitioned twin; a BabyBear element is one bare u32 lane with no
+    # planes to be resident in (its `_bb` kernel set is disjoint)
+    native_ok = not _FORCE_XLA[0] and mode != "gspmd"
+    explicit = env_flag_opt("BOOJUM_TPU_LIMB_RESIDENT")
+    planes = (
+        native_ok
+        and field == "goldilocks"
+        and (on_tpu if explicit is None else explicit)
+    )
+    return KernelVariant(
+        representation="planes" if planes else "u64",
+        pallas=native_ok and on_tpu,
+        mesh=mode,
+        field=field,
+    )
+
+
+def pallas_enabled() -> bool:
+    """`resolve_variant().pallas` as the layers that decide at trace time
+    see it (Poseidon2 sponges, the NTT dispatcher): additionally False in
+    a PLAIN jit over mesh-sharded operands, which GSPMD cannot hand to a
+    pallas_call; shard_map bodies announce their per-chip blocks via
+    `local_operands` and keep the kernels."""
+    if not resolve_variant().pallas:
         return False
     if _LOCAL_OPERANDS[0]:
         return True

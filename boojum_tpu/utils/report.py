@@ -2627,13 +2627,6 @@ def render_roofline(report: dict) -> str:
         _row(name, ent)
     if isinstance(cost.get("total"), dict):
         _row("TOTAL", cost["total"])
-    mc = cost.get("model_check")
-    if isinstance(mc, dict):
-        lines.append(
-            f"  model check: {mc.get('covered_kernels')} kernels vs XLA "
-            f"actuals — flops ratio {mc.get('flops_ratio')}, bytes ratio "
-            f"{mc.get('bytes_ratio')}"
-        )
     return "\n".join(lines)
 
 

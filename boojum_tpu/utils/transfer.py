@@ -1,8 +1,7 @@
 """Async host<->device transfers — the overlapped prove pipeline's seam.
 
-The sequenced prover blocks the host on every device->host pull (four
-separate `np.asarray` waits per evaluation round) and uploads the whole
-witness in one synchronous `jnp.asarray`, so the device queue drains at
+A prover that blocks the host on every device->host pull and uploads the
+whole witness in one synchronous `jnp.asarray` drains the device queue at
 every transcript interaction. This module gives the prover three
 overlap primitives, all bit-transparent (only WHEN bytes move changes,
 never what is absorbed into the transcript):
@@ -22,10 +21,8 @@ never what is absorbed into the transcript):
   blocking pull in the pipeline lands in the same metrics counters.
 
 Every blocking wait counts into `host.blocking_syncs` (one per `to_host`,
-one per `HostFetch` batch regardless of batch size) — the tier-1 guard
-test asserts the overlapped prove issues strictly fewer than the
-sequenced one. `BOOJUM_TPU_OVERLAP` (default on) gates all overlap
-behavior; `=0` restores the fully sequenced transfer order.
+one per `HostFetch` batch regardless of batch size); tests/test_overlap.py
+pins the count of a 2^10 prove.
 """
 
 from __future__ import annotations
@@ -62,17 +59,11 @@ def env_flag(name: str, default: bool) -> bool:
 def env_flag_opt(name: str) -> bool | None:
     """Tri-state form of `env_flag`: True/False for an explicit setting,
     None when the variable is unset/empty (callers supply a context-
-    dependent default, e.g. pallas_sweep's backend-dependent dispatch).
+    dependent default, e.g. pallas_util.resolve_variant's backend default).
     Same spelling set, same raise-on-junk contract."""
     if not os.environ.get(name, "").strip():
         return None
     return env_flag(name, False)
-
-
-def overlap_enabled() -> bool:
-    """BOOJUM_TPU_OVERLAP: default ON; 0/false/off/no disables (the fully
-    sequenced transfer order), 1/true/on/yes forces on."""
-    return env_flag("BOOJUM_TPU_OVERLAP", True)
 
 
 def _is_device_array(x) -> bool:
@@ -232,34 +223,13 @@ class HostFetch:
         return out
 
 
-class _SequencedFetch:
-    """start_fetch's overlap-off twin: nothing is started early; wait()
-    performs one fully blocking `to_host` per array (the pre-overlap
-    transfer order, one `host.blocking_syncs` tick each)."""
-
-    def __init__(self, arrays, label: str | None = None):
-        self.arrays = list(arrays)
-        self.label = label
-        self._out: list | None = None
-
-    def wait(self) -> list:
-        if self._out is None:
-            self._out = [to_host(a) for a in self.arrays]
-        return self._out
-
-
-def start_fetch(arrays, label: str | None = None):
-    """Begin a device->host batch: overlapped (`HostFetch`) when
-    BOOJUM_TPU_OVERLAP is on, fully sequenced otherwise. Either way the
-    caller gets `.wait() -> list[np.ndarray]`."""
-    if overlap_enabled():
-        return HostFetch(arrays, label=label)
-    return _SequencedFetch(arrays, label=label)
+def start_fetch(arrays, label: str | None = None) -> HostFetch:
+    """Begin a device->host batch; `.wait() -> list[np.ndarray]`."""
+    return HostFetch(arrays, label=label)
 
 
 def fetch_np(*arrays, label: str | None = None) -> list:
-    """Pull several device arrays as one batch (one blocking sync with
-    overlap on; per-array syncs with it off)."""
+    """Pull several device arrays as one batch (one blocking sync)."""
     return start_fetch(arrays, label=label).wait()
 
 
@@ -297,32 +267,22 @@ def chunked_upload(host_arrays, planes: bool = False):
     """Upload a list of (rows_i, n) host arrays as one (sum_rows, n)
     device stack.
 
-    Overlap on: each bounded row chunk goes up through its own
-    `jax.device_put` (async enqueue — the host returns to transcript work
-    while the DMA runs) and ONE jitted on-device concatenate joins them;
-    bit-identical to uploading the host-side concatenation. Overlap off:
-    exactly the legacy single synchronous `jnp.asarray(np.concatenate)`.
+    Each bounded row chunk goes up through its own `jax.device_put`
+    (async enqueue — the host returns to transcript work while the DMA
+    runs) and ONE jitted on-device concatenate joins them; bit-identical
+    to uploading the host-side concatenation.
 
     With `planes` (the limb-resident prove, ISSUE 10) each chunk splits
     ONCE on host (`limbs.split_np` — the H2D edge of the residency
     contract) and uploads as two u32 planes; returns the (lo, hi) device
     pair. Same chunk walk, same total bytes."""
     import jax
-    import jax.numpy as jnp
 
     host_arrays = [np.asarray(a) for a in host_arrays]
     if planes:
         from ..field import limbs
 
         split_arrays = [limbs.split_np(a) for a in host_arrays]
-        if not overlap_enabled():
-            if len(split_arrays) == 1:
-                lo, hi = split_arrays[0]
-                return jnp.asarray(lo), jnp.asarray(hi)
-            return (
-                jnp.asarray(np.concatenate([s[0] for s in split_arrays])),
-                jnp.asarray(np.concatenate([s[1] for s in split_arrays])),
-            )
         n = host_arrays[0].shape[-1]
         per = max(1, H2D_CHUNK_BYTES // max(n * 8, 1))
         parts_lo, parts_hi = [], []
@@ -334,10 +294,6 @@ def chunked_upload(host_arrays, planes: bool = False):
         if len(parts_lo) == 1:
             return parts_lo[0], parts_hi[0]
         return _concat_jit()(*parts_lo), _concat_jit()(*parts_hi)
-    if not overlap_enabled():
-        if len(host_arrays) == 1:
-            return jnp.asarray(host_arrays[0])
-        return jnp.asarray(np.concatenate(host_arrays, axis=0))
     n = host_arrays[0].shape[-1]
     per = max(1, H2D_CHUNK_BYTES // max(n * 8, 1))
     parts = []

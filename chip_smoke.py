@@ -231,8 +231,10 @@ def check_mesh(res: dict) -> list[str]:
         problems.append("meshless and mesh proof bytes differ")
     if not float(res.get("mesh_gauges", {}).get("ici.all_to_all_bytes", 0)) > 0:
         problems.append("ici.all_to_all_bytes is zero: no pivot crossed chips")
-    if not int(res.get("mesh_counters", {}).get("merkle.limb_leaf_sponges", 0)) > 0:
-        problems.append("merkle.limb_leaf_sponges is zero on the mesh prove")
+    mesh_counters = res.get("mesh_counters", {})
+    for name in ("merkle.resident_commits", "merkle.sm_commits"):
+        if not int(mesh_counters.get(name, 0)) > 0:
+            problems.append(f"{name} is zero on the mesh prove")
     peaks = res.get("peak_bytes_in_use_per_device", [])
     if len(peaks) != 4 or not all(int(p or 0) > 0 for p in peaks):
         problems.append(f"not every chip held data: peak bytes {peaks}")

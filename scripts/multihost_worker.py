@@ -263,13 +263,9 @@ def main():
         # native limb kernels + explicit collectives; gspmd = legacy
         # XLA-partitioned u64) — the parity test and MULTICHIP triage
         # both key on this stamp
-        from boojum_tpu.parallel.sharding import (
-            mesh_mode as _mesh_mode,
-            prover_mesh as _prover_mesh,
-        )
+        from boojum_tpu.utils.pallas_util import resolve_variant
 
-        with _prover_mesh(mesh):
-            result["mesh_mode"] = _mesh_mode()
+        result["mesh_mode"] = resolve_variant(mesh).mesh
         asm = build_circuit(0).into_assembly()
         setup = generate_setup(asm, cfg)
         proof = prove(asm, setup, cfg, mesh=mesh)

@@ -122,12 +122,12 @@ def baseline():
 
 
 # The tests that need a 2^10 prove on the interpret-mode limb kernels
-# (BOOJUM_TPU_LIMB_SWEEP / _LIMB_RESIDENT = 1, shard_map with them) are
+# (BOOJUM_TPU_LIMB_RESIDENT=1, shard_map with it) are
 # slow: the program's own jits compile through XLA:CPU's fusion emitters,
 # which under jax 0.9.0 run the u32-limb cores for over half an hour a
 # kernel (CHANGES.md PR 24). The slow lane sets
-# XLA_FLAGS=--xla_cpu_use_fusion_emitters=false (200 s for the limb parity
-# pair); each file says what tier-1 keeps of its path.
+# XLA_FLAGS=--xla_cpu_use_fusion_emitters=false; each file says what
+# tier-1 keeps of its path.
 interpret_e2e = pytest.mark.slow
 
 

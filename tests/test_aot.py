@@ -250,8 +250,10 @@ def test_cold_process_carries_cost_actuals():
     line = serve["report_line"]
     cost = line.get("cost")
     assert isinstance(cost, dict), "cold serve line missing cost record"
-    mc = cost.get("model_check")
-    assert mc and mc["covered_kernels"] >= 0.8 * build["num_kernels"], mc
+    assert (
+        len(cost.get("attributed_kernels") or [])
+        >= 0.8 * build["num_kernels"]
+    ), cost.get("attributed_kernels")
     ledger = line["compile_ledger"]
     assert set(cost.get("attributed_kernels") or []) <= set(
         ledger["kernel_names"]

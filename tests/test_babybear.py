@@ -359,8 +359,8 @@ def _fma_cfg_asm():
 
 def test_dispatcher_selects_bb_set_and_vetoes_limbs(monkeypatch):
     from boojum_tpu.prover.precompile import enumerate_kernels
-    from boojum_tpu.prover.pallas_sweep import limb_resident_enabled
     from boojum_tpu.prover.aot import variant_fingerprint
+    from boojum_tpu.utils.pallas_util import resolve_variant
     from boojum_tpu.prover.shape_key import shape_bucket
 
     asm, cfg = _fma_cfg_asm()
@@ -373,7 +373,7 @@ def test_dispatcher_selects_bb_set_and_vetoes_limbs(monkeypatch):
     monkeypatch.setenv("BOOJUM_TPU_FIELD", "babybear")
     # even with limb residency forced on, babybear vetoes it
     monkeypatch.setenv("BOOJUM_TPU_LIMB_RESIDENT", "1")
-    assert limb_resident_enabled() is False
+    assert resolve_variant().planes is False
     asm._shape_bucket_cache = {}
     assert shape_bucket(asm, cfg).key == key_gl + ":Fbabybear"
     assert variant_fingerprint()["field"] == "babybear"

@@ -58,7 +58,7 @@ _MAIN = {
 _MESH = {
     "phase": "mesh", "verify_single": True, "verify_mesh": True,
     "proofs_equal": True,
-    "mesh_counters": {"merkle.limb_leaf_sponges": 4},
+    "mesh_counters": {"merkle.resident_commits": 4, "merkle.sm_commits": 4},
     "mesh_gauges": {"ici.all_to_all_bytes": 1.5e9},
     "peak_bytes_in_use_per_device": [1 << 30] * 4,
     "precompile_counters": {"precompile.kernels": 114}, "ledger_errors": [],
@@ -184,7 +184,7 @@ def test_chips_4_runs_only_the_mesh_phase():
     (lambda r: r.update(proofs_equal=False), "bytes differ"),
     (lambda r: r["mesh_gauges"].update({"ici.all_to_all_bytes": 0.0}),
      "all_to_all_bytes"),
-    (lambda r: r["mesh_counters"].clear(), "limb_leaf_sponges"),
+    (lambda r: r["mesh_counters"].clear(), "resident_commits"),
     (lambda r: r["precompile_counters"].update(
         {"precompile.lower_errors": 2}), "lower_errors"),
     (lambda r: r.update(

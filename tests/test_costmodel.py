@@ -270,7 +270,7 @@ def test_kernel_costs_filter_by_shape_key():
     """The compile ledger is process-global and kernel names are not
     shape-qualified — a multi-bucket process must get ITS bucket's XLA
     actuals, never another bucket's (a 2^12 sweep's flops attributed to
-    a 2^10 prove would skew model_check ~4x)."""
+    a 2^10 prove would skew its cost record ~4x)."""
     from boojum_tpu.utils.profiling import CompileLedger
 
     led = CompileLedger()
@@ -333,27 +333,6 @@ def test_e2e_prove_emits_valid_cost_record():
     assert any(k.startswith("cost.round3_quotient.") for k in gauges)
 
 
-def test_analytic_model_within_tolerance_of_xla():
-    """Acceptance: the analytic model agrees with XLA cost_analysis()
-    within the documented band for the dispatched kernel set — family
-    aggregates within 4x, totals within 2.5x (BASELINE.md "Cost model
-    & trend protocol"). The `small` family (sub-microsecond power
-    tables) is explicitly outside the band."""
-    _asm, _cfg, spec_names, _led, line = _proved_with_costs()
-    mc = line["cost"]["model_check"]
-    assert mc["covered_kernels"] >= 0.8 * len(spec_names), mc
-    assert 0.4 <= mc["flops_ratio"] <= 2.5, mc
-    assert 0.4 <= mc["bytes_ratio"] <= 2.5, mc
-    for fam, ent in mc["families"].items():
-        if fam in ("small", "transfer", "fallback", "error"):
-            continue
-        for key in ("flops_ratio", "bytes_ratio"):
-            r = ent.get(key)
-            if r is None:
-                continue
-            assert 0.25 <= r <= 4.0, (fam, key, r, mc["families"])
-
-
 def test_roofline_cli_and_check_cli(tmp_path):
     _asm, _cfg, _names, _led, line = _proved_with_costs()
     path = tmp_path / "cost.jsonl"
@@ -372,7 +351,7 @@ def test_roofline_cli_and_check_cli(tmp_path):
     assert roof.returncode == 0, roof.stdout + roof.stderr
     assert "round3_quotient" in roof.stdout
     assert "GFLOP/s" in roof.stdout
-    assert "model check" in roof.stdout
+    assert "TOTAL" in roof.stdout
 
 
 # ---------------------------------------------------------------------------

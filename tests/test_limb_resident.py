@@ -38,7 +38,7 @@ from proving import (
 # Every test that reads the resident prove is slow (proving.interpret_e2e
 # says why). Tier-1 keeps the limb cores (test_limb_sweep's kernel
 # parities), the resident kernel set's enumeration and lowering
-# (test_precompile) and the dispatch predicate below.
+# (test_precompile) and the dispatch decision (test_variant).
 def _both_runs():
     # the shared baseline is the u64 prove (residency is off on CPU unless
     # asked for); it is proved first, so its caches never benefit from
@@ -46,39 +46,6 @@ def _both_runs():
     u64 = baseline()
     res = recorded_prove("res", {"BOOJUM_TPU_LIMB_RESIDENT": "1"})
     return {"u64": u64, "res": res}
-
-
-# ---------------------------------------------------------------------------
-# Dispatch predicate
-# ---------------------------------------------------------------------------
-
-
-def test_resident_flag_dispatch(monkeypatch):
-    """Tri-state: =0 off everywhere; =1 on (and implies the limb kernel
-    family) even on CPU; unset follows the native default (off on CPU);
-    junk raises; every limb-sweep veto also vetoes residency."""
-    from boojum_tpu.prover import pallas_sweep as ps
-    from boojum_tpu.utils.pallas_util import force_xla
-
-    monkeypatch.delenv("BOOJUM_TPU_LIMB_RESIDENT", raising=False)
-    monkeypatch.delenv("BOOJUM_TPU_LIMB_SWEEP", raising=False)
-    if jax.default_backend() != "tpu":
-        assert ps.limb_resident_enabled() is False
-    monkeypatch.setenv("BOOJUM_TPU_LIMB_RESIDENT", "1")
-    assert ps.limb_resident_enabled() is True
-    # residency implies the limb kernels
-    assert ps.limb_sweep_enabled() is True
-    monkeypatch.setenv("BOOJUM_TPU_LIMB_RESIDENT", "0")
-    assert ps.limb_resident_enabled() is False
-    monkeypatch.setenv("BOOJUM_TPU_LIMB_RESIDENT", "1")
-    monkeypatch.setenv("BOOJUM_TPU_LIMB_SWEEP", "0")
-    assert ps.limb_resident_enabled() is False  # no kernels, no residency
-    monkeypatch.delenv("BOOJUM_TPU_LIMB_SWEEP", raising=False)
-    with force_xla():
-        assert ps.limb_resident_enabled() is False
-    monkeypatch.setenv("BOOJUM_TPU_LIMB_RESIDENT", "maybe")
-    with pytest.raises(ValueError, match="BOOJUM_TPU_LIMB_RESIDENT"):
-        ps.limb_resident_enabled()
 
 
 # ---------------------------------------------------------------------------

@@ -11,10 +11,11 @@ interpret mode):
   non-canonical inputs; ext ops are canonical-domain);
 - per-kernel parity of the standalone sweep wrappers (gate terms, copy
   permutation, both lookup modes, FRI fold) against the u64 stage cores,
-  across tiled and non-tiled domain sizes;
-- the 2^10 end-to-end acceptance: proof bytes AND the flight-recorder
-  checkpoint stream are bit-identical under BOOJUM_TPU_LIMB_SWEEP=1 vs =0,
-  and the metrics counters prove the limb kernels actually dispatched.
+  across tiled and non-tiled domain sizes. The kernels take and return
+  (lo, hi) planes; the tests split the inputs and join the outputs.
+
+The whole-prove parity of the plane pipeline is tests/test_limb_resident.py
+(slow lane).
 """
 
 import functools
@@ -29,14 +30,6 @@ from boojum_tpu.field import gl
 from boojum_tpu.field import goldilocks as gf
 from boojum_tpu.field import limb_ops as lop
 from boojum_tpu.field import limbs
-from boojum_tpu.utils import report
-from proving import (
-    baseline,
-    checkpoint_stream,
-    interpret_e2e,
-    recorded_prove,
-    small_parts,
-)
 
 # values that stress every carry/borrow/canonicalization branch: around 0,
 # around p, around the 2^32 limb seam, and the non-canonical top band
@@ -304,7 +297,8 @@ def test_cp_quotient_kernel_parity(n):
         z, zs, partials, copy, sigma, xs, l0, b, g, a0, a1, chunks, ks
     )
     got = _jit(lambda *a: ps.cp_quotient(*a, chunks, ks))(
-        z, zs, partials, copy, sigma, xs, l0, b, g, a0, a1
+        _sx(z), _sx(zs), [_sx(p) for p in partials], _s(copy), _s(sigma),
+        _s(xs), _s(l0), b, g, a0, a1
     )
     _assert_ext_equal(got, ref, f"cp n={n}")
 
@@ -332,14 +326,16 @@ def test_lookup_quotient_kernel_parity(general):
             a_ldes, b_lde, cols, tid, tbl, mult, sel, b, g, a0, a1, R, w
         )
         got = _jit(lambda *a: ps.lookup_quotient_general(*a, R, w))(
-            a_ldes, b_lde, cols, tid, tbl, mult, sel, b, g, a0, a1
+            [_sx(a) for a in a_ldes], _sx(b_lde), _s(cols), _s(tid),
+            _s(tbl), _s(mult), _s(sel), b, g, a0, a1
         )
     else:
         ref = _lookup_quotient_core(
             a_ldes, b_lde, cols, tid, tbl, mult, b, g, a0, a1, R, w
         )
         got = _jit(lambda *a: ps.lookup_quotient(*a, R, w))(
-            a_ldes, b_lde, cols, tid, tbl, mult, b, g, a0, a1
+            [_sx(a) for a in a_ldes], _sx(b_lde), _s(cols), _s(tid),
+            _s(tbl), _s(mult), b, g, a0, a1
         )
     _assert_ext_equal(got, ref, f"lookup general={general}")
 
@@ -367,8 +363,8 @@ def test_gate_terms_kernel_parity(scan_threshold, monkeypatch):
         a0, a1 = _rnd(rng, reps), _rnd(rng, reps)
         ref = _build_gate_sweep(gates, paths, geom)(copy, None, const, a0, a1)
         limb_fn = ps.gate_terms_fn(gates, paths, geom)
-        got = _jit(lambda c, k, x, y: limb_fn(c, None, k, x, y))(
-            copy, const, a0, a1
+        got = _jit(lambda c, k, tb: limb_fn(c, None, k, tb))(
+            _s(copy), _s(const), ps._pack_table(a0, a1)
         )
         _assert_ext_equal(got, ref, f"gate threshold={scan_threshold}")
     finally:
@@ -379,95 +375,15 @@ def test_gate_terms_kernel_parity(scan_threshold, monkeypatch):
 @pytest.mark.parametrize("m", [512, 64])
 def test_fri_fold_kernel_parity(m):
     from boojum_tpu.prover import pallas_sweep as ps
-    from boojum_tpu.prover.fri import _fold_once_jit
+    from boojum_tpu.prover.fri import _ch_table_np, _fold_once_jit
     from boojum_tpu.prover.stages import ext_scalar
 
     rng = np.random.default_rng(13)
     vals = (_rnd(rng, m), _rnd(rng, m))
     invx = _rnd(rng, m // 2)
-    ch = ext_scalar(
-        tuple(int(v) for v in rng.integers(0, gl.P, 2, dtype=np.uint64))
+    ch = tuple(int(v) for v in rng.integers(0, gl.P, 2, dtype=np.uint64))
+    ref = _fold_once_jit(vals, ext_scalar(ch), invx)
+    got = _jit(ps.fri_fold_planes)(
+        _sx(vals), jnp.asarray(_ch_table_np(ch)), _s(invx)
     )
-    ref = _fold_once_jit(vals, ch, invx)
-    got = _jit(ps.fri_fold)(vals, ch, invx)
     _assert_ext_equal(got, ref, f"fold m={m}")
-
-
-def test_limb_sweep_enabled_dispatch(monkeypatch):
-    """On a non-TPU backend the limb sweep is opt-in (=1, interpret mode);
-    =0 always restores the u64 path; unset keeps the CPU default off."""
-    from boojum_tpu.prover import pallas_sweep as ps
-
-    monkeypatch.delenv("BOOJUM_TPU_LIMB_SWEEP", raising=False)
-    on_tpu = jax.default_backend() == "tpu"
-    assert ps.limb_sweep_enabled() is on_tpu
-    for v in ("1", "true", "on", "yes"):
-        monkeypatch.setenv("BOOJUM_TPU_LIMB_SWEEP", v)
-        assert ps.limb_sweep_enabled() is True
-    for v in ("0", "false", "off", "no"):
-        monkeypatch.setenv("BOOJUM_TPU_LIMB_SWEEP", v)
-        assert ps.limb_sweep_enabled() is False
-    monkeypatch.setenv("BOOJUM_TPU_LIMB_SWEEP", "maybe")
-    with pytest.raises(ValueError, match="BOOJUM_TPU_LIMB_SWEEP"):
-        ps.limb_sweep_enabled()
-    # the sharded pipeline must keep plain XLA (GSPMD cannot partition a
-    # pallas_call)
-    monkeypatch.setenv("BOOJUM_TPU_LIMB_SWEEP", "1")
-    from boojum_tpu.utils.pallas_util import force_xla
-
-    with force_xla():
-        assert ps.limb_sweep_enabled() is False
-
-
-# ---------------------------------------------------------------------------
-# End-to-end acceptance: 2^10 proof bytes + checkpoint stream identical
-# ---------------------------------------------------------------------------
-
-
-# The e2e pair is slow (proving.interpret_e2e says why). Tier-1 keeps the
-# same limb cores in the eight kernel parities above, and the limb kernel
-# set's enumeration and lowering in test_precompile.
-def _both_path_runs():
-    # the shared baseline is the u64 prove (the limb sweep is off on CPU
-    # unless asked for); it is proved first, so its caches never benefit
-    # from limb-run state
-    u64 = baseline()
-    limb = recorded_prove("limb", {"BOOJUM_TPU_LIMB_SWEEP": "1"})
-    return {"u64": u64, "limb": limb}
-
-
-@interpret_e2e
-def test_bit_parity_limb_vs_u64_2pow10():
-    """Acceptance: proof bytes AND the report.py checkpoint stream are
-    bit-identical with BOOJUM_TPU_LIMB_SWEEP=1 vs =0 — the limb kernels
-    change the REPRESENTATION the sweep computes in, never a value that
-    crosses the transcript."""
-    from boojum_tpu.prover import verify
-
-    runs = _both_path_runs()
-    p_u64, r_u64 = runs["u64"]
-    p_limb, r_limb = runs["limb"]
-    base = checkpoint_stream(r_u64)
-    assert base, "no checkpoints recorded"
-    assert checkpoint_stream(r_limb) == base
-    assert p_limb.to_json() == p_u64.to_json()
-    asm, setup, _config = small_parts()
-    assert verify(setup.vk, p_limb, asm.gates)
-    for rep in (r_u64, r_limb):
-        assert report.validate_report(rep) == []
-
-
-@interpret_e2e
-def test_limb_kernels_actually_dispatched():
-    """Metrics guard: the =1 run must have gone through the limb coset
-    sweep and the limb FRI folds (a silent fallback to u64 would make the
-    parity test vacuous)."""
-    runs = _both_path_runs()
-    c_u64 = runs["u64"][1]["metrics"]["counters"]
-    c_limb = runs["limb"][1]["metrics"]["counters"]
-    assert c_u64.get("quotient.limb_coset_sweeps", 0) == 0
-    assert c_u64.get("fri.limb_folds", 0) == 0
-    assert c_limb["quotient.limb_coset_sweeps"] == c_limb["quotient.coset_sweeps"]
-    assert c_limb["fri.limb_folds"] == c_limb["fri.folds"]
-    assert c_limb["quotient.limb_coset_sweeps"] > 0
-    assert c_limb["fri.limb_folds"] > 0

@@ -10,9 +10,10 @@ all_to_all per col->row Merkle pivot, one all_gather per cap), charged to
 - a 2^10 e2e prove produces bit-identical proof bytes AND digest
   checkpoint streams across {no mesh, 2x4 GSPMD mesh, 2x4 shard_map mesh
   with the limb kernels in interpret mode};
-- metrics guards that the shard_map limb kernels actually dispatched
-  (quotient.limb_coset_sweeps / fri.limb_folds / merkle.limb_leaf_sponges
-  nonzero) — without them the parity assertions would be vacuous;
+- metrics guards that the shard_map plane kernels actually dispatched
+  (quotient.resident_coset_sweeps / fri.resident_folds /
+  merkle.resident_commits nonzero) — without them the parity assertions
+  would be vacuous;
 - the new ici.* byte/time gauges appear in the ProveReport line and
   report.validate_report (the `prove_report.py --check` gate) validates
   them;
@@ -48,16 +49,19 @@ pytestmark = pytest.mark.skipif(
 # lowering (test_precompile) and the limb cores (test_limb_sweep).
 def _three_mode_runs():
     # the shared meshless baseline is proved first, so its caches never
-    # benefit from mesh-run state; the shard_map run forces the limb
-    # kernels (interpret mode on CPU) so the parity covers the per-chip
-    # Pallas path, not an XLA fallback
+    # benefit from mesh-run state; the shard_map run asks for the plane
+    # representation (interpret mode on CPU) so the parity covers the
+    # per-chip Pallas path the TPU runs, not the u64 XLA kernels
     nomesh = baseline()
     gspmd = recorded_prove(
         "gspmd", {"BOOJUM_TPU_MESH_MODE": "gspmd"}, mesh=_mesh()
     )
     sm = recorded_prove(
         "sm",
-        {"BOOJUM_TPU_MESH_MODE": "shard_map", "BOOJUM_TPU_LIMB_SWEEP": "1"},
+        {
+            "BOOJUM_TPU_MESH_MODE": "shard_map",
+            "BOOJUM_TPU_LIMB_RESIDENT": "1",
+        },
         mesh=_mesh(),
     )
     return {"nomesh": nomesh, "gspmd": gspmd, "sm": sm}
@@ -84,25 +88,28 @@ def test_three_mode_bit_parity_2pow10():
 @interpret_e2e
 def test_sm_limb_kernels_actually_dispatched():
     """Metrics guard: the shard_map run must have gone through the
-    per-chip limb coset sweep, the limb FRI folds AND the fused limb leaf
-    sponges — a silent fallback to u64/XLA or to GSPMD would make the
+    per-chip plane coset sweep, the plane FRI folds AND the plane
+    commits — a silent fallback to u64/XLA or to GSPMD would make the
     parity test vacuous."""
     runs = _three_mode_runs()
     c_sm = runs["sm"][1]["metrics"]["counters"]
     c_g = runs["gspmd"][1]["metrics"]["counters"]
-    assert c_sm["quotient.limb_coset_sweeps"] == c_sm["quotient.coset_sweeps"]
-    assert c_sm["quotient.limb_coset_sweeps"] > 0
-    assert c_sm["fri.limb_folds"] == c_sm["fri.folds"] > 0
-    assert c_sm["merkle.limb_leaf_sponges"] > 0
+    assert (
+        c_sm["quotient.resident_coset_sweeps"]
+        == c_sm["quotient.coset_sweeps"]
+    )
+    assert c_sm["quotient.resident_coset_sweeps"] > 0
+    assert c_sm["fri.resident_folds"] == c_sm["fri.folds"] > 0
+    assert c_sm["merkle.resident_commits"] > 0
     assert c_sm["merkle.sm_commits"] > 0
     assert c_sm["fri.sm_commits"] > 0
     assert c_sm["fri.sm_folds"] > 0
     assert c_sm["deep.sm_codewords"] == 1
     # GSPMD cannot partition a pallas_call: the legacy mode must NOT have
-    # dispatched any limb or shard_map kernel
+    # dispatched any plane or shard_map kernel
     for k in (
-        "quotient.limb_coset_sweeps", "fri.limb_folds",
-        "merkle.limb_leaf_sponges", "merkle.sm_commits", "fri.sm_commits",
+        "quotient.resident_coset_sweeps", "fri.resident_folds",
+        "merkle.resident_commits", "merkle.sm_commits", "fri.sm_commits",
     ):
         assert c_g.get(k, 0) == 0, k
 
@@ -154,7 +161,7 @@ def test_streamed_sm_bit_parity_2pow10():
         "sm_stream",
         {
             "BOOJUM_TPU_MESH_MODE": "shard_map",
-            "BOOJUM_TPU_LIMB_SWEEP": "1",
+            "BOOJUM_TPU_LIMB_RESIDENT": "1",
             "BOOJUM_TPU_STREAM_LDE": "1",
         },
         mesh=_mesh(),
@@ -165,33 +172,6 @@ def test_streamed_sm_bit_parity_2pow10():
     assert c["stream.sm_blocks"] > 0
     assert c["merkle.streamed_commits"] > 0
     assert report.validate_report(r) == []
-
-
-def test_mesh_mode_dispatch(monkeypatch):
-    """mesh_mode(): None without a mesh; shard_map by default on a
-    single-process mesh; BOOJUM_TPU_MESH_MODE forces either mode and junk
-    raises (a typo must never silently pick a mode)."""
-    from boojum_tpu.parallel.sharding import (
-        mesh_mode,
-        prover_mesh,
-        shard_map_mesh,
-    )
-
-    monkeypatch.delenv("BOOJUM_TPU_MESH_MODE", raising=False)
-    assert mesh_mode() is None
-    assert shard_map_mesh() is None
-    m = _mesh()
-    with prover_mesh(m):
-        assert mesh_mode() == "shard_map"
-        assert shard_map_mesh() is m
-        monkeypatch.setenv("BOOJUM_TPU_MESH_MODE", "gspmd")
-        assert mesh_mode() == "gspmd"
-        assert shard_map_mesh() is None
-        monkeypatch.setenv("BOOJUM_TPU_MESH_MODE", "sm")
-        assert mesh_mode() == "shard_map"
-        monkeypatch.setenv("BOOJUM_TPU_MESH_MODE", "fast")
-        with pytest.raises(ValueError, match="BOOJUM_TPU_MESH_MODE"):
-            mesh_mode()
 
 
 def test_shard_cols_fallback_warning(caplog):

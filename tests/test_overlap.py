@@ -4,10 +4,10 @@ prefetch — all on the CPU backend with the 2^10 acceptance circuit.
 
 Pins the acceptance criteria:
 - proof bytes AND the Fiat–Shamir digest checkpoint stream are
-  bit-identical across the overlapped / sequenced / streamed paths;
-- the overlapped prove issues STRICTLY FEWER blocking host syncs than
-  the sequenced baseline (metrics guard — the win can't silently
-  regress);
+  bit-identical across the overlapped (materialized) and streamed paths;
+- the overlapped prove issues the number of blocking host syncs it was
+  measured to issue (metrics guard — the win over the deleted sequenced
+  order can't silently regress);
 - a raise inside a streamed commit block still yields a partial
   ProveReport (error-annotated span tree + the checkpoints up to the
   failure).
@@ -31,20 +31,6 @@ from proving import (
 # ---------------------------------------------------------------------------
 
 
-def test_overlap_enabled_parsing(monkeypatch):
-    monkeypatch.delenv("BOOJUM_TPU_OVERLAP", raising=False)
-    assert transfer.overlap_enabled() is True  # default on
-    for v in ("1", "true", "on", "yes"):
-        monkeypatch.setenv("BOOJUM_TPU_OVERLAP", v)
-        assert transfer.overlap_enabled() is True
-    for v in ("0", "false", "off", "no"):
-        monkeypatch.setenv("BOOJUM_TPU_OVERLAP", v)
-        assert transfer.overlap_enabled() is False
-    monkeypatch.setenv("BOOJUM_TPU_OVERLAP", "maybe")
-    with pytest.raises(ValueError, match="BOOJUM_TPU_OVERLAP"):
-        transfer.overlap_enabled()
-
-
 def test_to_host_passthrough_and_device_counting():
     host = np.arange(7, dtype=np.uint64)
     reg = metrics.start_metrics()
@@ -61,13 +47,12 @@ def test_to_host_passthrough_and_device_counting():
         metrics.stop_metrics()
 
 
-def test_fetch_batches_one_blocking_sync(monkeypatch):
+def test_fetch_batches_one_blocking_sync():
     arrays = [
         jnp.asarray(np.arange(16, dtype=np.uint64)),
         jnp.asarray(np.arange(16, 48, dtype=np.uint64)),
         jnp.asarray(np.arange(3, dtype=np.uint64)),
     ]
-    monkeypatch.setenv("BOOJUM_TPU_OVERLAP", "1")
     reg = metrics.start_metrics()
     try:
         got = transfer.fetch_np(*arrays, label="unit")
@@ -81,17 +66,6 @@ def test_fetch_batches_one_blocking_sync(monkeypatch):
         metrics.stop_metrics()
     for a, h in zip(arrays, got):
         np.testing.assert_array_equal(np.asarray(a), h)
-
-    # sequenced twin: one blocking sync PER array
-    monkeypatch.setenv("BOOJUM_TPU_OVERLAP", "0")
-    reg = metrics.start_metrics()
-    try:
-        got2 = transfer.fetch_np(*arrays)
-        assert reg.counters["host.blocking_syncs"] == len(arrays)
-    finally:
-        metrics.stop_metrics()
-    for a, b in zip(got, got2):
-        np.testing.assert_array_equal(a, b)
 
     # wait() is idempotent
     f = transfer.start_fetch(arrays)
@@ -108,17 +82,12 @@ def test_chunked_upload_parity(monkeypatch):
     ref = np.concatenate(groups, axis=0)
     # force multi-chunk uploads (2 rows per chunk at n=64)
     monkeypatch.setattr(transfer, "H2D_CHUNK_BYTES", 2 * 64 * 8)
-    monkeypatch.setenv("BOOJUM_TPU_OVERLAP", "1")
     got = transfer.chunked_upload(groups)
     np.testing.assert_array_equal(np.asarray(got), ref)
     # the chunk plan helper mirrors the dispatch exactly
     shapes = transfer.upload_chunk_shapes([g.shape[0] for g in groups], 64)
     assert sum(shapes) == ref.shape[0]
     assert shapes == [2, 2, 1, 2, 1, 1]
-    # overlap off: the legacy single synchronous upload, same bytes
-    monkeypatch.setenv("BOOJUM_TPU_OVERLAP", "0")
-    got_seq = transfer.chunked_upload(groups)
-    np.testing.assert_array_equal(np.asarray(got_seq), ref)
 
 
 def test_render_report_shows_occupancy():
@@ -155,37 +124,32 @@ def test_render_report_shows_occupancy():
 
 
 # ---------------------------------------------------------------------------
-# End-to-end: overlapped vs sequenced vs streamed 2^10 proves
+# End-to-end: overlapped (materialized) vs streamed 2^10 proves
 # ---------------------------------------------------------------------------
 
 
-def _three_path_runs():
-    # sequenced FIRST so its counters never benefit from state the
-    # overlapped run warmed; the overlapped run is the shared baseline
-    # (overlap is on unless BOOJUM_TPU_OVERLAP=0)
-    seq = recorded_prove("sequenced", {"BOOJUM_TPU_OVERLAP": "0"})
+def _two_path_runs():
+    # the shared baseline is the overlapped prove (the only order there is)
     ovl = baseline()
     streamed = recorded_prove("streamed", {"BOOJUM_TPU_STREAM_LDE": "1"})
-    return {"sequenced": seq, "overlapped": ovl, "streamed": streamed}
+    return {"overlapped": ovl, "streamed": streamed}
 
 
-def test_bit_parity_overlapped_sequenced_streamed():
+def test_bit_parity_overlapped_streamed():
     """Acceptance: proof bytes and the PR-2 checkpoint stream are
-    bit-identical across all three dispatch orders — the overlap layer
-    changes WHEN work is enqueued, never what is absorbed."""
+    bit-identical across the materialized and the streamed (double-
+    buffered) commits — the overlap layer changes WHEN work is enqueued,
+    never what is absorbed."""
     from boojum_tpu.prover import verify
 
-    runs = _three_path_runs()
-    p_seq, r_seq = runs["sequenced"]
+    runs = _two_path_runs()
     p_ovl, r_ovl = runs["overlapped"]
     p_str, r_str = runs["streamed"]
 
-    base = checkpoint_stream(r_seq)
+    base = checkpoint_stream(r_ovl)
     assert base, "no checkpoints recorded"
-    assert checkpoint_stream(r_ovl) == base
     assert checkpoint_stream(r_str) == base
-    assert p_ovl.to_json() == p_seq.to_json()
-    assert p_str.to_json() == p_seq.to_json()
+    assert p_str.to_json() == p_ovl.to_json()
 
     asm, setup, _config = small_parts()
     assert verify(setup.vk, p_ovl, asm.gates)
@@ -193,25 +157,20 @@ def test_bit_parity_overlapped_sequenced_streamed():
         assert report.validate_report(rep) == []
 
 
-def test_overlapped_prove_strictly_fewer_blocking_syncs():
-    """CI guard (acceptance): the overlapped path must issue strictly
-    fewer blocking host syncs than the sequenced path — counted at the
+def test_overlapped_prove_blocking_syncs_pinned():
+    """CI guard (acceptance): the prove issues exactly the blocking host
+    syncs and d2h batches the overlapped order was measured to issue at
+    2^10 rows (8 and 2, read off PR 27's tree before the sequenced order
+    went; that order paid one sync per pulled array) — counted at the
     single d2h seam (utils/transfer.py), so a regression that quietly
     re-serializes a pull flips this test."""
-    runs = _three_path_runs()
-    seq = runs["sequenced"][1]["metrics"]["counters"]
-    ovl = runs["overlapped"][1]["metrics"]["counters"]
-    assert seq.get("host.blocking_syncs", 0) > 0
-    assert ovl.get("host.blocking_syncs", 0) > 0
-    assert ovl["host.blocking_syncs"] < seq["host.blocking_syncs"]
-    # the saving must come from batching, not from skipped transfers:
-    # both paths move the same d2h bytes
-    assert ovl["transfer.d2h_bytes"] == seq["transfer.d2h_bytes"]
-    assert ovl.get("transfer.d2h_batches", 0) >= 2  # round 4 + FRI final
+    ovl = _two_path_runs()["overlapped"][1]["metrics"]["counters"]
+    assert ovl["host.blocking_syncs"] == 8
+    assert ovl["transfer.d2h_batches"] == 2  # round 4 + FRI final
 
 
 def test_overlapped_report_carries_overlap_metrics():
-    runs = _three_path_runs()
+    runs = _two_path_runs()
     r_ovl = runs["overlapped"][1]
     gauges = r_ovl["metrics"]["gauges"]
     assert gauges.get("transfer.overlap_s", 0) > 0
@@ -231,7 +190,6 @@ def test_error_in_streamed_block_yields_partial_report(monkeypatch):
     from boojum_tpu.prover import streaming
 
     asm, setup, config = small_parts()
-    monkeypatch.setenv("BOOJUM_TPU_OVERLAP", "1")
     monkeypatch.setenv("BOOJUM_TPU_STREAM_LDE", "1")
 
     real_absorb = streaming._absorb_cols

@@ -92,39 +92,6 @@ def test_sha_geometry_enumerates_the_pick_not_the_transforms(
     assert "dynamic_slice" in pick.fn.lower(*pick.args).as_text()
 
 
-def test_limb_sweep_kernels_enumerate_and_lower(monkeypatch):
-    """ISSUE 4 satellite: with BOOJUM_TPU_LIMB_SWEEP=1 the enumeration
-    swaps in the limb-variant sweep kernels (the fused u32-limb Pallas
-    coset sweep and the limb FRI folds), they LOWER on CPU (interpret
-    mode traces cleanly) and land in the compile ledger under their
-    limb-tagged names."""
-    from boojum_tpu.prover.precompile import enumerate_kernels, precompile
-
-    monkeypatch.setenv("BOOJUM_TPU_LIMB_SWEEP", "1")
-    asm, cfg = fma_assembly(), small_config()
-    specs = enumerate_kernels(asm, cfg)
-    names = [s.name for s in specs]
-    assert "coset_sweep_terms_limb" in names
-    assert "coset_sweep_terms" not in names  # only the dispatched variant
-    limb_folds = [n for n in names if n.startswith("fri_fold_limb_")]
-    assert limb_folds, names
-    assert not any(
-        n.startswith("fri_fold_k") for n in names
-    ), "u64 fold variant enumerated alongside the limb one"
-
-    ledger = CompileLedger()
-    precompile(asm, cfg, ledger=ledger, lower_only=True)
-    by_name = {e["name"]: e for e in ledger.entries}
-    for name in ["coset_sweep_terms_limb"] + limb_folds:
-        assert name in by_name, name
-        assert "error" not in by_name[name], by_name[name]
-
-    # flag off: the same enumeration returns to the u64 names
-    monkeypatch.setenv("BOOJUM_TPU_LIMB_SWEEP", "0")
-    names_u64 = [s.name for s in enumerate_kernels(asm, cfg)]
-    assert "coset_sweep_terms" in names_u64
-
-
 def test_limb_resident_kernels_enumerate_and_lower(monkeypatch):
     """ISSUE 10 satellite: with BOOJUM_TPU_LIMB_RESIDENT=1 the enumeration
     swaps to the RESIDENT plane-kernel set (`*_limbres` ledger names —
@@ -139,7 +106,6 @@ def test_limb_resident_kernels_enumerate_and_lower(monkeypatch):
     names = [s.name for s in specs]
     assert "coset_sweep_terms_limbres" in names
     assert "coset_sweep_terms" not in names
-    assert "coset_sweep_terms_limb" not in names
     res_folds = [n for n in names if n.startswith("fri_fold_limbres_")]
     assert res_folds, names
     assert not any(n.startswith("fri_fold_k") for n in names)
@@ -169,13 +135,12 @@ def test_limb_resident_kernels_enumerate_and_lower(monkeypatch):
     # never serve a converting process)
     from boojum_tpu.prover.aot import variant_fingerprint
 
-    assert variant_fingerprint()["limb_resident"] is True
+    assert variant_fingerprint()["representation"] == "planes"
     monkeypatch.setenv("BOOJUM_TPU_LIMB_RESIDENT", "0")
-    assert variant_fingerprint()["limb_resident"] is False
+    assert variant_fingerprint()["representation"] == "u64"
     names_u64 = [s.name for s in enumerate_kernels(asm, cfg)]
     assert "coset_sweep_terms" in names_u64
     assert "coset_sweep_terms_limbres" not in names_u64
-    assert "coset_sweep_terms_limb" not in names_u64
 
 
 def test_mesh_shard_map_kernels_enumerate_and_lower(monkeypatch):
@@ -193,7 +158,6 @@ def test_mesh_shard_map_kernels_enumerate_and_lower(monkeypatch):
         pytest.skip("needs 8 virtual devices")
     from boojum_tpu.prover.precompile import enumerate_kernels, precompile
 
-    monkeypatch.delenv("BOOJUM_TPU_LIMB_SWEEP", raising=False)
     asm, cfg = fma_assembly(), small_config()
     specs = enumerate_kernels(asm, cfg, mesh_shape=(2, 4))
     names = [s.name for s in specs]
