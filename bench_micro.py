@@ -10,6 +10,7 @@ All metrics chain reps ON DEVICE inside one dispatch (jax.lax.fori_loop), so
 a reading is the chip's time and not the host's per-launch overhead.
 
 Usage: python bench_micro.py  (JSON lines on stdout; backend = ambient JAX)
+       python bench_micro.py poseidon2  (the Poseidon2 section alone)
 """
 
 import json
@@ -101,15 +102,7 @@ def main():
             backend=backend,
         )
 
-    # Poseidon2 permutation
-    from boojum_tpu.hashes.poseidon2 import poseidon2_permutation
-
-    st = _rand((1 << 18, 12), 40)
-    dt = timed_chain(poseidon2_permutation, st, 4)
-    emit(
-        "poseidon2_perms_per_s", int((1 << 18) / dt), "perms/s",
-        backend=backend,
-    )
+    poseidon2_section(backend)
 
     # batch inversion
     b = _rand((1 << 20,), 50)
@@ -124,6 +117,101 @@ def main():
     resident_section(backend)
     field_section(backend)
     mesh_section(backend)
+
+
+P2_TILES = (8, 16, 32, 64, 128, 256)
+P2_CHUNKS = (1, 2, 6, 8, 12, 20)  # node, quotient, stage-2, witness leaves
+
+
+def poseidon2_jobs(leaves):
+    """The Poseidon2 kernels by grid step: (kind, chunks, tile rows,
+    leaves, lowered) for `_sponge_planes` at every (chunks, tile) and
+    `_permute_planes` (chunks 0) at every tile."""
+    from boojum_tpu.hashes import pallas_poseidon2 as pp2
+
+    jobs = []
+    for n in leaves:
+        R = n // 128
+        for tile in P2_TILES:
+            if R % tile:
+                continue
+            for chunks in P2_CHUNKS:
+                v = jax.ShapeDtypeStruct((8 * chunks, R, 128), jnp.uint32)
+                low = pp2._sponge_planes.lower(v, v, chunks, tile, False)
+                jobs.append(("sponge", chunks, tile, n, low))
+            s = jax.ShapeDtypeStruct((12, R, 128), jnp.uint32)
+            low = pp2._permute_planes.lower(s, s, tile, False)
+            jobs.append(("permute", 0, tile, n, low))
+    return jobs
+
+
+def compile_pool(jobs, workers=12):
+    """Compile the lowered programs on a pool; [(compiled or the error's
+    first line, seconds)] in the jobs' order."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    def one(job):
+        t0 = time.perf_counter()
+        try:
+            out = job[-1].compile()
+        except Exception as e:  # a tile the VMEM cap refuses
+            out = (str(e).strip().splitlines() or [type(e).__name__])[0][:160]
+        return out, time.perf_counter() - t0
+
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        return list(pool.map(one, jobs))
+
+
+def poseidon2_section(backend, leaves=(1 << 19, 1 << 17)):
+    """Poseidon2: the dispatcher's permutation, and on the TPU perms/s of
+    the leaf sponge and the bare permutation kernel by (chunks, tile rows),
+    with each kernel's compile seconds (ISSUE 29's sweep: the rate is set
+    by the grid step's rows, `pallas_poseidon2.step_rows` picks them). The
+    kernels compile on a pool first; a tile the VMEM cap refuses is a line
+    with `error`."""
+    from boojum_tpu.hashes.poseidon2 import poseidon2_permutation
+
+    st = _rand((1 << 18, 12), 40)
+    dt = timed_chain(poseidon2_permutation, st, 4)
+    emit(
+        "poseidon2_perms_per_s", int((1 << 18) / dt), "perms/s",
+        backend=backend,
+    )
+    if backend != "tpu":
+        return  # interpret-mode kernels compile for minutes on XLA:CPU
+    jobs = poseidon2_jobs(leaves)
+    built = compile_pool(jobs)
+    rng = np.random.default_rng(41)
+
+    def planes(n):
+        # canonical limbs: hi < 2^32 - 1 keeps every value below p
+        shape = (8 * max(P2_CHUNKS), n // 128, 128)
+        lo = rng.integers(0, 1 << 32, shape, dtype=np.uint32)
+        hi = rng.integers(0, (1 << 32) - 1, shape, dtype=np.uint32)
+        return jnp.asarray(lo), jnp.asarray(hi)
+
+    inputs = {n: planes(n) for n in leaves}
+    first = {}
+    for (kind, chunks, tile, n, _), (exe, secs) in zip(jobs, built):
+        line = dict(
+            kind=kind, chunks=chunks, tile_rows=tile, leaves=n,
+            compile_s=round(secs, 2), backend=backend,
+        )
+        rate = 0
+        if isinstance(exe, str):
+            line["error"] = exe
+        else:
+            rows = 8 * chunks or 12
+            args = tuple(p[:rows] for p in inputs[n])
+            out = exe(*args)
+            ref = first.setdefault((kind, chunks, n), out)
+            line["equal_to_first_tile"] = all(
+                bool(jnp.array_equal(a, b)) for a, b in zip(out, ref)
+            )
+            dt = timed_call(exe, args)
+            line["ms"] = round(dt * 1e3, 3)
+            rate = int(n * max(chunks, 1) / dt)
+        emit("poseidon2_kernel_perms_per_s", rate, "perms/s", **line)
 
 
 def timed_call(fn, args, reps=3):
@@ -631,4 +719,7 @@ def mesh_section(backend):
 
 
 if __name__ == "__main__":
-    main()
+    if sys.argv[1:] == ["poseidon2"]:  # that section alone, one chip call
+        poseidon2_section(jax.default_backend())
+    else:
+        main()

@@ -3,10 +3,17 @@
 The TPU counterpart of the reference's AVX-512 Poseidon2 state
 (`/root/reference/src/implementations/poseidon2/state_avx512.rs`): where that
 packs the width-12 state into 512-bit registers and keeps a whole permutation
-in-register, this kernel keeps a (12, TILE, 128) tile of states resident in
-VMEM for all 30 rounds — one HBM read and one write per permutation batch,
-instead of one round-trip per round (what the staged XLA version pays when the
-fused graph exceeds the fusion horizon).
+in-register, this kernel keeps a (12, TILE, 128) tile of states on the core
+for all 30 rounds: one HBM read and one write per permutation batch, instead
+of one round-trip per round (what the staged XLA version pays when the fused
+graph exceeds the fusion horizon). Every tile gives that; what the tile
+decides is whether the rounds run in the vector registers or through VMEM.
+At 8 rows a plane of the state is 12 vregs and the (lo, hi) state 24 of the
+core's 64; at 256 rows it is 768, and every one of a permutation's ~94 k u32
+operations loads and stores VMEM. `step_rows` therefore picks the smallest
+legal tile for every call (PERF.md section 6, PR 29, has the sweep: the rate
+is set by the tile, whatever the chunk count), which is also the fastest to
+compile: Mosaic unrolls each operation over TILE / 8 vregs a plane row.
 
 Layout: the batch axis is tiled (rows x 128 lanes); the state axis (12) and
 the limb axis (2) are leading dims, so every field op is an elementwise VPU op
@@ -191,7 +198,8 @@ from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 
 from ..utils.pallas_util import imap32  # noqa: E402
 
-# wide-leaf sponge tiles exceed the default 16 MiB scoped-vmem budget
+# an 8-row step of the widest leaf (1024 values, both planes, two buffers
+# each) is 16 MiB of blocks alone: over the default scoped-vmem budget
 from ..utils.pallas_util import tpu_compiler_params  # noqa: E402
 
 _CP = tpu_compiler_params(64 * 1024 * 1024)
@@ -263,9 +271,25 @@ _LANE = 128
 _MIN_BATCH = 1024  # below this the XLA path wins (kernel launch overhead)
 
 
+def step_rows(num_chunks: int, R: int) -> int:
+    """Rows of one grid step, for a call that absorbs `num_chunks` chunks
+    (1 for the bare permutation and for a node) over R sublane rows: the
+    one rule behind every call of `_sponge_planes` and `_permute_planes`.
+
+    8 rows, the smallest legal sublane tile, whenever 8 divides R: a plane
+    of the state is then 12 vregs and the rounds run in registers (module
+    docstring; the rate falls with every doubling of the step, at every
+    chunk count). Where no multiple of 8 divides R the whole axis is the
+    one legal block, and the VMEM budget bounds it as it bounded every
+    tile before: `pick_tile` raises past it."""
+    if R % 8 == 0:
+        return 8
+    return _pick_tile(R, max(8, (2 << 20) // (8 * num_chunks * _LANE * 8)))
+
+
 def batch_fits(n: int) -> bool:
-    # n % 1024 guarantees a row count with a legal sublane tile (multiple
-    # of 8) whenever the batch exceeds the per-step VMEM budget
+    # n % 1024: the row count is a multiple of 8, so `step_rows` has its
+    # 8-row sublane tile
     return n >= _MIN_BATCH and n % (8 * _LANE) == 0
 
 
@@ -284,8 +308,7 @@ def permutation_planes(state_p, interpret: bool = False):
     # (N, 12) -> (12, R, 128) plane layout
     lo = slo.T.reshape(12, R, _LANE)
     hi = shi.T.reshape(12, R, _LANE)
-    tile = _pick_tile(R, 16)
-    olo, ohi = _permute_planes(lo, hi, tile, interpret)
+    olo, ohi = _permute_planes(lo, hi, step_rows(1, R), interpret)
     return olo.reshape(12, n).T, ohi.reshape(12, n).T
 
 
@@ -302,12 +325,7 @@ def sponge_hash_planes(values_p, interpret: bool = False):
         pad = jnp.zeros((8 * num_chunks - L, R, _LANE), jnp.uint32)
         vlo = jnp.concatenate([vlo, pad], axis=0)
         vhi = jnp.concatenate([vhi, pad], axis=0)
-    # VMEM budget: (L + out + temps) * tile * 128 * 4B * 2 planes. Floor at
-    # 8 (the minimum legal sublane tile): wide leaves simply use more VMEM
-    # per step — the raised compiler vmem cap covers L up to ~1024, and the
-    # leaf_hash dispatcher falls back to XLA beyond that.
-    budget = max(8, (2 << 20) // max(8 * num_chunks * _LANE * 8, 1))
-    tile = _pick_tile(R, budget)
+    tile = step_rows(num_chunks, R)
     olo, ohi = _sponge_planes(vlo, vhi, num_chunks, tile, interpret)
     return olo.reshape(4, n).T, ohi.reshape(4, n).T
 

@@ -86,27 +86,40 @@ def _jit_static(fn, *static):
     return jax.jit(lambda *arrays: fn(*arrays, *static))
 
 
-@pytest.mark.parametrize("L", [64, 128])
+# 64 / 128: column blocks of the oracles' leaves; 16: the quotient's
+# leaves (2 chunks)
+@pytest.mark.parametrize("L", [64, 128, 16])
 def test_poseidon2_leaf_sponge(one_chip, L):
-    """The leaf sponge over 2^19 leaves of an L-wide column block (the
-    oracles absorb their columns in such blocks), with the tile
-    sponge_hash_planes picks for that width."""
+    """The leaf sponge over 2^19 leaves of an L-wide column block, at the
+    grid step the kernel's one rule picks for that shape."""
     from boojum_tpu.hashes import pallas_poseidon2 as p2
 
     R = LEAVES // 128
     chunks = L // 8
-    tile = p2._pick_tile(R, max(8, (2 << 20) // (8 * chunks * 128 * 8)))
     v = _u32(one_chip, L, R, 128)
-    _compile(_jit_static(p2._sponge_planes, chunks, tile, False), v, v)
+    _compile(
+        _jit_static(p2._sponge_planes, chunks, p2.step_rows(chunks, R), False),
+        v, v,
+    )
+
+
+def test_poseidon2_node_sponge(one_chip):
+    """The first node layer under 2^19 leaves: 2^18 nodes, each one chunk
+    (two digests), through the same sponge."""
+    from boojum_tpu.hashes import pallas_poseidon2 as p2
+
+    R = LEAVES // 2 // 128
+    v = _u32(one_chip, 8, R, 128)
+    _compile(_jit_static(p2._sponge_planes, 1, p2.step_rows(1, R), False), v, v)
 
 
 def test_poseidon2_node_permutation(one_chip):
-    """One Merkle node layer: 2^19 states as (12, 4096, 128) planes."""
+    """The bare permutation: 2^19 states as (12, 4096, 128) planes."""
     from boojum_tpu.hashes import pallas_poseidon2 as p2
 
     R = LEAVES // 128
     s = _u32(one_chip, 12, R, 128)
-    _compile(_jit_static(p2._permute_planes, p2._pick_tile(R, 16), False), s, s)
+    _compile(_jit_static(p2._permute_planes, p2.step_rows(1, R), False), s, s)
 
 
 def _mxu_planes(one_chip, lead):

@@ -193,6 +193,74 @@ class TestPoseidon2Kernel:
         assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
+# The grid step's rows (ISSUE 29): one rule, `pallas_poseidon2.step_rows`.
+# Shapes are the three cells' own calls: 20 / 12 chunks the witness leaves
+# (Era, anchor), 8 / 6 stage 2, 2 the quotient, 1 a node layer, at 2^19
+# leaves (R 4096; the first node layer has 2^18 nodes, R 2048) and at the
+# small cell's 2^17 (R 1024, 512).
+@pytest.mark.parametrize(
+    "chunks, R, want",
+    [
+        (20, 4096, 8), (12, 4096, 8), (8, 4096, 8),
+        (6, 4096, 8), (2, 4096, 8), (1, 2048, 8),
+        (20, 1024, 8), (12, 1024, 8), (8, 1024, 8),
+        (6, 1024, 8), (2, 1024, 8), (1, 512, 8),
+        (1, 8, 8),  # the smallest batch the dispatchers send (1024 states)
+        (1, 2, 2), (3, 4, 4),  # R < 8: the whole axis
+        (1, 12, 12), (2, 100, 100),  # no multiple of 8 divides R
+        # the widest leaf the dispatcher sends (1024 values): 8 rows where
+        # they are legal; a whole axis past the VMEM budget is refused
+        (128, 4096, 8), (128, 12, ValueError), (20, 100, ValueError),
+    ],
+)
+def test_poseidon2_step_rows(chunks, R, want):
+    from boojum_tpu.hashes import pallas_poseidon2 as pp2
+
+    if want is ValueError:
+        with pytest.raises(ValueError):
+            pp2.step_rows(chunks, R)
+        return
+    tile = pp2.step_rows(chunks, R)
+    assert tile == want
+    # legal for Mosaic: divides R, and a multiple of 8 or R itself
+    assert R % tile == 0 and (tile % 8 == 0 or tile == R)
+
+
+@pytest.fixture(scope="module")
+def tile_cases():
+    """Inputs of 8192 states (R 64: every tile below divides it) with the
+    digests `test_sponge_interpret` / `test_permutation_interpret` hold
+    the kernels to: the u64 XLA twins, and the host's python integers on
+    the edge states."""
+    from boojum_tpu.hashes import poseidon2 as p2
+
+    state = np.concatenate([_rand((8192 - 12, 12), 25), _edge_states()])
+    vals = _rand((8192, 21), 26)  # three chunks, the last one padded
+    return {
+        "permute": (state, np.asarray(
+            p2.poseidon2_permutation_xla(jnp.asarray(state)))),
+        "sponge": (vals, np.asarray(p2.leaf_hash_xla(jnp.asarray(vals)))),
+    }
+
+
+@pytest.mark.parametrize("tile", [8, 16, 64])
+@pytest.mark.parametrize("kernel", ["permute", "sponge"])
+def test_poseidon2_kernels_equal_at_every_tile(
+    tile_cases, monkeypatch, kernel, tile
+):
+    """`_permute_planes` and `_sponge_planes` (interpret mode) give the
+    same digests whatever the grid step: the rule is free to choose."""
+    from boojum_tpu.hashes import pallas_poseidon2 as pp2
+
+    monkeypatch.setattr(pp2, "step_rows", lambda chunks, R: tile)
+    data, want = tile_cases[kernel]
+    fn = pp2.permutation if kernel == "permute" else pp2.sponge_hash
+    got = np.asarray(fn(jnp.asarray(data), interpret=True))
+    assert np.array_equal(got, want)
+    if kernel == "permute":
+        assert np.array_equal(got[-12:], _host_permutation(data[-12:]))
+
+
 class TestMXUNTTKernel:
     """Bit-parity of the MXU matmul-NTT (ntt/mxu_ntt.py) vs the staged-XLA
     path. Interpret mode executes the same exact-integer int8/i32 ops on
