@@ -363,7 +363,15 @@ def _np_array_u64(vals):
     return _np.array([int(v) % gl.P for v in vals], dtype=_np.uint64)
 
 
+_PROGRAM_CACHE: dict = {}
 _PACKED_CACHE: dict = {}
+
+
+def program_for(gate) -> GateProgram:
+    """`capture_gate_program(gate)`, captured once a gate."""
+    if gate.name not in _PROGRAM_CACHE:
+        _PROGRAM_CACHE[gate.name] = capture_gate_program(gate)
+    return _PROGRAM_CACHE[gate.name]
 
 
 def packed_program_for(gate, threshold: int | None = None):
@@ -378,7 +386,7 @@ def packed_program_for(gate, threshold: int | None = None):
         )
     key = (gate.name, threshold)
     if key not in _PACKED_CACHE:
-        prog = capture_gate_program(gate)
+        prog = program_for(gate)
         _PACKED_CACHE[key] = (
             pack_for_scan(prog) if len(prog.ops) > threshold else None
         )

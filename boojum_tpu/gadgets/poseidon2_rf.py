@@ -62,3 +62,19 @@ def circuit_hash_node(cs, left, right):
     sp = CircuitPoseidon2Sponge(cs)
     sp.absorb(list(left) + list(right))
     return sp.finalize(CAPACITY)
+
+
+def circuit_merkle_root(cs, leaf_vars, n=CAPACITY):
+    """Root of the binary Merkle tree over `leaf_vars` (a power-of-two
+    number of variable lists): `circuit_hash_leaf` on each leaf, then node
+    hashes (two digests, one permutation) layer by layer to one digest of
+    `n` variables: what the recursion's tree hasher does in a verifier
+    circuit (reference gadgets/recursion/recursive_tree_hasher.rs)."""
+    layer = [circuit_hash_leaf(cs, leaf, n) for leaf in leaf_vars]
+    assert layer and len(layer) & (len(layer) - 1) == 0, len(layer)
+    while len(layer) > 1:
+        layer = [
+            circuit_hash_node(cs, layer[i], layer[i + 1])
+            for i in range(0, len(layer), 2)
+        ]
+    return layer[0]

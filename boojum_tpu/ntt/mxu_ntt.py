@@ -614,17 +614,42 @@ def _fft_hybrid(a: jax.Array, log_n: int, interpret: bool):
     return out.reshape(lead + (n,))
 
 
-@partial(jax.jit, static_argnums=(1, 2))
-def _ifft_hybrid(a: jax.Array, log_n: int, interpret: bool):
+def _ifft_hybrid_blocks_body(a: jax.Array, log_n: int, interpret: bool):
+    """The 2^MAX_LOG_N-blocks' own inverse kernels (each includes its
+    1/2^MAX_LOG_N) on bit-reversed input of size 2^log_n."""
+    outer = log_n - MAX_LOG_N
+    lead = a.shape[:-1]
+    blocks = a.reshape(lead + (1 << outer, 1 << MAX_LOG_N))
+    out = ifft_bitreversed_to_natural(blocks, interpret)
+    return out.reshape(lead + (1 << log_n,))
+
+
+def _ifft_hybrid_outer_body(out: jax.Array, log_n: int):
+    """The outer radix-2 DIT stages and the leftover 1/2^outer."""
     from ..field import goldilocks as gf
     from .ntt import dit_stages, get_ntt_context
 
-    n = 1 << log_n
-    outer = log_n - MAX_LOG_N
-    ctx = get_ntt_context(log_n)
-    lead = a.shape[:-1]
-    blocks = a.reshape(lead + (1 << outer, 1 << MAX_LOG_N))
-    # per-block inverse includes 1/2^16; outer stages + leftover 1/2^outer
-    out = ifft_bitreversed_to_natural(blocks, interpret).reshape(lead + (n,))
-    out = dit_stages(out, ctx, MAX_LOG_N, log_n)
-    return gf.mul(out, jnp.uint64(gl.inv(1 << outer)))
+    out = dit_stages(out, get_ntt_context(log_n), MAX_LOG_N, log_n)
+    return gf.mul(out, jnp.uint64(gl.inv(1 << (log_n - MAX_LOG_N))))
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _ifft_hybrid(a: jax.Array, log_n: int, interpret: bool):
+    return _ifft_hybrid_outer_body(
+        _ifft_hybrid_blocks_body(a, log_n, interpret), log_n
+    )
+
+
+_ifft_hybrid_blocks = jax.jit(_ifft_hybrid_blocks_body, static_argnums=(1, 2))
+_ifft_hybrid_outer = jax.jit(_ifft_hybrid_outer_body, static_argnums=(1,))
+
+
+def ifft_hybrid_apart(a: jax.Array, log_n: int, interpret: bool = False):
+    """`_ifft_hybrid` as two device programs, for a caller outside jit.
+    Compiled with the bit reversal into the ONE program of
+    `ntt._monomial_from_values_jit`, the inverse of (10, 2^18) u64 columns
+    did not come back in 150 s on the v5e, where (64, 2^18) takes 28 ms;
+    apart, the reversal, these two and 10 columns take 0.48 s (my chip
+    run, PR 32). The forward's story, on planes, is
+    `limb_ntt._hybrid_fwd_p`'s (PR 26)."""
+    return _ifft_hybrid_outer(_ifft_hybrid_blocks(a, log_n, interpret), log_n)

@@ -368,7 +368,18 @@ def ntt_kernel_specs(B: int, log_n: int, lde_factor: int | None = None,
         return jax.ShapeDtypeStruct(shape, jnp.uint64)
 
     specs = []
-    if mono:
+    if mono and _inverse_is_apart(n):
+        from . import mxu_ntt
+
+        for b in chunk_shapes(B, n * 8):
+            specs += [
+                (f"imono_brev_b{b}_n{n}", _bitreversed_jit, (sds(b, n),)),
+                (f"imono_blocks_b{b}_n{n}", mxu_ntt._ifft_hybrid_blocks,
+                 (sds(b, n), log_n, False)),
+                (f"imono_outer_b{b}_n{n}", mxu_ntt._ifft_hybrid_outer,
+                 (sds(b, n), log_n)),
+            ]
+    elif mono:
         specs += [
             (f"imono_b{b}_n{n}", _monomial_from_values_jit, (sds(b, n),))
             for b in chunk_shapes(B, n * 8)
@@ -411,18 +422,45 @@ def ntt_kernel_specs(B: int, log_n: int, lde_factor: int | None = None,
     return specs
 
 
+@jax.jit
+def _bitreversed_jit(values: jax.Array) -> jax.Array:
+    n = values.shape[-1]
+    return values[..., get_ntt_context(n.bit_length() - 1).brev]
+
+
+def _inverse_is_apart(n: int) -> bool:
+    """True where `monomial_from_values` runs the inverse transform of
+    size n as separate device programs (`mxu_ntt.ifft_hybrid_apart`)."""
+    if not _mxu_ntt_ready(n, None):
+        return False
+    from . import mxu_ntt
+
+    return n.bit_length() - 1 > mxu_ntt.MAX_LOG_N
+
+
+def _monomial_chunk(values: jax.Array) -> jax.Array:
+    n = values.shape[-1]
+    if isinstance(values, jax.core.Tracer) or not _inverse_is_apart(n):
+        return _monomial_from_values_jit(values)
+    from . import mxu_ntt
+
+    return mxu_ntt.ifft_hybrid_apart(
+        _bitreversed_jit(values), n.bit_length() - 1
+    )
+
+
 def monomial_from_values(values: jax.Array) -> jax.Array:
     """Values over H (natural order) -> monomial coefficients (column
     batches chunked to bound transient memory)."""
     if values.ndim < 2:
-        return _monomial_from_values_jit(values)
+        return _monomial_chunk(values)
     B = values.shape[0]
     per = _col_chunks(B, values.size // B * 8)
     if per is None:
-        return _monomial_from_values_jit(values)
+        return _monomial_chunk(values)
     return _assemble_chunks(
         values.shape,
-        lambda i: _monomial_from_values_jit(values[i : i + per]),
+        lambda i: _monomial_chunk(values[i : i + per]),
         range(0, B, per),
     )
 

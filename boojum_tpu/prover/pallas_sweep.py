@@ -13,9 +13,11 @@ column blocks:
   copy-permutation terms, the lookup terms and the 1/Z_H multiply — the
   plane counterpart of `prover._u64_sweep_core`. Trace columns and the
   challenge table are array arguments (new challenges never retrace);
-  challenge scalars and alpha/γ-power tables ride SMEM; packed gate
-  programs replay from SMEM op tables under `fori_loop` (constant graph
-  size).
+  challenge scalars and alpha/γ-power tables ride SMEM; every gate's
+  evaluator is traced directly, the flattened Poseidon2 gate's 2,036
+  operations included (at the 8-row step a 130-column sweep runs at, the
+  unrolled trace beat a replay from an SMEM op table over a VMEM
+  register file on the v5e: PERF.md, PR 32).
 - `fri_fold_planes(...)`: one fold round f'(x^2) = (f(x)+f(-x))/2 +
   ch·(f(x)-f(-x))/(2x) on deinterleaved even/odd limb planes.
 - standalone `cp_quotient` / `lookup_quotient` / `lookup_quotient_general`
@@ -44,7 +46,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -55,6 +56,7 @@ from ..field import limb_ops as lop
 from ..field import limbs
 from ..utils import metrics as _metrics
 from ..utils.pallas_util import imap32, pick_tile, tpu_compiler_params
+from ..utils.spans import span as _span
 
 _LANE = 128
 _INV2_PAIR = limbs.const_pair((gl.P + 1) // 2)
@@ -111,14 +113,11 @@ def _row1(p):
     return p[0][None], p[1][None]
 
 
-def _tiled_ext_call(
-    body, ins, table, extra_tables=(), num_ext_out=1, interpret=None
-):
+def _tiled_ext_call(body, ins, table, num_ext_out=1, interpret=None):
     """Run `body` over limb planes of the column stacks `ins`.
 
     ins: list of (lo, hi) u32 plane pairs of shape (B_i, n). table: (4, S)
-    uint32 scalar table (SMEM). extra_tables: int32 2-D tables (SMEM;
-    packed gate programs). body(table, tables, pairs) receives pairs[i] =
+    uint32 scalar table (SMEM). body(table, pairs) receives pairs[i] =
     (lo, hi) uint32 arrays of block shape (B_i, T, 128) and returns
     `num_ext_out` ext limb elements of shape (T, 128). Returns that many
     ext limb pairs ((lo, hi), (lo, hi)) of (n,) planes.
@@ -128,14 +127,13 @@ def _tiled_ext_call(
     n = int(ins[0][0].shape[-1])
     if interpret is None:
         interpret = _interpret()
-    extra_tables = tuple(jnp.asarray(t) for t in extra_tables)
 
     def _planes(x, shape):
         return x[0].reshape(shape), x[1].reshape(shape)
 
     if n % _LANE != 0:
         pairs = [_planes(x, (int(x[0].shape[0]), 1, n)) for x in ins]
-        outs = body(table, extra_tables, pairs)
+        outs = body(table, pairs)
         return tuple(
             (
                 (c0[0].reshape(n), c0[1].reshape(n)),
@@ -149,16 +147,13 @@ def _tiled_ext_call(
     tile = pick_tile(R, budget_rows)
     grid = (R // tile,)
 
-    def _smem_spec(t):
-        return pl.BlockSpec(
-            t.shape, imap32(lambda *_: (0,) * t.ndim), memory_space=pltpu.SMEM
+    in_specs = [
+        pl.BlockSpec(
+            table.shape, imap32(lambda *_: (0,) * table.ndim),
+            memory_space=pltpu.SMEM,
         )
-
-    in_specs = [_smem_spec(table)]
+    ]
     args = [table]
-    for t in extra_tables:
-        in_specs.append(_smem_spec(t))
-        args.append(t)
     for x in ins:
         B = int(x[0].shape[0])
         lo, hi = _planes(x, (B, R, _LANE))
@@ -175,18 +170,16 @@ def _tiled_ext_call(
     out_shape = [
         jax.ShapeDtypeStruct((R, _LANE), jnp.uint32)
     ] * (4 * num_ext_out)
-    n_tab = 1 + len(extra_tables)
     n_in = len(ins)
 
     def kernel(*refs):
         tb = refs[0]
-        tabs = refs[1:n_tab]
-        in_refs = refs[n_tab : n_tab + 2 * n_in]
-        out_refs = refs[n_tab + 2 * n_in :]
+        in_refs = refs[1 : 1 + 2 * n_in]
+        out_refs = refs[1 + 2 * n_in :]
         pairs = [
             (in_refs[2 * i][:], in_refs[2 * i + 1][:]) for i in range(n_in)
         ]
-        outs = body(tb, tabs, pairs)
+        outs = body(tb, pairs)
         for k, (c0, c1) in enumerate(outs):
             out_refs[4 * k][:] = c0[0]
             out_refs[4 * k + 1][:] = c0[1]
@@ -310,96 +303,38 @@ def _selector_from_consts(const_p, path):
     return sel
 
 
-def _scan_replay(packed, ops_ref, row):
-    """Replay a PackedGateProgram over limb-pair row values: the limb twin
-    of gate_capture.scan_evaluate — regs are two stacked uint32 planes and
-    the op table streams from SMEM under one fori_loop (constant graph
-    size for permutation-sized gates)."""
-    loads = []
-    sample = None
-    for idx, reg, getter in (
-        [(i, r, row.v) for i, r in zip(packed.v_idx, packed.v_regs)]
-        + [(i, r, row.w) for i, r in zip(packed.w_idx, packed.w_regs)]
-        + [(i, r, row.c) for i, r in zip(packed.c_idx, packed.c_regs)]
-    ):
-        val = getter(idx)
-        sample = val
-        loads.append((reg, val))
-    assert sample is not None, packed.gate_name
-    shape = sample[0].shape
-    regs_lo = jnp.zeros((packed.num_regs,) + shape, jnp.uint32)
-    regs_hi = jnp.zeros((packed.num_regs,) + shape, jnp.uint32)
-    for reg, (vlo, vhi) in loads:
-        regs_lo = regs_lo.at[reg].set(jnp.broadcast_to(vlo, shape))
-        regs_hi = regs_hi.at[reg].set(jnp.broadcast_to(vhi, shape))
-    for val, reg in zip(packed.const_vals, packed.const_regs):
-        clo, chi = limbs.const_pair(val)
-        regs_lo = regs_lo.at[reg].set(jnp.full(shape, clo, jnp.uint32))
-        regs_hi = regs_hi.at[reg].set(jnp.full(shape, chi, jnp.uint32))
-
-    def step(i, carry):
-        rl, rh = carry
-        a = (
-            jax.lax.dynamic_index_in_dim(rl, ops_ref[i, 2], 0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(rh, ops_ref[i, 2], 0, keepdims=False),
-        )
-        b = (
-            jax.lax.dynamic_index_in_dim(rl, ops_ref[i, 3], 0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(rh, ops_ref[i, 3], 0, keepdims=False),
-        )
-        res = jax.lax.switch(
-            ops_ref[i, 0],
-            (
-                lambda x, y: limbs.add(x, y),
-                lambda x, y: limbs.sub(x, y),
-                lambda x, y: limbs.mul(x, y),
-            ),
-            a,
-            b,
-        )
-        rl = jax.lax.dynamic_update_index_in_dim(rl, res[0], ops_ref[i, 1], 0)
-        rh = jax.lax.dynamic_update_index_in_dim(rh, res[1], ops_ref[i, 1], 0)
-        return rl, rh
-
-    regs_lo, regs_hi = jax.lax.fori_loop(
-        jnp.int32(0), jnp.int32(packed.num_ops), step, (regs_lo, regs_hi)
-    )
-    return [(regs_lo[r], regs_hi[r]) for r in packed.term_regs]
-
-
-def _gate_terms(tb, tabs, like, copy_p, wit_p, const_p, plan, a_col):
+def _gate_terms(tb, like, copy_p, wit_p, const_p, plan, a_col):
     """Gate-terms contribution (stages._build_gate_sweep core): per gate,
-    selector-masked sum over instances/terms of alpha^t·term. Consumes one
-    SMEM op table from `tabs` per packed gate, in plan order. Returns
-    (acc_ext_or_None, alpha powers consumed)."""
+    selector-masked sum over instances/terms of alpha^t·term, every gate's
+    evaluator traced directly over limb pairs. Returns (acc_ext_or_None,
+    alpha powers consumed)."""
     t = 0
-    tab_i = 0
     acc = None
-    for gate, path, reps, packed in plan:
+    for gate, path, reps in plan:
         sel = _selector_from_consts(const_p, path)
-        ops_ref = None
-        if packed is not None:
-            ops_ref = tabs[tab_i]
-            tab_i += 1
         gate_acc = None
-        for inst in range(reps):
-            row = RowView(
-                lambda i, o=inst * gate.principal_width: _row(copy_p, o + i),
-                lambda i, o=inst * gate.witness_width: _row(wit_p, o + i),
-                lambda i, o=len(path): _row(const_p, o + i),
-            )
-            if packed is not None:
-                terms = _scan_replay(packed, ops_ref, row)
-            else:
+        # the seconds a gate's terms take to trace show in a recording of
+        # the library's first lowering (a permutation-sized gate: seconds)
+        with _span(
+            "gate_kernel_trace", gate=gate.name, reps=reps,
+            terms=gate.num_terms,
+        ):
+            for inst in range(reps):
+                row = RowView(
+                    lambda i, o=inst * gate.principal_width: _row(
+                        copy_p, o + i
+                    ),
+                    lambda i, o=inst * gate.witness_width: _row(wit_p, o + i),
+                    lambda i, o=len(path): _row(const_p, o + i),
+                )
                 dst = TermsCollector()
                 gate.evaluate(LimbOps, row, dst)
-                terms = dst.terms
-            assert len(terms) == gate.num_terms, gate.name
-            for term in terms:
-                gate_acc = lop.accumulate(
-                    gate_acc, term, _sc_ext(tb, a_col + t, like)
-                )
-                t += 1
+                assert len(dst.terms) == gate.num_terms, gate.name
+                for term in dst.terms:
+                    gate_acc = lop.accumulate(
+                        gate_acc, term, _sc_ext(tb, a_col + t, like)
+                    )
+                    t += 1
         if gate_acc is not None:
             if sel is not None:
                 gate_acc = (
@@ -408,15 +343,6 @@ def _gate_terms(tb, tabs, like, copy_p, wit_p, const_p, plan, a_col):
                 )
             acc = gate_acc if acc is None else lop.ext_add(acc, gate_acc)
     return acc, t
-
-
-def _packed_tables(plan):
-    """The SMEM int32 op tables of the plan's packed gates, in plan order."""
-    return tuple(
-        np.asarray(packed.ops_arr, dtype=np.int32)
-        for _gate, _path, _reps, packed in plan
-        if packed is not None
-    )
 
 
 def _ext_scalar_cols(s):
@@ -449,17 +375,16 @@ def build_coset_terms(gates, selector_paths, geometry, lk_ctx, non_residues):
     non_residues = tuple(int(k) for k in non_residues)
     plan = gate_sweep_plan(gates, selector_paths, geometry)
     total_gate_terms = sum(
-        reps * gate.num_terms for gate, _path, reps, _packed in plan
+        reps * gate.num_terms for gate, _path, reps in plan
     )
     expected = (
         total_gate_terms + 1 + len(chunks) + ((R_args + 1) if lookups else 0)
     )
     assert expected == total_alpha_terms, (expected, total_alpha_terms)
-    tabs_static = _packed_tables(plan)
     ab_off = 2 + 2 * num_partials
     _metrics.count("pallas_sweep.builds")
 
-    def body(tb, tabs, pairs, A):
+    def body(tb, pairs, A):
         wit_p, setup_p, s2_p, zs_p, xs_p, l0_p, zh_p = pairs
         like = xs_p[0][0]
         xs = _row(xs_p, 0)
@@ -477,7 +402,7 @@ def build_coset_terms(gates, selector_paths, geometry, lk_ctx, non_residues):
         if total_gate_terms:
             gcopy_p = (copy_p[0][:Cg], copy_p[1][:Cg])
             acc, t = _gate_terms(
-                tb, tabs, like, gcopy_p, gate_wit_p, const_p, plan, a_col=0
+                tb, like, gcopy_p, gate_wit_p, const_p, plan, a_col=0
             )
             assert t == total_gate_terms
         cp = _cp_terms(
@@ -527,7 +452,6 @@ def build_coset_terms(gates, selector_paths, geometry, lk_ctx, non_residues):
                 _row1(xs_p), _row1(l0_p), _row1(zh_p),
             ],
             table,
-            extra_tables=tabs_static,
         )
         return out
 
@@ -560,7 +484,7 @@ def cp_quotient(
     chunks = tuple(tuple(c) for c in chunks)
     non_residues = tuple(int(k) for k in non_residues)
 
-    def body(tb, _tabs, pairs):
+    def body(tb, pairs):
         s2_p, zs_p, copy_pp, sigma_pp, xs_pp, l0_pp = pairs
         like = xs_pp[0][0]
         acc = _cp_terms(
@@ -605,7 +529,7 @@ def _lookup_quotient_shared(
     if general:
         ins.append(_row1(sel_p))
 
-    def body(tb, _tabs, pairs):
+    def body(tb, pairs):
         if general:
             s2_pp, cols_pp, tid_pp, table_pp, mult_pp, sel_pp = pairs
             sel = _row(sel_pp, 0)
@@ -659,7 +583,6 @@ def gate_terms_fn(gates, selector_paths, geometry, interpret=None):
     plan = gate_sweep_plan(
         tuple(gates), tuple(tuple(p) for p in selector_paths), geometry
     )
-    tabs_static = _packed_tables(plan)
 
     def fn(copy_p, wit_p, const_p, table):
         ins = [copy_p]
@@ -668,7 +591,7 @@ def gate_terms_fn(gates, selector_paths, geometry, interpret=None):
             ins.append(wit_p)
         ins.append(const_p)
 
-        def body(tb, tabs, pairs):
+        def body(tb, pairs):
             if has_wit:
                 copy_pp, wit_pp, const_pp = pairs
             else:
@@ -676,13 +599,11 @@ def gate_terms_fn(gates, selector_paths, geometry, interpret=None):
                 wit_pp = None
             like = copy_pp[0][0]
             acc, _t = _gate_terms(
-                tb, tabs, like, copy_pp, wit_pp, const_pp, plan, a_col=0
+                tb, like, copy_pp, wit_pp, const_pp, plan, a_col=0
             )
             return (acc,)
 
-        (out,) = _tiled_ext_call(
-            body, ins, table, extra_tables=tabs_static, interpret=interpret
-        )
+        (out,) = _tiled_ext_call(body, ins, table, interpret=interpret)
         return out
 
     return fn
@@ -693,7 +614,7 @@ def gate_terms_fn(gates, selector_paths, geometry, interpret=None):
 # ---------------------------------------------------------------------------
 
 
-def _fold_body(tb, _tabs, pairs):
+def _fold_body(tb, pairs):
     quad, inv = pairs
     like = quad[0][0]
     a = (_row(quad, 0), _row(quad, 1))

@@ -578,6 +578,23 @@ def _sweep_barrier_stride(Q: int, working_set_bytes: int) -> int:
     return max(1, int(room // working_set_bytes))
 
 
+def _gate_sweep_stats(assembly, planes: bool):
+    """(field operations a row, gates replayed from a packed program) of
+    the assembly's gate sweep on one representation, from the gate set
+    alone (each gate's program is captured once a process). The limb-plane
+    kernel traces every gate directly; the u64 sweep replays a gate past
+    the scan threshold under lax.scan."""
+    from ..cs.gate_capture import packed_program_for
+    from .stages import gate_sweep_ops_per_row
+
+    packed = 0 if planes else sum(
+        packed_program_for(g) is not None
+        for g in assembly.gates
+        if g.num_terms
+    )
+    return gate_sweep_ops_per_row(assembly.gates, assembly.geometry), packed
+
+
 def _coset_sweep_fn(
     assembly, selector_paths, non_residues, lk_ctx, planes: bool,
     sm_mesh=None,
@@ -1799,6 +1816,12 @@ def _prove_impl(
         _barrier_stride = _sweep_barrier_stride(Q, _sweep_ws)
         _metrics.gauge_max("quotient.sweep_working_set_bytes", _sweep_ws)
         _metrics.count("quotient.sweep_barriers", 0)
+        # what the gates cost the sweep, from the plan alone: the field
+        # operations of one row, and how many gates are replayed from a
+        # packed program instead of traced (the u64 path's lax.scan)
+        _ops_per_row, _packed = _gate_sweep_stats(assembly, res)
+        _metrics.count("quotient.gate_ops_per_row", _ops_per_row)
+        _metrics.count("quotient.packed_gates", _packed)
         if sm_mesh is not None:
             # pad + column-shard the four monomial groups ONCE per round
             # (not per coset); each coset evaluation then runs the

@@ -307,26 +307,31 @@ def gate_terms_contribution(
 
 
 def gate_sweep_plan(gates, selector_paths, geometry):
-    """Static per-gate sweep schedule shared by the u64 sweep trace and the
-    limb-domain Pallas kernel builder (prover/pallas_sweep.py): one
-    (gate, selector_path, repetitions, packed_program) tuple per gate with
-    quotient terms, in gate order — both backends MUST consume terms (and
-    therefore alpha powers) in exactly this order or challenges desync."""
-    from ..cs.gate_capture import packed_program_for
+    """Static per-gate schedule of the limb-domain Pallas kernel builder
+    (prover/pallas_sweep.py): one (gate, selector_path, repetitions) tuple
+    per gate with quotient terms, in gate order — the order in which the
+    u64 sweep below consumes terms (and therefore alpha powers): both
+    backends MUST keep to it or challenges desync."""
+    return [
+        (gate, tuple(selector_paths[gid]), gate.num_repetitions(geometry))
+        for gid, gate in enumerate(gates)
+        if gate.num_terms
+    ]
 
-    plan = []
-    for gid, gate in enumerate(gates):
-        if gate.num_terms == 0:
-            continue
-        plan.append(
-            (
-                gate,
-                tuple(selector_paths[gid]),
-                gate.num_repetitions(geometry),
-                packed_program_for(gate),
-            )
-        )
-    return plan
+
+def gate_sweep_ops_per_row(gates, geometry) -> int:
+    """Field operations one row costs the gate sweep: each gate's captured
+    program (additions, subtractions, multiplications, doublings,
+    negations, one each) times its repetitions, summed over the gates with
+    quotient terms. The accumulation by the alpha powers and the selector
+    products are not in it. Static: a function of the gate set."""
+    from ..cs.gate_capture import program_for
+
+    return sum(
+        gate.num_repetitions(geometry) * len(program_for(gate).ops)
+        for gate in gates
+        if gate.num_terms
+    )
 
 
 def _build_gate_sweep(gates, selector_paths, geometry):
@@ -348,27 +353,33 @@ def _build_gate_sweep(gates, selector_paths, geometry):
             # Poseidon2 gate made the unrolled sweep uncompilable
             packed = packed_program_for(gate)
             gate_acc = None
-            for inst in range(reps):
-                row = LdeRowView(
-                    copy_lde_flat,
-                    wit_lde_flat,
-                    const_lde_flat,
-                    inst * gate.principal_width,
-                    inst * gate.witness_width,
-                    # variable-depth selectors: a gate's constants start
-                    # right after ITS OWN path bits
-                    len(selector_paths[gid]),
-                )
-                if packed is not None:
-                    terms = scan_evaluate(packed, row)
-                else:
-                    dst = TermsCollector()
-                    gate.evaluate(ArrayOps, row, dst)
-                    terms = dst.terms
-                assert len(terms) == gate.num_terms, gate.name
-                for term in terms:
-                    gate_acc = accumulate_ext(gate_acc, term, (a0[t], a1[t]))
-                    t += 1
+            with _span(
+                "gate_kernel_trace", gate=gate.name, reps=reps,
+                terms=gate.num_terms, packed=packed is not None,
+            ):
+                for inst in range(reps):
+                    row = LdeRowView(
+                        copy_lde_flat,
+                        wit_lde_flat,
+                        const_lde_flat,
+                        inst * gate.principal_width,
+                        inst * gate.witness_width,
+                        # variable-depth selectors: a gate's constants
+                        # start right after ITS OWN path bits
+                        len(selector_paths[gid]),
+                    )
+                    if packed is not None:
+                        terms = scan_evaluate(packed, row)
+                    else:
+                        dst = TermsCollector()
+                        gate.evaluate(ArrayOps, row, dst)
+                        terms = dst.terms
+                    assert len(terms) == gate.num_terms, gate.name
+                    for term in terms:
+                        gate_acc = accumulate_ext(
+                            gate_acc, term, (a0[t], a1[t])
+                        )
+                        t += 1
             if gate_acc is not None:
                 if sel is not None:
                     gate_acc = (

@@ -272,6 +272,17 @@ def _rnd(rng, *s):
 _jit = functools.partial(
     jax.jit, compiler_options={"xla_cpu_use_fusion_emitters": False}
 )
+# a permutation-sized gate unrolls to some 10^5 limb operations, over which
+# XLA:CPU's default pipeline takes five minutes; without LLVM's
+# optimisation passes, under two (here, 8 cores). Same integers.
+_jit_unoptimised = functools.partial(
+    jax.jit,
+    compiler_options={
+        "xla_cpu_use_fusion_emitters": False,
+        "xla_backend_optimization_level": 0,
+        "xla_llvm_disable_expensive_passes": True,
+    },
+)
 
 
 # 256 exercises the tiled pallas path (R=2 sublane rows); 96 the
@@ -340,13 +351,46 @@ def test_lookup_quotient_kernel_parity(general):
     _assert_ext_equal(got, ref, f"lookup general={general}")
 
 
-@pytest.mark.parametrize("scan_threshold", [None, 1])
-def test_gate_terms_kernel_parity(scan_threshold, monkeypatch):
-    """Direct-trace gates AND the packed-program SMEM scan replay
-    (threshold 1 forces even the 3-op FMA program through _scan_replay)."""
-    from boojum_tpu.cs.gate_capture import _PACKED_CACHE
+def _fma_case(rng, n):
     from boojum_tpu.cs.gates import FmaGate
     from boojum_tpu.examples import EXAMPLE_GEOMETRY as geom
+
+    return FmaGate.instance(), geom, _rnd(rng, 8, n), _rnd(rng, 6, n)
+
+
+def _poseidon2_flat_case(rng, n):
+    """Upstream's flattened Poseidon2 gate on the Era geometry: one
+    permutation a row of 130 columns, 2,036 field operations, 118 terms."""
+    from boojum_tpu.cs.gates import Poseidon2FlattenedGate
+    from boojum_tpu.cs.types import CSGeometry
+
+    geom = CSGeometry(
+        num_columns_under_copy_permutation=130,
+        num_witness_columns=0,
+        num_constant_columns=8,
+        max_allowed_constraint_degree=7,
+    )
+    return (
+        Poseidon2FlattenedGate.instance(), geom,
+        _rnd(rng, 130, n), _rnd(rng, 8, n),
+    )
+
+
+@pytest.mark.parametrize("case,scan_threshold,jit", [
+    pytest.param(_fma_case, None, _jit, id="None"),
+    pytest.param(_fma_case, 1, _jit, id="1"),
+    pytest.param(
+        _poseidon2_flat_case, None, _jit_unoptimised, id="poseidon2_flat"
+    ),
+])
+def test_gate_terms_kernel_parity(case, scan_threshold, jit, monkeypatch):
+    """The limb kernel traces every gate directly, small or
+    permutation-sized (the form the chip chose, PERF.md PR 32); the u64
+    sweep it is held to replays a gate past the scan threshold under
+    lax.scan: threshold 1 sends even the 3-op FMA program through that
+    replay, and the flattened Poseidon2 gate (2,036 operations on 256
+    random rows) goes through it at the default threshold."""
+    from boojum_tpu.cs.gate_capture import _PACKED_CACHE
     from boojum_tpu.prover import pallas_sweep as ps
     from boojum_tpu.prover.stages import _build_gate_sweep
 
@@ -354,19 +398,21 @@ def test_gate_terms_kernel_parity(scan_threshold, monkeypatch):
         monkeypatch.setenv("BOOJUM_TPU_SCAN_GATE_THRESHOLD", str(scan_threshold))
     saved = dict(_PACKED_CACHE)
     try:
-        gates = (FmaGate.instance(),)
-        paths = ((),)
         rng = np.random.default_rng(12)
         n = 256
-        copy, const = _rnd(rng, 8, n), _rnd(rng, 6, n)
-        reps = FmaGate.instance().num_repetitions(geom)
-        a0, a1 = _rnd(rng, reps), _rnd(rng, reps)
+        gate, geom, copy, const = case(rng, n)
+        gates = (gate,)
+        paths = ((),)
+        terms = gate.num_repetitions(geom) * gate.num_terms
+        a0, a1 = _rnd(rng, terms), _rnd(rng, terms)
         ref = _build_gate_sweep(gates, paths, geom)(copy, None, const, a0, a1)
         limb_fn = ps.gate_terms_fn(gates, paths, geom)
-        got = _jit(lambda c, k, tb: limb_fn(c, None, k, tb))(
+        got = jit(lambda c, k, tb: limb_fn(c, None, k, tb))(
             _s(copy), _s(const), ps._pack_table(a0, a1)
         )
-        _assert_ext_equal(got, ref, f"gate threshold={scan_threshold}")
+        _assert_ext_equal(
+            got, ref, f"gate {gate.name} threshold={scan_threshold}"
+        )
     finally:
         _PACKED_CACHE.clear()
         _PACKED_CACHE.update(saved)

@@ -362,6 +362,40 @@ class TestMXUNTTKernel:
         goti = mxu_ntt.ifft_bitreversed_to_natural(want, interpret=True)
         assert np.array_equal(np.asarray(goti), np.asarray(wanti))
 
+    def test_monomials_above_the_kernel_ceiling_run_apart(self, monkeypatch):
+        """`monomial_from_values` above the single-kernel ceiling on the
+        MXU path: the bit reversal, the per-block inverse kernels and the
+        outer stages as separate programs (as ONE program, 10 columns of
+        2^18 did not come back on the v5e: PERF.md, PR 32), equal to the
+        staged-XLA inverse; traced into a caller's jit it stays the one
+        program. The ceiling is lowered to the smallest kernel so that
+        2^15 is a hybrid size interpret mode can afford."""
+        import jax
+
+        from boojum_tpu.ntt import mxu_ntt, ntt
+
+        a = self._data(self.LOG_N + 1, cols=3, seed=35)
+        want = ntt._monomial_from_values_jit(a)  # XLA: off a TPU
+        monkeypatch.setattr(mxu_ntt, "MAX_LOG_N", self.LOG_N)
+        monkeypatch.setattr(ntt, "_mxu_ntt_ready", lambda n, ctx: True)
+        apart = mxu_ntt.ifft_hybrid_apart
+        calls = []
+
+        def interpreted(x, log_n):
+            calls.append((tuple(x.shape), log_n))
+            return apart(x, log_n, True)
+
+        monkeypatch.setattr(mxu_ntt, "ifft_hybrid_apart", interpreted)
+        got = ntt.monomial_from_values(a)
+        assert calls == [((3, 1 << (self.LOG_N + 1)), self.LOG_N + 1)]
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        names = [name for name, _fn, _args in ntt.ntt_kernel_specs(
+            3, self.LOG_N + 1)]
+        assert [n.rsplit("_b", 1)[0] for n in names] == [
+            "imono_brev", "imono_blocks", "imono_outer"]
+        jax.make_jaxpr(ntt.monomial_from_values)(a)
+        assert len(calls) == 1
+
     def test_lde_interpret(self):
         from boojum_tpu.ntt import ntt
         from boojum_tpu.ntt import mxu_ntt
