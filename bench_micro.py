@@ -11,6 +11,7 @@ a reading is the chip's time and not the host's per-launch overhead.
 
 Usage: python bench_micro.py  (JSON lines on stdout; backend = ambient JAX)
        python bench_micro.py poseidon2  (the Poseidon2 section alone)
+       python bench_micro.py binv       (the batch-inversion section alone)
 """
 
 import json
@@ -104,7 +105,28 @@ def main():
 
     poseidon2_section(backend)
 
-    # batch inversion
+    batch_inverse_section(backend)
+    sweep_section(backend)
+    resident_section(backend)
+    field_section(backend)
+    mesh_section(backend)
+
+
+# the cells' inversions (PERF.md section 5): Keccak's 32 public inputs,
+# its 22 chunk and 9 lookup denominators, DEEP's two, the anchor's lookup
+BINV_SHAPES = (
+    (32, 1 << 19), (22, 1 << 18), (9, 1 << 18), (2, 1 << 19), (9, 1 << 16),
+)
+
+
+def batch_inverse_section(backend):
+    """Batch inversion: the u64 routine on 2^20 elements, and on the TPU
+    the limb-plane routine the prover dispatches (`lop.batch_inverse_jit`)
+    at the cells' shapes, with multiplications a second by the plan's own
+    count (`lop.batch_inverse_muls`: what `field.batch_inverse_muls`
+    adds for the call)."""
+    from boojum_tpu.field import limb_ops as lop
+
     b = _rand((1 << 20,), 50)
     b = jnp.where(b == 0, jnp.uint64(1), b)
     dt = timed_chain(gf.batch_inverse_xla, b, 4)
@@ -112,11 +134,23 @@ def main():
         "batch_inverse_elems_per_s", int((1 << 20) / dt), "elems/s",
         backend=backend,
     )
-
-    sweep_section(backend)
-    resident_section(backend)
-    field_section(backend)
-    mesh_section(backend)
+    if backend != "tpu":
+        return  # XLA:CPU runs the u32 limb cores for minutes a shape
+    rng = np.random.default_rng(51)
+    for shape in BINV_SHAPES:
+        # canonical and nonzero: lo >= 1, hi < 2^32 - 1
+        lo = jnp.asarray(rng.integers(1, 1 << 32, shape, dtype=np.uint32))
+        hi = jnp.asarray(
+            rng.integers(0, (1 << 32) - 1, shape, dtype=np.uint32)
+        )
+        dt = timed_call(lop.batch_inverse_jit, ((lo, hi),), reps=5)
+        muls = lop.batch_inverse_muls(shape)
+        emit(
+            "batch_inverse_planes_muls_per_s", int(muls / dt), "muls/s",
+            shape=list(shape), ms=round(dt * 1e3, 3), plan_muls=muls,
+            muls_per_elem=round(muls / (shape[0] * shape[1]), 3),
+            backend=backend,
+        )
 
 
 P2_TILES = (8, 16, 32, 64, 128, 256)
@@ -721,5 +755,7 @@ def mesh_section(backend):
 if __name__ == "__main__":
     if sys.argv[1:] == ["poseidon2"]:  # that section alone, one chip call
         poseidon2_section(jax.default_backend())
+    elif sys.argv[1:] == ["binv"]:
+        batch_inverse_section(jax.default_backend())
     else:
         main()

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import blocked_inverse
 from .spec import BABYBEAR as SPEC
 
 P = SPEC.p
@@ -201,37 +202,12 @@ def inv(a):
     return acc
 
 
-def prefix_product(x):
-    """Inclusive prefix products along the last axis, log-depth
-    (Hillis–Steele doubling — same shape as goldilocks.prefix_product:
-    field mul is NOT associative-scan-safe under XLA's reassociation
-    assumptions, so the doubling is explicit)."""
-    n = x.shape[-1]
-    steps = max(1, (n - 1).bit_length())
-    y = x
-    for s in range(steps):
-        shift = 1 << s
-        ones = jnp.ones_like(y[..., :shift])
-        shifted = jnp.concatenate([ones, y[..., :-shift]], axis=-1)
-        y = mul(y, shifted)
-    return y
-
-
 @jax.jit
 def batch_inverse_xla(x):
-    """Montgomery's trick: two prefix-product sweeps + ONE Fermat
-    inversion, all on device — the BabyBear twin of
-    goldilocks.batch_inverse_xla."""
-    pref = prefix_product(x)
-    total_inv = inv(pref[..., -1:])
-    ones = jnp.ones_like(x[..., :1])
-    pref_prev = jnp.concatenate([ones, pref[..., :-1]], axis=-1)
-    # suffix product of the tail via reversed prefix products
-    rev = jnp.flip(x, axis=-1)
-    suff = jnp.concatenate(
-        [jnp.flip(prefix_product(rev), axis=-1)[..., 1:], ones], axis=-1
-    )
-    return mul(mul(pref_prev, suff), total_inv)
+    """Montgomery's trick, all on device: the BabyBear twin of
+    goldilocks.batch_inverse_xla, `blocked_inverse.batch_inverse` over
+    this field's `mul` and `inv`."""
+    return blocked_inverse.batch_inverse(x, mul, inv, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -461,8 +437,9 @@ def ext_inv_np(a):
 
 def ext_prefix_product(a):
     """Inclusive prefix products of a GF(p^4) vector (4-tuple of device
-    arrays) along the last axis — Hillis–Steele doubling with ext_mul,
-    the extension twin of prefix_product (ISSUE 20 stage-2 z column)."""
+    arrays) along the last axis — explicit Hillis–Steele doubling with
+    ext_mul (field mul is not associative-scan-safe under XLA's
+    reassociation assumptions; ISSUE 20 stage-2 z column)."""
     n = a[0].shape[-1]
     steps = max(1, (n - 1).bit_length())
     y = a

@@ -76,8 +76,9 @@ def batch_inverse(a):
 
 @jax.jit
 def prefix_product(a):
-    """Inclusive ext prefix product along the last axis (log-doubling; same
-    rationale as gf.prefix_product — associative_scan's graph explodes XLA
+    """Inclusive ext prefix product along the last axis (Hillis–Steele
+    log-doubling, log2(n) rounds of shift+multiply; deliberately NOT
+    lax.associative_scan, whose recursive slicing graph explodes XLA
     compile time for wide combine fns)."""
     n = a[0].shape[-1]
     shift = 1

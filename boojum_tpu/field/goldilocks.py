@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import blocked_inverse
+
 # ---------------------------------------------------------------------------
 # Constants
 # ---------------------------------------------------------------------------
@@ -154,47 +156,22 @@ def inv(a: jax.Array) -> jax.Array:
     return pow_const(a, P_INT - 2)
 
 
-def prefix_product(a: jax.Array) -> jax.Array:
-    """Inclusive modular prefix product along the last axis via log-doubling
-    (Hillis–Steele): log2(n) rounds of shift+multiply. Deliberately NOT
-    lax.associative_scan — its recursive slicing graph makes XLA compile
-    time blow up on wide combine functions; this form compiles flat."""
-    n = a.shape[-1]
-    shift = 1
-    while shift < n:
-        ones = jnp.ones(a.shape[:-1] + (shift,), a.dtype)
-        shifted = jnp.concatenate([ones, a[..., :-shift]], axis=-1)
-        a = mul(a, shifted)
-        shift *= 2
-    return a
-
-
 def batch_inverse(a: jax.Array) -> jax.Array:
-    """Montgomery batch inversion along the last axis (log-doubling XLA
-    scans; a sequential-tile Pallas block-scan was tried and measured ~10x
-    slower on v5e — carry serialization defeats pipelining — so the XLA
-    form is the single implementation)."""
+    """Montgomery batch inversion along the last axis: the blocked form of
+    `blocked_inverse` (short independent chains, three multiplications an
+    element), the single implementation. Two others ran here before: a
+    sequential-tile Pallas block-scan, ONE chain across the axis whose
+    carry from tile to tile serialized the grid (measured ~10x slower on
+    v5e), and two log-doubling XLA scans (2 log2 n + 2 multiplications an
+    element). The blocked form carries nothing from group to group."""
     return batch_inverse_xla(a)
 
 
 @jax.jit
 def batch_inverse_xla(a: jax.Array) -> jax.Array:
-    """Montgomery batch inversion along the last axis.
-
-    Two modular prefix-product passes plus ONE Fermat inversion (the
-    vectorized counterpart of the reference's serial Montgomery trick,
-    `/root/reference/src/cs/implementations/utils.rs:405`).
-    """
-    prefix = prefix_product(a)
-    total_inv = inv(prefix[..., -1:])
-    rev = jnp.flip(a, axis=-1)
-    rev_prefix = prefix_product(rev)
-    # prod(a[i+1:]) = rev_prefix[n-2-i] for i < n-1, 1 for i = n-1
-    suffix = jnp.concatenate(
-        [jnp.flip(rev_prefix[..., :-1], axis=-1), jnp.ones_like(a[..., :1])],
-        axis=-1,
-    )
-    shifted_prefix = jnp.concatenate(
-        [jnp.ones_like(a[..., :1]), prefix[..., :-1]], axis=-1
-    )
-    return mul(mul(total_inv, suffix), shifted_prefix)
+    """Montgomery batch inversion along the last axis (the vectorized
+    counterpart of the reference's serial Montgomery trick,
+    `/root/reference/src/cs/implementations/utils.rs:405`): ONE Fermat
+    inversion a few elements a row, `blocked_inverse.batch_inverse` over
+    the u64 `mul` and `inv`."""
+    return blocked_inverse.batch_inverse(a, mul, inv, 1)

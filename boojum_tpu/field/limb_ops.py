@@ -25,7 +25,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from . import gl
+from . import blocked_inverse, gl
 from . import limbs
 from .limbs import add, double, ext_add, ext_mul, ext_sub, mul, neg, sqr, sub
 
@@ -181,7 +181,8 @@ def aggregate_columns(cols, table_id_col, gpow, beta):
 # Inversion (ISSUE 10: the resident prover's denominators/fold tables stay
 # in limb planes end-to-end, so the Montgomery trick needs a limb form).
 # Inverses are unique mod p and every op here is exact+canonical, so values
-# are bit-identical to the u64 goldilocks.batch_inverse family.
+# are bit-identical to the u64 goldilocks.batch_inverse family, whatever
+# the grouping: both instantiate `blocked_inverse.batch_inverse`.
 # ---------------------------------------------------------------------------
 
 
@@ -207,51 +208,27 @@ def inv(a):
     return pow_int(a, gl.P - 2)
 
 
-def prefix_product(a):
-    """Inclusive modular prefix product along the last axis (log-doubling
-    Hillis–Steele, the goldilocks.prefix_product twin on planes)."""
-    lo, hi = a
-    n = lo.shape[-1]
-    shift = 1
-    while shift < n:
-        pad_lo = jnp.ones(lo.shape[:-1] + (shift,), jnp.uint32)
-        pad_hi = jnp.zeros(hi.shape[:-1] + (shift,), jnp.uint32)
-        shifted = (
-            jnp.concatenate([pad_lo, lo[..., :-shift]], axis=-1),
-            jnp.concatenate([pad_hi, hi[..., :-shift]], axis=-1),
-        )
-        lo, hi = mul((lo, hi), shifted)
-        shift *= 2
-    return lo, hi
+# multiplications of one Fermat inversion: a squaring a bit below the top
+# one of p - 2 and a multiply a set bit (`pow_int`)
+FERMAT_MULS = (gl.P - 2).bit_length() + bin(gl.P - 2).count("1") - 2
 
 
 def batch_inverse(a):
-    """Montgomery batch inversion along the last axis on limb planes
-    (two prefix-product passes + ONE Fermat inversion)."""
-    lo, hi = a
-    prefix = prefix_product(a)
-    total_inv = inv((prefix[0][..., -1:], prefix[1][..., -1:]))
-    rev = (jnp.flip(lo, axis=-1), jnp.flip(hi, axis=-1))
-    rev_prefix = prefix_product(rev)
-    suffix = (
-        jnp.concatenate(
-            [jnp.flip(rev_prefix[0][..., :-1], axis=-1),
-             jnp.ones_like(lo[..., :1])], axis=-1,
-        ),
-        jnp.concatenate(
-            [jnp.flip(rev_prefix[1][..., :-1], axis=-1),
-             jnp.zeros_like(hi[..., :1])], axis=-1,
-        ),
-    )
-    shifted_prefix = (
-        jnp.concatenate(
-            [jnp.ones_like(lo[..., :1]), prefix[0][..., :-1]], axis=-1
-        ),
-        jnp.concatenate(
-            [jnp.zeros_like(hi[..., :1]), prefix[1][..., :-1]], axis=-1
-        ),
-    )
-    return mul(mul(total_inv, suffix), shifted_prefix)
+    """Montgomery batch inversion along the last axis on limb planes: the
+    blocked form of `blocked_inverse`, three multiplications an element
+    and one Fermat chain on a few elements a row, every chain step whole
+    vregs with the batch folded beside the groups. Short independent
+    chains, so nothing is carried across tiles (the carried scan that was
+    tried, and the log-doubling scan that ran here until PR 33, are told
+    there). No caller passes a zero, and nothing is promised for one."""
+    return blocked_inverse.batch_inverse(a, mul, inv, (1, 0))
+
+
+def batch_inverse_muls(shape) -> int:
+    """Field multiplications `batch_inverse` spends on planes of `shape` by
+    its plan; `ext_batch_inverse` inverts one norm an element, so the same
+    (its four products an element for norm and conjugate are not in it)."""
+    return blocked_inverse.planned_muls(shape, FERMAT_MULS)
 
 
 def ext_batch_inverse(a):
@@ -267,6 +244,20 @@ def ext_batch_inverse(a):
 # inlined into large XLA:CPU modules has miscompiled — keep it separate)
 batch_inverse_jit = jax.jit(batch_inverse)
 ext_batch_inverse_jit = jax.jit(ext_batch_inverse)
+
+
+def counted(program, a):
+    """Dispatch an inversion program (`batch_inverse_jit`,
+    `ext_batch_inverse_jit`, `resident._lookup_denominators_inv_p`) on base
+    or ext planes `a` from the prover's host code, its plan's
+    multiplications added to the flight recorder's
+    `field.batch_inverse_muls` (here and not in the routine, which is
+    traced once a shape)."""
+    from ..utils import metrics as _metrics
+
+    ref = a[0][0] if isinstance(a[0], tuple) else a[0]
+    _metrics.count("field.batch_inverse_muls", batch_inverse_muls(ref.shape))
+    return program(a)
 
 
 # ---------------------------------------------------------------------------

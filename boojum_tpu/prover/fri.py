@@ -93,7 +93,7 @@ def fold_challenge_tables_p(log_full: int, num_rounds: int):
         xs = limbs.mul_const(xs, limbs.const_pair(shift))
         brev = jnp.asarray(bitreverse_indices(log_nr))
         xs_pairs = (xs[0][brev][0::2], xs[1][brev][0::2])
-        tables.append(lop.batch_inverse_jit(xs_pairs))
+        tables.append(lop.counted(lop.batch_inverse_jit, xs_pairs))
     return tables
 
 

@@ -1434,7 +1434,7 @@ def _prove_impl(
                 copy_vals, sigma_dev, ks, (xs_h, bg_arr),
                 tuple(tuple(c) for c in chunks),
             )
-            den_inv_all = lop.ext_batch_inverse_jit(den_all)
+            den_inv_all = lop.counted(lop.ext_batch_inverse_jit, den_all)
         _metrics.count("stage2.chunk_scans")
         lk_inv = mult_dev = consts_dev = None
         if lookups:
@@ -1469,7 +1469,7 @@ def _prove_impl(
             dens = RES._lookup_denominators_p(
                 lkcols, (tid_col, table_stack), lkbg_arr, R_args, lp.width
             )
-            lk_inv = RES._lookup_denominators_inv_p(dens)
+            lk_inv = lop.counted(RES._lookup_denominators_inv_p, dens)
         z_pp = RES._z_and_partials_p(num_all, den_inv_all)
         stack = RES.stage2_stack_fn_p(assembly, setup.selector_paths)
         s2_vals = stack(z_pp[0], z_pp[1], lk_inv, mult_dev, consts_dev)

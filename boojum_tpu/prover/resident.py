@@ -178,13 +178,13 @@ def l0_brev_p(log_n: int, lde_factor: int):
     )
     zh = host_planes(np.repeat(zh_vals, n))
     xs = domain_xs_brev_p(log_n, lde_factor)
-    binv = lop.batch_inverse_jit(_sub_ones_jit(xs))
+    binv = lop.counted(lop.batch_inverse_jit, _sub_ones_jit(xs))
     return _l0_scale_jit(zh, binv, log_n)
 
 
 @lru_cache(maxsize=4)
-def inv_xs_brev_p(log_n: int, lde_factor: int):
-    return lop.batch_inverse_jit(domain_xs_brev_p(log_n, lde_factor))
+def inv_xs_brev_p(log_n: int, L: int):
+    return lop.counted(lop.batch_inverse_jit, domain_xs_brev_p(log_n, L))
 
 
 @lru_cache(maxsize=4)
@@ -865,7 +865,7 @@ def deep_round5_prep_p(
     num_lk = (R_args + 1) if lookups else 0
     num_pi = len(assembly.public_inputs)
     d = _deep_denoms_p(xs_lde_p, z_tb, zw_tb)
-    dinv = lop.ext_batch_inverse_jit(d)
+    dinv = lop.counted(lop.ext_batch_inverse_jit, d)
     ab_off = 2 + 2 * num_partials
     s2_idxs = [0, 1] + [ab_off + j for j in range(2 * num_lk)]
     if isinstance(s2_lde_flat_p, MonomialPlanesSource):
@@ -891,8 +891,8 @@ def deep_round5_prep_p(
                 dtype=np.uint64,
             )
         )
-        pi_denoms = lop.batch_inverse_jit(
-            _pi_denom_sub_jit(xs_lde_p, pi_points)
+        pi_denoms = lop.counted(
+            lop.batch_inverse_jit, _pi_denom_sub_jit(xs_lde_p, pi_points)
         )
         pi_vals = host_planes(
             np.array(

@@ -175,6 +175,22 @@ def test_fri_fold(one_chip):
     )
 
 
+# the lookup's (R_args + 1, n) and DEEP's (2, N) of this geometry, and the
+# Era cell's 32 public inputs over 2^19 points
+@pytest.mark.parametrize("shape", [(9, 1 << LOG_N), (2, LEAVES), (32, LEAVES)])
+def test_batch_inverse_keeps_the_batch_off_the_lanes(one_chip, shape):
+    """The blocked inversion (ISSUE 33) holds one temporary or two the size
+    of its input: with the batch axis of a `(B, n)` plane on the 128 lanes,
+    as the log-doubling scan it replaced had it, a (9, 2^18) inverse held
+    1,030 MiB of temporaries beside 32 MiB of input. No Pallas here: it is
+    plain XLA, chain steps over whole (8, 128) tiles."""
+    from boojum_tpu.field import limb_ops as lop
+
+    compiled = lop.batch_inverse_jit.lower(_pair(one_chip, *shape)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 2 * mem.argument_size_in_bytes
+
+
 def test_fused_limb_coset_sweep(one_chip, monkeypatch):
     """`coset_sweep_terms_limbres` of upstream's SHA-256 circuit: gates,
     copy-permutation and the 8 lookup arguments fused over 93 witness and
