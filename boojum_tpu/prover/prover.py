@@ -578,21 +578,28 @@ def _sweep_barrier_stride(Q: int, working_set_bytes: int) -> int:
     return max(1, int(room // working_set_bytes))
 
 
-def _gate_sweep_stats(assembly, planes: bool):
-    """(field operations a row, gates replayed from a packed program) of
-    the assembly's gate sweep on one representation, from the gate set
-    alone (each gate's program is captured once a process). The limb-plane
-    kernel traces every gate directly; the u64 sweep replays a gate past
-    the scan threshold under lax.scan."""
+def _gate_sweep_stats(assembly, selector_paths, planes: bool):
+    """(field operations a row, gates replayed from a packed program, gates
+    under the selector tree) of the assembly's gate sweep on one
+    representation, from the gate set alone (each gate's program is
+    captured once a process). The limb-plane kernel traces every gate
+    directly; the u64 sweep replays a gate past the scan threshold under
+    lax.scan. The third is the plan's gates whose terms the sweep masks by
+    a selector product (a path of one bit or more)."""
     from ..cs.gate_capture import packed_program_for
-    from .stages import gate_sweep_ops_per_row
+    from .stages import gate_sweep_ops_per_row, gate_sweep_plan
 
     packed = 0 if planes else sum(
         packed_program_for(g) is not None
         for g in assembly.gates
         if g.num_terms
     )
-    return gate_sweep_ops_per_row(assembly.gates, assembly.geometry), packed
+    plan = gate_sweep_plan(assembly.gates, selector_paths, assembly.geometry)
+    return (
+        gate_sweep_ops_per_row(assembly.gates, assembly.geometry),
+        packed,
+        sum(1 for _gate, path, _reps in plan if path),
+    )
 
 
 def _coset_sweep_fn(
@@ -1819,9 +1826,12 @@ def _prove_impl(
         # what the gates cost the sweep, from the plan alone: the field
         # operations of one row, and how many gates are replayed from a
         # packed program instead of traced (the u64 path's lax.scan)
-        _ops_per_row, _packed = _gate_sweep_stats(assembly, res)
+        _ops_per_row, _packed, _selected = _gate_sweep_stats(
+            assembly, setup.selector_paths, res
+        )
         _metrics.count("quotient.gate_ops_per_row", _ops_per_row)
         _metrics.count("quotient.packed_gates", _packed)
+        _metrics.count("quotient.selector_tree_gates", _selected)
         if sm_mesh is not None:
             # pad + column-shard the four monomial groups ONCE per round
             # (not per coset); each coset evaluation then runs the

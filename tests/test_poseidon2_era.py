@@ -261,6 +261,9 @@ def test_recorder_says_what_the_gates_cost(recorded):
     assert ops["poseidon2_flat"] == 2036
     assert counters["quotient.gate_ops_per_row"] == sum(ops.values())
     assert counters["quotient.packed_gates"] == 1
+    # the flattened gate and the constants allocator share the trace: two
+    # gates with terms under the selector tree (public_input, nop: none)
+    assert counters["quotient.selector_tree_gates"] == 2
 
 
 def test_altered_opening_is_rejected(proved):
