@@ -12,12 +12,14 @@ a reading is the chip's time and not the host's per-launch overhead.
 Usage: python bench_micro.py  (JSON lines on stdout; backend = ambient JAX)
        python bench_micro.py poseidon2  (the Poseidon2 section alone)
        python bench_micro.py binv       (the batch-inversion section alone)
+       python bench_micro.py ntt        (the forward NTT above 2^16 rows alone)
 """
 
 import json
 import os
 import sys
 import time
+from functools import partial
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
@@ -150,6 +152,95 @@ def batch_inverse_section(backend):
             shape=list(shape), ms=round(dt * 1e3, 3), plan_muls=muls,
             muls_per_elem=round(muls / (shape[0] * shape[1]), 3),
             backend=backend,
+        )
+
+
+# the Era cells' chunks (PERF.md section 5): 64 columns of a coset
+# evaluation under one row, 32 columns of a commit's LDE under L = 2 rows
+NTT_SHAPES = (("coset_eval", 64, None), ("lde", 32, 2))
+
+
+def ntt_section(backend, log_n=18):
+    """The forward transform above 2^16 rows on limb planes: the one fused
+    program a chunk (`limb_ntt._hybrid_fwd_p`: the rows and the outer
+    radix-2 stages are the matmul kernel's prologue) beside the three
+    programs it replaced (PR 34's scale, XLA outer stages and per-block
+    matmul kernel, rebuilt here from the pieces the library keeps), on the
+    same planes and held equal to the bit. Multiplications a second by
+    count: a column transform under a row costs the fused kernel's
+    prologue 7 n / 4 (4 by the row, 3 by the twiddle tables), the scale
+    program n and the two outer stages n / 2 each."""
+    if backend != "tpu":
+        return  # the matmul kernel is native on the TPU alone
+    from boojum_tpu.field import limbs
+    from boojum_tpu.ntt import limb_ntt as LN
+    from boojum_tpu.ntt import mxu_ntt
+
+    n = 1 << log_n
+    stages = log_n - mxu_ntt.MAX_LOG_N
+    ctx = mxu_ntt.get_mxu_ctx(mxu_ntt.MAX_LOG_N)
+
+    @partial(jax.jit, static_argnums=(3,))
+    def scale_p(p, rows, start, size):
+        if start is not None:
+            p = tuple(
+                jax.lax.dynamic_slice_in_dim(a, start, size, 0) for a in p
+            )
+        if rows[0].ndim == 2:
+            p = (p[0][..., None, :], p[1][..., None, :])
+        return limbs.mul(p, rows)
+
+    @jax.jit
+    def outer_p(p):
+        p = LN.dif_stages_p(p, LN.PlaneNTTContext(log_n), 0, stages)
+        return tuple(a.reshape(-1, ctx.R, ctx.C) for a in p)
+
+    @partial(jax.jit, static_argnums=(1,))
+    def mxu_p(flat, shape):
+        out = mxu_ntt._fft_planes(flat, mxu_ntt.MAX_LOG_N, False)
+        return out[0].reshape(shape), out[1].reshape(shape)
+
+    rng = np.random.default_rng(60)
+
+    def planes(*shape):
+        return tuple(
+            jnp.asarray(rng.integers(0, hi, shape, dtype=np.uint32))
+            for hi in (1 << 32, (1 << 32) - 1)  # canonical: hi < 2^32 - 1
+        )
+
+    for name, cols, L in NTT_SHAPES:
+        p, rows = planes(cols, n), planes(*((L, n) if L else (n,)))
+        transforms = cols * (L or 1)
+        programs = LN._LDE_FORWARD if L else LN._COSET_EVAL_FORWARD
+        # a coset evaluation's chunk is cut from its group inside the program
+        call = (p, rows) + ((None, None) if L else (jnp.int32(0), cols))
+        fused = partial(LN._hybrid_fwd_p, p, log_n, programs, *call[1:])
+        dt_fused = timed_call(fused, (), reps=5)
+        dt_scale = timed_call(scale_p, call, reps=5)
+        scaled = scale_p(*call)
+        dt_outer = timed_call(outer_p, (scaled,), reps=5)
+        flat = outer_p(scaled)
+        dt_mxu = timed_call(mxu_p, (flat, scaled[0].shape), reps=5)
+        want, got = mxu_p(flat, scaled[0].shape), fused()
+        equal = all(bool(jnp.array_equal(w, g)) for w, g in zip(want, got))
+        line = dict(
+            shape=[cols] + ([L] if L else []) + [n], backend=backend,
+            equal_to_staged=equal,
+        )
+        for part, dt, muls in (
+            ("fused", dt_fused, 7 * n // 4 * transforms),
+            ("scale", dt_scale, n * transforms),
+            ("outer", dt_outer, stages * (n // 2) * transforms),
+            ("mxu", dt_mxu, 0),
+        ):
+            emit(
+                f"ntt_forward_{name}_{part}_ms", round(dt * 1e3, 3), "ms",
+                us_per_transform=round(dt * 1e6 / transforms, 2),
+                muls_per_s=int(muls / dt), **line,
+            )
+        emit(
+            f"ntt_forward_{name}_staged_over_fused",
+            round((dt_scale + dt_outer + dt_mxu) / dt_fused, 3), "x", **line,
         )
 
 
@@ -757,5 +848,7 @@ if __name__ == "__main__":
         poseidon2_section(jax.default_backend())
     elif sys.argv[1:] == ["binv"]:
         batch_inverse_section(jax.default_backend())
+    elif sys.argv[1:] == ["ntt"]:
+        ntt_section(jax.default_backend())
     else:
         main()

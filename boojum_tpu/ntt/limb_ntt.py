@@ -30,7 +30,7 @@ import numpy as np
 
 from ..field import gl
 from ..field import limbs
-
+from ..utils import metrics as _metrics
 from .ntt import (
     _col_chunks,
     _mxu_ntt_ready,
@@ -170,10 +170,10 @@ def _fft_body(p):
 # ---------------------------------------------------------------------------
 
 
-def _mxu_fft_p(p, inverse: bool, forward=None):
-    """`forward`: the two named programs of a forward transform above
-    2^MAX_LOG_N (`_two_program_forward`); the commits' LDE and the coset
-    evaluations pass their own, so a device trace can tell them apart."""
+def _mxu_fft_p(p, inverse: bool):
+    """The MXU transforms on planes: one kernel up to 2^MAX_LOG_N rows;
+    above, the inverse is `_hybrid_inv_p` and the forward a program of
+    its own (`_hybrid_fwd_p`, here without a row)."""
     from . import mxu_ntt
 
     n = p[0].shape[-1]
@@ -181,7 +181,7 @@ def _mxu_fft_p(p, inverse: bool, forward=None):
     if log_n > mxu_ntt.MAX_LOG_N:
         if inverse:
             return _hybrid_inv_p(p, log_n)
-        return _hybrid_fwd_p(p, log_n, forward or _NTT_FORWARD)
+        return _hybrid_fwd_p(p, log_n, _NTT_FORWARD)
     ctx = mxu_ntt.get_mxu_ctx(log_n)
     lead = p[0].shape[:-1]
     flat = (p[0].reshape(-1, ctx.R, ctx.C), p[1].reshape(-1, ctx.R, ctx.C))
@@ -190,69 +190,69 @@ def _mxu_fft_p(p, inverse: bool, forward=None):
     return out[0].reshape(lead + (n,)), out[1].reshape(lead + (n,))
 
 
-def forward_is_two_programs(n: int) -> bool:
-    """True where the forward transform of size n runs as two device
-    programs (`_hybrid_fwd_p`) and must not be traced into a caller's jit."""
+def forward_is_own_program(n: int) -> bool:
+    """True where the forward transform of size n is a device program of
+    its own (`_hybrid_fwd_p`) and must not be traced into a caller's jit."""
     from . import mxu_ntt
 
     n = int(n)
     return _mxu_ntt_ready(n, None) and n.bit_length() - 1 > mxu_ntt.MAX_LOG_N
 
 
-def _two_program_forward(prefix: str):
-    """(outer, mxu): the two jitted programs of `_hybrid_fwd_p`, named
-    `<prefix>_outer_p` and `<prefix>_mxu_p` so that a device trace (and
+def _forward_programs(prefix: str):
+    """(outer, fused): the jitted programs of `_hybrid_fwd_p`, named
+    `<prefix>_outer_p` and `<prefix>_fused_p` so that a device trace (and
     `benchmark/families.json`) gives each caller's time to its own layer.
-    Reshapes happen inside the programs: nothing runs between them."""
+    Both take `(p, scale, start, size, log_n)`: rows [start, start + size)
+    of the planes `p` (`start` a device scalar; None: all of `p`) under
+    `scale`: an (n,) row -> (.., n), (L, n) rows -> (.., L, n), None: no
+    row. Slices and reshapes happen inside: nothing runs beside them."""
 
-    def outer(p, log_n: int):
-        """The outer radix-2 DIF stages on planes, cut into the
-        2^MAX_LOG_N blocks the MXU kernel transforms: (blocks, R, C)."""
+    def take(p, scale, start, size):
+        if start is not None:
+            p = tuple(
+                jax.lax.dynamic_slice_in_dim(a, start, size, 0) for a in p
+            )
+        rows = () if scale is None else scale[0].shape[:-1]
+        return p, p[0].shape[:-1] + rows + p[0].shape[-1:]
+
+    def outer(p, scale, start, size, log_n: int):
+        """Above 2^(MAX_LOG_N + 2) rows: the rows, which belong before the
+        first stage, and the leading stages the kernel does not absorb."""
+        from .mxu_ntt import leading_outer_stages
+
+        p, _ = take(p, scale, start, size)
+        if scale is not None and scale[0].ndim == 2:
+            p = tuple(a[..., None, :] for a in p)
+        p = p if scale is None else limbs.mul(p, scale)
+        stages = leading_outer_stages(log_n)
+        return dif_stages_p(p, PlaneNTTContext(log_n), 0, stages)
+
+    def fused(p, scale, start, size, log_n: int):
+        """The matmul kernel on (blocks, R, C): the rows and the last
+        `fused_outer_stages(log_n)` outer stages are its prologue."""
         from . import mxu_ntt
 
         ctx = mxu_ntt.get_mxu_ctx(mxu_ntt.MAX_LOG_N)
-        p = dif_stages_p(
-            p, PlaneNTTContext(log_n), 0, log_n - mxu_ntt.MAX_LOG_N
-        )
-        return (
-            p[0].reshape(-1, ctx.R, ctx.C), p[1].reshape(-1, ctx.R, ctx.C)
-        )
+        p, shape = take(p, scale, start, size)
 
-    def mxu(flat, shape: tuple):
-        from . import mxu_ntt
+        def blocks(v):
+            return v and tuple(a.reshape(-1, ctx.R, ctx.C) for a in v)
 
-        out = mxu_ntt._fft_planes(flat, mxu_ntt.MAX_LOG_N, False)
+        k = mxu_ntt.fused_outer_stages(log_n)
+        out = mxu_ntt._fwd_radix_planes(blocks(p), blocks(scale), k, False)
         return out[0].reshape(shape), out[1].reshape(shape)
 
     outer.__name__ = outer.__qualname__ = f"{prefix}_outer_p"
-    mxu.__name__ = mxu.__qualname__ = f"{prefix}_mxu_p"
-    return (
-        jax.jit(outer, static_argnums=(1,)), jax.jit(mxu, static_argnums=(1,))
-    )
+    fused.__name__ = fused.__qualname__ = f"{prefix}_fused_p"
+    return tuple(jax.jit(f, static_argnums=(3, 4)) for f in (outer, fused))
 
 
-_NTT_FORWARD = _two_program_forward("_ntt_hybrid")
+_NTT_FORWARD = _forward_programs("_ntt_hybrid")
 # the commits' LDE keeps `lde_planes` in its programs' names at every size
 # (`mxu_ntt._lde_planes` at or below 2^MAX_LOG_N): one name to find it by
-_LDE_FORWARD = _two_program_forward("_lde_planes_hybrid")
-_COSET_EVAL_FORWARD = _two_program_forward("_coset_eval_hybrid")
-
-
-def _hybrid_fwd_p(p, log_n: int, programs):
-    """2^17..2^22, forward: plane XLA outer stages, then the per-block MXU
-    kernel, as TWO device programs and never one. Compiled into a single
-    program the pair does not come back on the v5e once the batch is more
-    than a few columns: (32, 2, 2^18) planes stalled for over 170 s where
-    the outer stages alone take 6.8 ms and the MXU kernel on their blocks
-    8.6 ms, and a batch of one returns in 2.5 ms (my chip run, PR 26). So
-    this function is not jitted and refuses to be traced into a caller's."""
-    if isinstance(p[0], jax.core.Tracer):
-        raise TypeError(
-            "the forward NTT above 2^16 runs as two device programs: call "
-            "it outside jit (limb_ntt.forward_is_two_programs says when)"
-        )
-    outer, mxu = programs
-    return mxu(outer(p, log_n), tuple(p[0].shape))
+_LDE_FORWARD = _forward_programs("_lde_planes_hybrid")
+_COSET_EVAL_FORWARD = _forward_programs("_coset_eval_hybrid")
 
 
 @partial(jax.jit, static_argnums=(1,))
@@ -324,16 +324,38 @@ def _assemble_chunks_p(shape, produce, starts):
     return out_lo, out_hi
 
 
-@partial(jax.jit, static_argnums=(3,))
-def _coset_eval_scale_p(p, row_p, start, size: int):
-    """Columns [start, start + size) of `p`, each times the row `row_p`
-    (`start` is a device scalar: one program per chunk size)."""
-    return limbs.mul(
-        (
-            jax.lax.dynamic_slice_in_dim(p[0], start, size, axis=0),
-            jax.lax.dynamic_slice_in_dim(p[1], start, size, axis=0),
-        ),
-        (row_p[0][None], row_p[1][None]),
+def _hybrid_fwd_p(p, log_n: int, programs, scale=None, start=None, size=None):
+    """2^17..2^22, forward, under the rows `scale` if any: ONE device
+    program, the matmul kernel with the rows and the last two outer
+    radix-2 stages as its prologue (above 2^18 rows the `outer` program of
+    the leading stages runs before it). Dispatched on its own, never
+    traced into a caller's jit: compiled into one program, XLA stages and
+    the kernel did not come back on the v5e once the batch was more than a
+    few columns ((32, 2, 2^18) planes: over 170 s, my chip run, PR 26)."""
+    if isinstance(p[0], jax.core.Tracer):
+        raise TypeError(
+            "the forward NTT above 2^16 is a device program of its own: call "
+            "it outside jit (limb_ntt.forward_is_own_program says when)"
+        )
+    from . import mxu_ntt
+
+    outer, fused = programs
+    if mxu_ntt.leading_outer_stages(log_n):
+        p = outer(p, scale, start, size, log_n)
+        scale = start = size = None
+    out = fused(p, scale, start, size, log_n)
+    _count_fused_stages(log_n, out[0].size >> log_n)
+    return out
+
+
+def _count_fused_stages(log_n: int, transforms: int):
+    """`ntt.fused_outer_stages`: the radix-2 stages the kernel of one
+    forward dispatch absorbed, times its column transforms (0 a transform
+    up to 2^MAX_LOG_N rows, where there is no outer stage)."""
+    from .mxu_ntt import fused_outer_stages
+
+    _metrics.count(
+        "ntt.fused_outer_stages", fused_outer_stages(log_n) * transforms
     )
 
 
@@ -345,42 +367,21 @@ def scaled_fft_chunks(B: int, n: int, chunk_bytes: int) -> dict:
 
 def scaled_fft_p(p, row_p, chunk_bytes: int):
     """(B, n) monomial planes times one (n,) scale row, then the forward
-    NTT: a coset evaluation. For sizes whose forward transform is two
-    programs (`forward_is_two_programs`): column chunks of at most
-    `chunk_bytes`, each its own scale / outer-stage / MXU dispatches."""
+    NTT: a coset evaluation. For sizes whose forward transform is its own
+    program (`forward_is_own_program`): column chunks of at most
+    `chunk_bytes`, each ONE dispatch (its slice, the row, the transform)."""
     B, n = p[0].shape
     chunks = scaled_fft_chunks(B, n, chunk_bytes)
 
     def produce(i):
-        scaled = _coset_eval_scale_p(p, row_p, jnp.int32(i), chunks[i])
-        return _mxu_fft_p(scaled, False, _COSET_EVAL_FORWARD)
+        return _hybrid_fwd_p(
+            p, n.bit_length() - 1, _COSET_EVAL_FORWARD,
+            row_p, jnp.int32(i), chunks[i],
+        )
 
     if len(chunks) == 1:
         return produce(0)
     return _assemble_chunks_p(p[0].shape, produce, chunks)
-
-
-@jax.jit
-def _lde_planes_scale_p(p, scale):
-    """(..., n) monomial planes times the (L, n) coset scale rows:
-    (..., L, n)."""
-    return limbs.mul((p[0][..., None, :], p[1][..., None, :]), scale)
-
-
-def monomial_from_values_p(p):
-    """Values over H -> monomial coefficients, on planes (chunked)."""
-    lo, hi = p
-    if lo.ndim < 2:
-        return _imono_p_jit(p)
-    B = lo.shape[0]
-    per = _col_chunks(B, lo.size // B * 8)
-    if per is None:
-        return _imono_p_jit(p)
-    return _assemble_chunks_p(
-        lo.shape,
-        lambda i: _imono_p_jit((lo[i : i + per], hi[i : i + per])),
-        range(0, B, per),
-    )
 
 
 def _lde_one_p(p, lde_factor: int, coset: int):
@@ -391,9 +392,8 @@ def _lde_one_p(p, lde_factor: int, coset: int):
         log_n = n.bit_length() - 1
         if log_n > mxu_ntt.MAX_LOG_N:
             scale = _lde_scale_planes(log_n, lde_factor, coset)
-            return _mxu_fft_p(
-                _lde_planes_scale_p(p, scale), False, _LDE_FORWARD
-            )
+            return _hybrid_fwd_p(p, log_n, _LDE_FORWARD, scale)
+        _count_fused_stages(log_n, p[0].size // n * lde_factor)
         ctx = mxu_ntt.get_mxu_ctx(log_n)
         lead = p[0].shape[:-1]
         flat = (
@@ -435,6 +435,22 @@ def lde_from_monomial_p(
     )
 
 
+def monomial_from_values_p(p):
+    """Values over H -> monomial coefficients, on planes (chunked)."""
+    lo, hi = p
+    if lo.ndim < 2:
+        return _imono_p_jit(p)
+    B = lo.shape[0]
+    per = _col_chunks(B, lo.size // B * 8)
+    if per is None:
+        return _imono_p_jit(p)
+    return _assemble_chunks_p(
+        lo.shape,
+        lambda i: _imono_p_jit((lo[i : i + per], hi[i : i + per])),
+        range(0, B, per),
+    )
+
+
 # ---------------------------------------------------------------------------
 # Precompile enumeration (ntt.ntt_kernel_specs twin, resident names)
 # ---------------------------------------------------------------------------
@@ -446,18 +462,20 @@ def sdsp(*shape):
     return (s, s)
 
 
-def hybrid_fwd_kernel_specs(name: str, shape: tuple, log_n: int,
+def hybrid_fwd_kernel_specs(name: str, call: tuple, log_n: int,
                             programs) -> list:
-    """The two programs of `_hybrid_fwd_p` on planes of `shape` (last axis
-    2^log_n)."""
+    """What `_hybrid_fwd_p` dispatches for `call` = (p, scale, start,
+    size), as it takes them (shapes for arrays): the fused program, and
+    above 2^(MAX_LOG_N + 2) rows the outer program before it."""
     from . import mxu_ntt
 
-    ctx = mxu_ntt.get_mxu_ctx(mxu_ntt.MAX_LOG_N)
-    blocks = int(np.prod(shape)) >> mxu_ntt.MAX_LOG_N
-    outer, mxu = programs
+    outer, fused = programs
+    if not mxu_ntt.leading_outer_stages(log_n):
+        return [(f"{name}:fused", fused, (*call, log_n))]
+    staged = jax.eval_shape(lambda *a: outer(*a, call[3], log_n), *call[:3])
     return [
-        (f"{name}:outer", outer, (sdsp(*shape), log_n)),
-        (f"{name}:mxu", mxu, (sdsp(blocks, ctx.R, ctx.C), tuple(shape))),
+        (f"{name}:outer", outer, (*call, log_n)),
+        (f"{name}:fused", fused, (staged, None, None, None, log_n)),
     ]
 
 
@@ -491,13 +509,9 @@ def plane_ntt_kernel_specs(B: int, log_n: int, lde_factor: int | None = None,
         from . import mxu_ntt
 
         if log_n > mxu_ntt.MAX_LOG_N:
-            name = f"lde_hybrid_limbres_b{b}_n{n}_L{L}"
-            specs.append((
-                f"{name}:scale", _lde_planes_scale_p,
-                (sdsp(b, n), sdsp(L, n)),
-            ))
             specs += hybrid_fwd_kernel_specs(
-                name, (b, L, n), log_n, _LDE_FORWARD
+                f"lde_hybrid_limbres_b{b}_n{n}_L{L}",
+                (sdsp(b, n), sdsp(L, n), None, None), log_n, _LDE_FORWARD,
             )
             continue
         ctx = mxu_ntt.get_mxu_ctx(log_n)

@@ -40,8 +40,8 @@ constant bias contribution is subtracted at the end.
 
 Sizes 2^14..2^16 run as single fused kernels; 2^17..2^22 run the leading
 (resp. trailing) radix-2 stages in XLA and drop bit-exactly into per-block
-2^16 kernels (DIF stage s only combines elements 2^16 apart for s < log_n-16,
-so the remaining per-block work is a plain 2^16 transform).
+2^16 kernels (DIF stage s only combines elements 2^16 apart for s < log_n-16);
+the forward on limb planes takes its last two into the kernel (end of file).
 
 Outputs are bit-identical to the staged-XLA path (`ntt.py`): same twiddle
 constants, exact integer arithmetic, canonical representatives.
@@ -653,3 +653,156 @@ def ifft_hybrid_apart(a: jax.Array, log_n: int, interpret: bool = False):
     run, PR 32). The forward's story, on planes, is
     `limb_ntt._hybrid_fwd_p`'s (PR 26)."""
     return _ifft_hybrid_outer(_ifft_hybrid_blocks(a, log_n, interpret), log_n)
+
+
+# ---------------------------------------------------------------------------
+# 2^17 and 2^18 rows forward as ONE kernel: the coset row and the outer
+# radix-2 DIF stages are a radix-2^k prologue of the matmul kernel (PR 35)
+# ---------------------------------------------------------------------------
+# A column of 2^(MAX_LOG_N + k) rows is 2^k contiguous parts of 2^MAX_LOG_N,
+# and k DIF stages only combine the elements at one index j of every part.
+# For k = 2, parts A, B, C, D, the row s, w = omega_n and i = w^(n/4) = 2^48:
+#
+#   a, b, c, d = A[j] s[j], B[j] s[j + n/4], C[j] s[j + n/2], D[j] s[j + 3n/4]
+#   y0[j] =  (a + c) + (b + d)
+#   y1[j] = ((a + c) - (b + d)) * w^(2j)
+#   y2[j] = ((a - c) + i (b - d)) * w^j
+#   y3[j] = ((a - c) - i (b - d)) * w^(3j)
+#
+# and y0..y3 are the blocks the matmul kernel transforms, in its order. It
+# is added BELOW the kernels above so that theirs keep their line numbers
+# (a Mosaic program's cache key carries the call stack it was traced under).
+
+MAX_FUSED_OUTER = 2  # outer stages the prologue absorbs: radix 4
+# Rows of a block a prologue step holds. The kernel alone on 64 columns of
+# 2^18 rows under a row: 8.93 ms at 8 rows (one sublane tile), 7.75 at 16,
+# 7.86 at 32, 8.68 with the whole block in one step; the blocks' matmul
+# kernel without a prologue 6.55 (my chip run, PR 35).
+_PROLOGUE_ROWS = 16
+
+
+def fused_outer_stages(log_n: int) -> int:
+    """Radix-2 stages of a size-2^log_n forward transform that run inside
+    the matmul kernel (`_fwd_radix_planes`): 0 up to the single-kernel
+    ceiling, 1 at the next size, 2 from there up."""
+    return max(0, min(log_n - MAX_LOG_N, MAX_FUSED_OUTER))
+
+
+def leading_outer_stages(log_n: int) -> int:
+    """The outer stages before those: what an XLA program still runs
+    ahead of the kernel, above 2^(MAX_LOG_N + MAX_FUSED_OUTER) rows."""
+    return max(0, log_n - MAX_LOG_N) - fused_outer_stages(log_n)
+
+
+@lru_cache(maxsize=None)
+def _radix_tables(log_block: int, k: int):
+    """(lo, hi), each (2^k - 1, R, C): w^(m j) for m = 1 .. 2^k - 1 and
+    j < 2^log_block, w = omega of the 2^(log_block + k) rows, laid out as
+    the kernel's blocks (element j at [j // C][j % C])."""
+    ctx = get_mxu_ctx(log_block)
+    w = gl.omega(log_block + k)
+    # the radix-4 butterfly multiplies by i = w^(n/4) with shifts alone
+    assert k == 1 or gl.pow_(w, 1 << log_block) == 1 << 48
+    tabs = np.stack([
+        _pow_table(gl.pow_(w, m), 1 << log_block).reshape(ctx.R, ctx.C)
+        for m in range(1, 1 << k)
+    ])
+    with jax.ensure_compile_time_eval():
+        return _pair_np(tabs)
+
+
+def _mul_i(x):
+    """x * 2^48, a primitive fourth root of unity (2^96 = -1 mod p): a
+    16-bit shift and a word shift, then the 128-bit reduction."""
+    q0, q1, q2 = limbs._shl96(x, 16)
+    return limbs.reduce128(jnp.zeros_like(q0), q0, q1, q2)
+
+
+def radix_prologue(x, s, w):
+    """The 2^k parts `x` of a column times the parts `s` of its row (None:
+    no row), then k radix-2 DIF stages across the parts, k = 1 or 2. `w`:
+    the 2^k - 1 tables of `_radix_tables`. Pairs in, pairs out, exact."""
+    if s is not None:
+        x = [limbs.mul(xi, si) for xi, si in zip(x, s)]
+    if len(x) == 2:
+        a, b = x
+        return [limbs.add(a, b), limbs.mul(limbs.sub(a, b), w[0])]
+    a, b, c, d = x
+    ac, bd = limbs.add(a, c), limbs.add(b, d)
+    ca, db = limbs.sub(a, c), _mul_i(limbs.sub(b, d))
+    return [
+        limbs.add(ac, bd),
+        limbs.mul(limbs.sub(ac, bd), w[1]),
+        limbs.mul(limbs.add(ca, db), w[0]),
+        limbs.mul(limbs.sub(ca, db), w[2]),
+    ]
+
+
+def _fwd_radix_kernel(ctx, G, scaled, dr, dct, tlo, thi, wl, wh, *refs):
+    """One column (and one row of the scale) a grid step: the prologue a
+    few rows at a time, staged in the output block, then `_fwd_body` on
+    the G blocks it left there."""
+    sl, sh = refs[:2] if scaled else (None, None)
+    xl, xh, ol, oh = refs[-4:]
+    R, C = ctx.R, ctx.C
+
+    def step(r, _):
+        # i32 arithmetic: under x64 a bare product is i64, which Mosaic refuses
+        first = pl.multiple_of(jnp.int32(_PROLOGUE_ROWS) * r, _PROLOGUE_ROWS)
+        rows = pl.ds(first, _PROLOGUE_ROWS)
+        x = [(xl[g, rows, :], xh[g, rows, :]) for g in range(G)]
+        s = None
+        if scaled:
+            s = [(sl[g, rows, :], sh[g, rows, :]) for g in range(G)]
+        w = [(wl[m, rows, :], wh[m, rows, :]) for m in range(G - 1)]
+        for g, y in enumerate(radix_prologue(x, s, w)):
+            ol[g, rows, :] = y[0]
+            oh[g, rows, :] = y[1]
+
+    steps = jnp.int32(R // _PROLOGUE_ROWS)
+    jax.lax.fori_loop(jnp.int32(0), steps, step, None)
+    z = _fwd_body(ctx, (ol[:], oh[:]), dr, dct, tlo[:], thi[:], G)
+    ol[:] = z[0].reshape(G, R, C)
+    oh[:] = z[1].reshape(G, R, C)
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _fwd_radix_planes(planes, scale_planes, k: int, interpret: bool):
+    """Forward transforms of 2^(MAX_LOG_N + k) rows, k = 1 or 2, scale
+    and outer stages fused. `planes`: (B * 2^k, R, C), the 2^k parts of B
+    columns; `scale_planes`: (L * 2^k, R, C), the parts of L rows, or None
+    for a transform without a row (L = 1) -> (B * L * 2^k, R, C): column
+    b under row l, bit-reversed, at blocks [(b * L + l) * 2^k, + 2^k)."""
+    G = 1 << k
+    ctx = get_mxu_ctx(MAX_LOG_N)
+    R, C = ctx.R, ctx.C
+    lo, hi = planes
+    B = lo.shape[0] // G
+    scaled = scale_planes is not None
+    L = scale_planes[0].shape[0] // G if scaled else 1
+
+    def spec(index):
+        return pl.BlockSpec((G, R, C), imap32(index), memory_space=pltpu.VMEM)
+
+    tables = _radix_tables(MAX_LOG_N, k)
+    scale_specs = [spec(lambda b, l: (l, 0, 0))] * 2 if scaled else []
+    out_shape = jax.ShapeDtypeStruct((B * L * G, R, C), jnp.uint32)
+    return pl.pallas_call(
+        partial(_fwd_radix_kernel, ctx, G, scaled),
+        grid=(B, L),
+        out_shape=[out_shape, out_shape],
+        in_specs=[
+            _const_spec((8, R, R)),
+            _const_spec((8, C, C)),
+            _const_spec((R, C)),
+            _const_spec((R, C)),
+            _const_spec((G - 1, R, C)),
+            _const_spec((G - 1, R, C)),
+            *scale_specs,
+            spec(lambda b, l: (b, 0, 0)),
+            spec(lambda b, l: (b, 0, 0)),
+        ],
+        out_specs=[spec(lambda b, l: (b * L + l, 0, 0))] * 2,
+        interpret=interpret,
+        compiler_params=None if interpret else _COMPILER_PARAMS,
+    )(ctx.dr, ctx.dct, *ctx.tw, *tables, *(scale_planes or ()), lo, hi)

@@ -545,10 +545,10 @@ def _coset_eval_row_p(scale_q_p, c_arr):
 def coset_eval_q_p(mono_p, scale_q_p, c_arr):
     """One group's evaluation on coset `c_arr` of the rate-Q domain: one
     program (`_coset_eval_q_p`) up to 2^16 rows; above, where the forward
-    NTT is two programs that may not share one (limb_ntt._hybrid_fwd_p),
-    the scale row's pick and then scale / outer stages / MXU kernel per
-    column chunk, each its own dispatch."""
-    if not LN.forward_is_two_programs(mono_p[0].shape[-1]):
+    NTT is a program of its own that no jit may hold
+    (limb_ntt._hybrid_fwd_p), the scale row's pick and then ONE dispatch a
+    column chunk: its slice, the row and the transform in one kernel."""
+    if not LN.forward_is_own_program(mono_p[0].shape[-1]):
         return _coset_eval_q_p(mono_p, scale_q_p, c_arr)
     return LN.scaled_fft_p(
         mono_p, _coset_eval_row_p(scale_q_p, c_arr), _SWEEP_EVAL_CHUNK
@@ -557,22 +557,22 @@ def coset_eval_q_p(mono_p, scale_q_p, c_arr):
 
 def coset_eval_kernel_specs(tag: str, B: int, n: int, Q: int) -> list:
     """(name, fn, args) of what `coset_eval_q_p` dispatches for a (B, n)
-    group: the one program, or the pieces of the two-program form."""
+    group: the one program, or the row's pick and a fused transform for
+    each size of column chunk (the chunk's first column is a device
+    scalar, so chunks of one size share a program; above 2^18 rows the
+    outer program of the leading stages comes with it)."""
     sdsp = LN.sdsp
     i32 = jax.ShapeDtypeStruct((), jnp.int32)
     name = f"coset_eval_{tag}_limbres"
-    if not LN.forward_is_two_programs(n):
+    if not LN.forward_is_own_program(n):
         return [(name, _coset_eval_q_p, (sdsp(B, n), sdsp(Q, n), i32))]
     log_n = n.bit_length() - 1
     specs = [(f"{name}:row", _coset_eval_row_p, (sdsp(Q, n), i32))]
     sizes = LN.scaled_fft_chunks(B, n, _SWEEP_EVAL_CHUNK).values()
     for b in sorted(set(sizes)):
-        specs.append((
-            f"{name}:scale_b{b}", LN._coset_eval_scale_p,
-            (sdsp(B, n), sdsp(n), i32, b),
-        ))
         specs += LN.hybrid_fwd_kernel_specs(
-            f"{name}:fft_b{b}", (b, n), log_n, LN._COSET_EVAL_FORWARD
+            f"{name}:fft_b{b}", (sdsp(B, n), sdsp(n), i32, b),
+            log_n, LN._COSET_EVAL_FORWARD,
         )
     return specs
 

@@ -249,3 +249,32 @@ def test_round3_pick_of_the_committed_cosets(one_chip, label, widths, log_n, L):
     tiled = sum(-(-B // 8) * 8 for B in widths) * n * 8
     assert mem.output_size_in_bytes - tiled < 1 << 16, label
     assert mem.temp_size_in_bytes == 0, label
+
+
+# the Era cells' chunks at 2^18 rows: 64 columns of a coset evaluation cut
+# from a 130-column group under one row; 32 columns of a commit under the
+# two rows of LDE 2
+@pytest.mark.parametrize("form", ["coset_eval", "lde"])
+def test_fused_forward_ntt_at_the_era_chunks(one_chip, form):
+    """ISSUE 35: the forward transform of 2^18 rows as one program a chunk:
+    the matmul kernel with the rows and two outer radix-2 stages as its
+    radix-4 prologue, the chunk's slice and the relayouts to and from the
+    kernel's (blocks, 256, 256) inside the same program; it lowers and
+    compiles for the chip and holds no more than a chunk beside its
+    arguments and result."""
+    from boojum_tpu.ntt import limb_ntt as LN
+
+    n = 1 << 18
+    if form == "coset_eval":
+        start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        program = LN._COSET_EVAL_FORWARD[1]
+        args = (_pair(one_chip, 130, n), _pair(one_chip, n), start, 64, 18)
+    else:
+        program = LN._LDE_FORWARD[1]
+        args = (_pair(one_chip, 32, n), _pair(one_chip, 2, n), None, None, 18)
+    compiled = program.lower(*args).compile()
+    txt = compiled.as_text()
+    assert txt.count("custom_call_target=\"tpu_custom_call\"") == 1
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >> 20 == 128  # (64, n) or (32, 2, n) x 2
+    assert mem.temp_size_in_bytes <= mem.output_size_in_bytes + (1 << 20)

@@ -308,20 +308,21 @@ def test_sweep_barrier_is_chosen_from_device_memory(
 
 
 def test_forward_ntt_above_2_16_refuses_to_be_traced_into_one_program():
-    """On the v5e the outer radix-2 stages and the MXU kernel of a forward
+    """On the v5e XLA's outer radix-2 stages and the MXU kernel of a forward
     transform above 2^16 do not come back when compiled into one program
-    (PERF.md, PR 26): `_hybrid_fwd_p` dispatches them as two and raises if
-    a caller's jit would fuse them after all."""
+    (PERF.md, PR 26): `_hybrid_fwd_p` dispatches the transform as a program
+    of its own (since PR 35 the stages are the kernel's prologue) and
+    raises if a caller's jit would hold it after all."""
     import jax
     import jax.numpy as jnp
 
     from boojum_tpu.ntt import limb_ntt as LN
 
     p = (jnp.zeros((2, 1 << 17), jnp.uint32),) * 2
-    with pytest.raises(TypeError, match="two device programs"):
+    with pytest.raises(TypeError, match="a device program of its own"):
         jax.jit(lambda q: LN._hybrid_fwd_p(q, 17, LN._LDE_FORWARD))(p)
     # off a TPU the MXU transform is not in use and nothing changes
-    assert not LN.forward_is_two_programs(1 << 18)
+    assert not LN.forward_is_own_program(1 << 18)
 
 
 @pytest.mark.parametrize("resident", [False, True])
@@ -351,3 +352,33 @@ def test_era_library_lists_the_pick_beside_the_transforms(
     assert widths == [(sb.B_wit, N), (sb.B_setup, N), (sb.S, N)], widths
     for name in evals:
         specs[name].fn.lower(*specs[name].args)
+
+
+def test_era_library_lists_one_fused_transform_a_chunk_at_the_cells_size(
+    monkeypatch,
+):
+    """ISSUE 35: at the Era cells' 2^18 rows a group's coset evaluation is
+    the row's pick and ONE fused program for each size of column chunk
+    (64 and the remainder), where PR 26 listed a scale, an outer-stage and
+    an MXU program for each; a commit's LDE likewise. Their names keep
+    `coset_eval` and `lde_planes`, which the benchmark's families and
+    `kernel.lde_hbm_share` find them by."""
+    from boojum_tpu.ntt import limb_ntt as LN
+    from boojum_tpu.prover import resident as RES
+
+    monkeypatch.setattr(LN, "_mxu_ntt_ready", lambda n, ctx: True)
+    n = 1 << 18
+    for tag, B, sizes in (("wit", 155, (27, 64)), ("zs", 2, (2,))):
+        specs = RES.coset_eval_kernel_specs(tag, B, n, 8)
+        stem = f"coset_eval_{tag}_limbres"
+        assert [s[0] for s in specs] == [f"{stem}:row"] + [
+            f"{stem}:fft_b{b}:fused" for b in sizes
+        ]
+        assert {s[1].__name__ for s in specs[1:]} == {
+            "_coset_eval_hybrid_fused_p"
+        }
+    lde = LN.plane_ntt_kernel_specs(155, 18, 2, mono=False)
+    assert [s[0] for s in lde] == [
+        f"lde_hybrid_limbres_b{b}_n{n}_L2:fused" for b in (27, 32)
+    ]
+    assert {s[1].__name__ for s in lde} == {"_lde_planes_hybrid_fused_p"}
