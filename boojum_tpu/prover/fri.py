@@ -22,6 +22,7 @@ from ..field import extension as ext_f
 from ..field import goldilocks as gf
 from ..merkle import MerkleTreeWithCap
 from ..utils import metrics as _metrics
+from ..utils import transfer as _transfer
 from ..utils.report import checkpoint as _checkpoint
 from ..utils.spans import span as _span
 from ..ntt import (
@@ -254,14 +255,14 @@ def _fri_commit_fn_p(k: int, cap: int):
     from ..merkle import _node_layers_planes_body
 
     @jax.jit
-    def fn(c0, c1):
+    def _fri_oracle_p(c0, c1):
         N = c0[0].shape[0]
         llo = jnp.stack([c0[0], c1[0]], axis=-1).reshape(N >> k, -1)
         lhi = jnp.stack([c0[1], c1[1]], axis=-1).reshape(N >> k, -1)
         dig = leaf_hash_planes((llo, lhi))
         return _node_layers_planes_body(dig, cap)
 
-    return fn
+    return _fri_oracle_p
 
 
 @lru_cache(maxsize=None)
@@ -294,16 +295,16 @@ def _fri_fold_fn_p(k: int, mesh=None):
         )
 
         @jax.jit
-        def fn(c0, c1, tb, tables):
+        def _fri_fold_p(c0, c1, tb, tables):
             return smf(c0, c1, tb, *tables)
 
-        return fn
+        return _fri_fold_p
 
     @jax.jit
-    def fn(c0, c1, tb, tables):
+    def _fri_fold_p(c0, c1, tb, tables):
         return body(c0, c1, tb, *tables)
 
-    return fn
+    return _fri_fold_p
 
 
 @_partial(jax.jit, static_argnums=(2,))
@@ -501,9 +502,10 @@ def fri_prove(
                     layers = _fri_commit_fn_p(
                         k, config.merkle_tree_cap_size
                     )(cur[0], cur[1])
-                tree = PlaneMerkleTree.from_layers(
-                    list(layers), config.merkle_tree_cap_size
-                )
+                with _transfer.pull_site(f"fri_cap_{r}"):
+                    tree = PlaneMerkleTree.from_layers(
+                        list(layers), config.merkle_tree_cap_size
+                    )
             elif fused:
                 if mesh_k is not None:
                     from ..parallel.shard_sweep import fri_commit_sm
@@ -515,34 +517,42 @@ def fri_prove(
                     layers = _fri_commit_fn(
                         k, config.merkle_tree_cap_size
                     )(*cur)
-                tree = MerkleTreeWithCap.from_layers(
-                    list(layers), config.merkle_tree_cap_size
-                )
+                with _transfer.pull_site(f"fri_cap_{r}"):
+                    tree = MerkleTreeWithCap.from_layers(
+                        list(layers), config.merkle_tree_cap_size
+                    )
             else:
-                tree = commit_codeword(
-                    cur, config.merkle_tree_cap_size, elems_per_leaf=1 << k
-                )
+                with _transfer.pull_site(f"fri_cap_{r}"):
+                    tree = commit_codeword(
+                        cur, config.merkle_tree_cap_size,
+                        elems_per_leaf=1 << k,
+                    )
             _metrics.count("fri.oracle_commits")
             out.trees.append(tree)
             out.values.append(cur)
-            transcript.witness_merkle_tree_cap(tree.get_cap())
-            _checkpoint(5, f"fri_cap_{r}", tree.get_cap())
-            ch = transcript.get_ext_challenge()
-            _checkpoint(5, f"fri_challenge_{r}", ch)
+            with _span("host.transcript"):
+                transcript.witness_merkle_tree_cap(tree.get_cap())
+                _checkpoint(5, f"fri_cap_{r}", tree.get_cap())
+                ch = transcript.get_ext_challenge()
+                _checkpoint(5, f"fri_challenge_{r}", ch)
             out.challenges.append(ch)
             _metrics.count("fri.folds", k)
             if resident:
                 _metrics.count("fri.resident_folds", k)
                 if mesh_k is not None:
                     _metrics.count("fri.sm_folds", k)
-                tb = jnp.asarray(_ch_table_np(ch))
+                with _transfer.upload("fri_challenge", 16):
+                    tb = jnp.asarray(_ch_table_np(ch))
                 cur = _fri_fold_fn_p(k, mesh_k)(
                     cur[0], cur[1], tb,
                     tuple(tables[fold_round : fold_round + k]),
                 )
                 fold_round += k
             elif fused:
-                ch01 = jnp.asarray(np.array([ch[0], ch[1]], dtype=np.uint64))
+                with _transfer.upload("fri_challenge", 16):
+                    ch01 = jnp.asarray(
+                        np.array([ch[0], ch[1]], dtype=np.uint64)
+                    )
                 if mesh_k is not None:
                     _metrics.count("fri.sm_folds", k)
                 cur = _fri_fold_fn(k, mesh_k)(
@@ -596,9 +606,10 @@ def fri_prove(
         "final FRI polynomial exceeds degree bound"
     )
     out.final_monomials = [(int(a), int(b)) for a, b in zip(m0[:deg_bound], m1[:deg_bound])]
-    for c0, c1 in out.final_monomials:
-        transcript.witness_field_elements([c0, c1])
-    _checkpoint(5, "fri_final_monomials", out.final_monomials)
+    with _span("host.transcript"):
+        for c0, c1 in out.final_monomials:
+            transcript.witness_field_elements([c0, c1])
+        _checkpoint(5, "fri_final_monomials", out.final_monomials)
     out.num_folds = num_folds
     return out
 

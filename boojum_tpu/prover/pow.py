@@ -12,21 +12,25 @@ backends: seed = 4 challenges as LE bytes, digest's first LE u64 must have
 """
 
 from ..hashes.poseidon2 import Poseidon2SpongeHost
+from ..utils.spans import span as _span
 
 
 def pow_grind(transcript, pow_bits: int) -> int:
     if pow_bits == 0:
         return 0
     assert pow_bits <= 32, "unreasonable pow difficulty"
-    seed = transcript.get_multiple_challenges(4)
-    mask = (1 << pow_bits) - 1
-    nonce = 0
-    while True:
-        h = Poseidon2SpongeHost.hash_leaf(seed + [nonce])
-        if h[0] & mask == 0:
-            break
-        nonce += 1
-    transcript.witness_field_elements([nonce])
+    # the seed's draw, the grinding and the nonce's absorb are the host's
+    # own time, like every other block of transcript work in a prove
+    with _span("host.transcript", pow_bits=pow_bits):
+        seed = transcript.get_multiple_challenges(4)
+        mask = (1 << pow_bits) - 1
+        nonce = 0
+        while True:
+            h = Poseidon2SpongeHost.hash_leaf(seed + [nonce])
+            if h[0] & mask == 0:
+                break
+            nonce += 1
+        transcript.witness_field_elements([nonce])
     return nonce
 
 

@@ -5,6 +5,8 @@ from __future__ import annotations
 import json
 from dataclasses import dataclass, field
 
+from ..utils.spans import span as _span
+
 
 @dataclass
 class OracleQuery:
@@ -46,7 +48,10 @@ class Proof:
                 return list(o)
             raise TypeError(type(o))
 
-        return json.dumps(self.__dict__, default=enc)
+        # serialisation as the program's own span: a request that ends in
+        # proof bytes (the benchmark's) shows what they cost the host
+        with _span("proof.to_json"):
+            return json.dumps(self.__dict__, default=enc)
 
     @staticmethod
     def from_json(s: str) -> "Proof":

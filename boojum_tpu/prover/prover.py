@@ -63,7 +63,9 @@ class _StageClock:
     span (incl. any jax.profiler annotation), recording the partial stage
     with an `error` field instead of dropping it. Each stage start also
     takes a metrics boundary snapshot (live-buffer census + device memory
-    high water) when a registry is installed."""
+    high water) when a registry is installed; the registry files the
+    `mem.in_use_mib.<stage>` samples of the stage's syncs and uploads under
+    the name it was handed."""
 
     def __init__(self):
         self._cm = None
@@ -71,26 +73,13 @@ class _StageClock:
     def start(self, name):
         self.stop()
         _metrics.stage_boundary(name)
-        import os
-
-        if os.environ.get("BOOJUM_TPU_MEMLOG"):
-            import sys
-
-            census = _metrics.live_buffer_census()
-            if census is not None:
-                num, total = census
-                print(
-                    f"[boojum_tpu mem] before {name}: "
-                    f"{total / 2**30:.2f} GiB ({num} arrays)",
-                    file=sys.stderr,
-                    flush=True,
-                )
         self._cm = _span(name, stage=True)
         self._cm.__enter__()
 
     def stop(self, error: BaseException | None = None):
         if self._cm is None:
             return
+        _metrics.stage_closed()
         cm, self._cm = self._cm, None
         if error is None:
             cm.__exit__(None, None, None)
@@ -325,16 +314,16 @@ def _dev_cached(obj, name: str, build):
     import os
 
     if os.environ.get("BOOJUM_TPU_CACHE_DEVICE_INPUTS", "").strip() == "0":
-        return _metrics.count_upload(build())
+        return _transfer.uploaded(name, build)
     cache = getattr(obj, "_dev_cache", None)
     if cache is None:
         cache = {}
         try:
             obj._dev_cache = cache
         except Exception:
-            return _metrics.count_upload(build())
+            return _transfer.uploaded(name, build)
     if name not in cache:
-        cache[name] = _metrics.count_upload(build())
+        cache[name] = _transfer.uploaded(name, build)
     return cache[name]
 
 
@@ -1072,7 +1061,8 @@ def _deep_round5_prep(
     if isinstance(s2_lde_flat, MonomialSource):
         s2_cols = _cols_from_mono(s2_mono, tuple(s2_idxs), L)
     else:
-        s2_cols = s2_lde_flat[jnp.asarray(np.array(s2_idxs))]
+        with _transfer.upload("deep_prep", 8 * len(s2_idxs)):
+            s2_cols = s2_lde_flat[jnp.asarray(np.array(s2_idxs))]
     inv_x = (
         _inv_xs_brev(log_n, L) if lookups else jnp.zeros((1,), jnp.uint64)
     )
@@ -1081,20 +1071,22 @@ def _deep_round5_prep(
         if isinstance(wit_lde_all, MonomialSource):
             cols_pi = _cols_from_mono(wit_mono, tuple(pi_cols_idx), L)
         else:
-            cols_pi = wit_lde_all[jnp.asarray(np.array(pi_cols_idx))]
+            with _transfer.upload("deep_prep", 8 * num_pi):
+                cols_pi = wit_lde_all[jnp.asarray(np.array(pi_cols_idx))]
         pi_points = np.array(
             [gl.pow_(omega, r) for (_c, r, _v) in assembly.public_inputs],
             dtype=np.uint64,
         )
-        pi_denoms = gf.batch_inverse(
-            gf.sub(xs_lde[None, :], jnp.asarray(pi_points)[:, None])
-        )
-        pi_vals = jnp.asarray(
-            np.array(
-                [v for (_c, _r, v) in assembly.public_inputs],
-                dtype=np.uint64,
+        with _transfer.upload("deep_prep", 16 * num_pi, 2):
+            pi_denoms = gf.batch_inverse(
+                gf.sub(xs_lde[None, :], jnp.asarray(pi_points)[:, None])
             )
-        )
+            pi_vals = jnp.asarray(
+                np.array(
+                    [v for (_c, _r, v) in assembly.public_inputs],
+                    dtype=np.uint64,
+                )
+            )
     else:
         cols_pi = jnp.zeros((0, N), jnp.uint64)
         pi_denoms = cols_pi
@@ -1359,12 +1351,13 @@ def _prove_impl(
                 lookups=lookups, lk_mode=lk_mode, resident=res,
             )
 
-    t = make_transcript(setup.vk.transcript)
-    t.witness_merkle_tree_cap(setup.vk.setup_merkle_cap)
-    _checkpoint(0, "setup_cap", setup.vk.setup_merkle_cap)
-    pi_values = [v for (_c, _r, v) in assembly.public_inputs]
-    t.witness_field_elements(pi_values)
-    _checkpoint(0, "public_inputs", pi_values)
+    with _span("host.transcript"):
+        t = make_transcript(setup.vk.transcript)
+        t.witness_merkle_tree_cap(setup.vk.setup_merkle_cap)
+        _checkpoint(0, "setup_cap", setup.vk.setup_merkle_cap)
+        pi_values = [v for (_c, _r, v) in assembly.public_inputs]
+        t.witness_field_elements(pi_values)
+        _checkpoint(0, "public_inputs", pi_values)
 
     # ---- round 1: witness commitment -------------------------------------
     clock.start("round1_witness_commit")
@@ -1387,22 +1380,25 @@ def _prove_impl(
                 witness_cols, L, cap, stream, sm_mesh
             )
         _prefetch_r(layers[-1])  # cap d2h rides the queue
-        wit_tree = _tree_r(layers)
+        with _transfer.pull_site("witness_cap"):
+            wit_tree = _tree_r(layers)
     else:
         wit_mono = monomial_from_values(witness_cols)
         wit_lde = lde_from_monomial(wit_mono, L)  # (Ct+W+M, L, n)
-        wit_tree, _ = _commit_columns(wit_lde, cap)
+        with _transfer.pull_site("witness_cap"):
+            wit_tree, _ = _commit_columns(wit_lde, cap)
     del witness_cols  # values over H: monomials carry them from here
-    t.witness_merkle_tree_cap(wit_tree.get_cap())
-    _checkpoint(1, "witness_cap", wit_tree.get_cap())
-    beta = t.get_ext_challenge()
-    gamma = t.get_ext_challenge()
-    r1_challenges = [beta, gamma]
-    if lookups:
-        lookup_beta = t.get_ext_challenge()
-        lookup_gamma = t.get_ext_challenge()
-        r1_challenges += [lookup_beta, lookup_gamma]
-    _checkpoint(1, "challenges", r1_challenges)
+    with _span("host.transcript"):
+        t.witness_merkle_tree_cap(wit_tree.get_cap())
+        _checkpoint(1, "witness_cap", wit_tree.get_cap())
+        beta = t.get_ext_challenge()
+        gamma = t.get_ext_challenge()
+        r1_challenges = [beta, gamma]
+        if lookups:
+            lookup_beta = t.get_ext_challenge()
+            lookup_gamma = t.get_ext_challenge()
+            r1_challenges += [lookup_beta, lookup_gamma]
+        _checkpoint(1, "challenges", r1_challenges)
 
     # ---- round 2: copy-permutation + lookup stage 2 ----------------------
     clock.start("round2_stage2_commit")
@@ -1435,7 +1431,8 @@ def _prove_impl(
                 )
             ),
         )
-        bg_arr = jnp.asarray(RES.bg_np(beta, gamma))
+        with _transfer.upload("challenges_r2", 32):
+            bg_arr = jnp.asarray(RES.bg_np(beta, gamma))
         with _span("stage2_chunk_num_den"):
             num_all, den_all = RES._all_chunk_num_den_p(
                 copy_vals, sigma_dev, ks, (xs_h, bg_arr),
@@ -1472,7 +1469,8 @@ def _prove_impl(
                     consts_dev[0][len(mk_path_r2)],
                     consts_dev[1][len(mk_path_r2)],
                 )
-            lkbg_arr = jnp.asarray(RES.bg_np(lookup_beta, lookup_gamma))
+            with _transfer.upload("challenges_r2", 32):
+                lkbg_arr = jnp.asarray(RES.bg_np(lookup_beta, lookup_gamma))
             dens = RES._lookup_denominators_p(
                 lkcols, (tid_col, table_stack), lkbg_arr, R_args, lp.width
             )
@@ -1485,7 +1483,8 @@ def _prove_impl(
         )
         del s2_vals
         _prefetch_r(layers[-1])
-        s2_tree = _tree_r(layers)
+        with _transfer.pull_site("stage2_cap"):
+            s2_tree = _tree_r(layers)
         num_all = den_all = den_inv_all = lk_inv = dens = mult_dev = None
         z_pp = None
         if stream:
@@ -1521,7 +1520,8 @@ def _prove_impl(
         def _pair(s):
             return jnp.asarray(np.array([s[0], s[1]], dtype=np.uint64))
 
-        beta01, gamma01 = _pair(beta), _pair(gamma)
+        with _transfer.upload("challenges_r2", 32, 2):
+            beta01, gamma01 = _pair(beta), _pair(gamma)
         with _span("stage2_chunk_num_den"):
             num_all, den_all = _all_chunk_num_den(
                 copy_vals, sigma_dev, ks, xs_h,
@@ -1533,7 +1533,8 @@ def _prove_impl(
         lk_inv = mult_dev = consts_dev = None
         lkb01 = lkg01 = None
         if lookups:
-            lkb01, lkg01 = _pair(lookup_beta), _pair(lookup_gamma)
+            with _transfer.upload("challenges_r2", 32, 2):
+                lkb01, lkg01 = _pair(lookup_beta), _pair(lookup_gamma)
             table_stack = _dev_cached(
                 assembly,
                 "table_stack",
@@ -1574,7 +1575,8 @@ def _prove_impl(
         )
         del s2_vals
         _transfer.prefetch_async(layers[-1])
-        s2_tree = _tree_from_layers(layers, cap)
+        with _transfer.pull_site("stage2_cap"):
+            s2_tree = _tree_from_layers(layers, cap)
         # the chunk numerator/denominator ext stacks, the z/partials and
         # the lookup denominators total ~2 GB at 2^20 rows and are dead
         # after the commit — rebind so the buffers free before the
@@ -1647,12 +1649,14 @@ def _prove_impl(
         s2_mono = monomial_from_values(stage2_cols)
         del stage2_cols
         s2_lde = lde_from_monomial(s2_mono, L)
-        s2_tree, _ = _commit_columns(s2_lde, cap)
+        with _transfer.pull_site("stage2_cap"):
+            s2_tree, _ = _commit_columns(s2_lde, cap)
     del copy_vals, sigma_dev  # round 3 reads sigmas from the setup monomials
-    t.witness_merkle_tree_cap(s2_tree.get_cap())
-    _checkpoint(2, "stage2_cap", s2_tree.get_cap())
-    alpha = t.get_ext_challenge()
-    _checkpoint(2, "alpha", alpha)
+    with _span("host.transcript"):
+        t.witness_merkle_tree_cap(s2_tree.get_cap())
+        _checkpoint(2, "stage2_cap", s2_tree.get_cap())
+        alpha = t.get_ext_challenge()
+        _checkpoint(2, "alpha", alpha)
 
     # ---- round 3: quotient (streamed per coset at rate Q) ----------------
     # The sweep runs over Q = vk.quotient_degree cosets while every oracle
@@ -1788,14 +1792,14 @@ def _prove_impl(
         if res:
             # the alpha/γ-power scalar table is host-built; no device u64
             # challenge arrays exist in the resident round
-            sweep_tb = jnp.asarray(
-                RES.sweep_table_np(
-                    alpha, total_alpha_terms, beta, gamma,
-                    lookup_beta if lookups else (0, 0),
-                    lookup_gamma if lookups else (0, 0),
-                    lookups, (lp.width if lookups else 0),
-                )
+            _sweep_tb_np = RES.sweep_table_np(
+                alpha, total_alpha_terms, beta, gamma,
+                lookup_beta if lookups else (0, 0),
+                lookup_gamma if lookups else (0, 0),
+                lookups, (lp.width if lookups else 0),
             )
+            with _transfer.upload("sweep_table", _sweep_tb_np.nbytes):
+                sweep_tb = jnp.asarray(_sweep_tb_np)
         else:
             ap = AlphaPows(alpha, total_alpha_terms)
             zero2 = jnp.zeros((2,), jnp.uint64)
@@ -1895,7 +1899,8 @@ def _prove_impl(
             resident=res, sm=sm_mesh is not None,
         ):
             for c in range(Q):
-                ci = jnp.int32(c)
+                with _transfer.upload("coset_index", 4):
+                    ci = jnp.int32(c)
                 _metrics.count("quotient.coset_sweeps")
                 if res:
                     # flight-recorder surface: makes "which representation
@@ -1922,7 +1927,8 @@ def _prove_impl(
                 ):
                     _metrics.count("quotient.sweep_barriers")
                     _metrics.count("host.blocking_syncs")
-                    jax.block_until_ready(t1c)
+                    with _transfer.sync("sweep_barrier"):
+                        jax.block_until_ready(t1c)
                 T_parts0.append(t0c)
                 T_parts1.append(t1c)
                 # the queued sweep holds its inputs: drop ours, so that a
@@ -1959,7 +1965,8 @@ def _prove_impl(
             )
         del T_parts0, T_parts1
         _prefetch_r(layers[-1])
-        q_tree = _tree_r(layers)
+        with _transfer.pull_site("quotient_cap"):
+            q_tree = _tree_r(layers)
     else:
         T_parts0, T_parts1 = [], []
         _group_mono = {
@@ -2045,11 +2052,13 @@ def _prove_impl(
                 q_cols.append(T_mono[comp][i * n : (i + 1) * n])
         q_mono = shard_cols(jnp.stack(q_cols))  # (2Q, n) already monomial
         q_lde = lde_from_monomial(q_mono, L)
-        q_tree, _ = _commit_columns(q_lde, cap)
-    t.witness_merkle_tree_cap(q_tree.get_cap())
-    _checkpoint(3, "quotient_cap", q_tree.get_cap())
-    z_chal = t.get_ext_challenge()
-    _checkpoint(3, "z", z_chal)
+        with _transfer.pull_site("quotient_cap"):
+            q_tree, _ = _commit_columns(q_lde, cap)
+    with _span("host.transcript"):
+        t.witness_merkle_tree_cap(q_tree.get_cap())
+        _checkpoint(3, "quotient_cap", q_tree.get_cap())
+        z_chal = t.get_ext_challenge()
+        _checkpoint(3, "z", z_chal)
 
     # ---- round 4: evaluations at z (and z*omega, 0) ----------------------
     clock.start("round4_evaluations")
@@ -2084,8 +2093,9 @@ def _prove_impl(
     if res:
         # evaluations compute on planes; the pull fetches u32 planes and
         # u64 reassembles ON HOST (the transcript absorb edge)
-        z_tb = jnp.asarray(RES.ext_sc_np(z_chal))
-        zw_tb = jnp.asarray(RES.ext_sc_np(zw))
+        with _transfer.upload("z_points", 32, 2):
+            z_tb = jnp.asarray(RES.ext_sc_np(z_chal))
+            zw_tb = jnp.asarray(RES.ext_sc_np(zw))
         ev0p, ev1p, evw0p, evw1p = RES._evals_p(
             all_mono, s2_mono, z_tb, zw_tb
         )
@@ -2113,8 +2123,11 @@ def _prove_impl(
         evw1 = _join_np(got[6], got[7])
         s2_mono_host = _join_np(got[8], got[9]) if lookups else None
     elif fused:
-        z01 = jnp.asarray(np.array([z_chal[0], z_chal[1]], dtype=np.uint64))
-        zw01 = jnp.asarray(np.array([zw[0], zw[1]], dtype=np.uint64))
+        with _transfer.upload("z_points", 32, 2):
+            z01 = jnp.asarray(
+                np.array([z_chal[0], z_chal[1]], dtype=np.uint64)
+            )
+            zw01 = jnp.asarray(np.array([zw[0], zw[1]], dtype=np.uint64))
         ev0, ev1, evw0, evw1 = _evals_fused(all_mono, s2_mono, z01, zw01)
         # ONE batched, prefetched d2h for the whole evaluation round
         # (the sequenced path pays four-plus separate blocking pulls);
@@ -2146,35 +2159,38 @@ def _prove_impl(
         s2_mono_host = None
     from ..parallel.sharding import host_np
 
-    values_at_z = [
-        (int(a), int(b)) for a, b in zip(host_np(ev0), host_np(ev1))
-    ]
-    values_at_z_omega = [
-        (int(a), int(b)) for a, b in zip(host_np(evw0), host_np(evw1))
-    ]
+    with _transfer.pull_site("round4_evals"):
+        values_at_z = [
+            (int(a), int(b)) for a, b in zip(host_np(ev0), host_np(ev1))
+        ]
+        values_at_z_omega = [
+            (int(a), int(b)) for a, b in zip(host_np(evw0), host_np(evw1))
+        ]
     # lookup sum openings at 0: ext value of each A_i/B pair is the pair of
     # constant monomial coefficients
     values_at_0 = []
     if lookups:
         if s2_mono_host is None:
-            s2_mono_host = host_np(s2_mono[:, 0])
+            with _transfer.pull_site("round4_evals"):
+                s2_mono_host = host_np(s2_mono[:, 0])
         ab_off = 2 + 2 * num_partials
         for i in range(R_args + 1):
             values_at_0.append(
                 (int(s2_mono_host[ab_off + 2 * i]),
                  int(s2_mono_host[ab_off + 2 * i + 1]))
             )
-    for v in values_at_z:
-        t.witness_field_elements(v)
-    for v in values_at_z_omega:
-        t.witness_field_elements(v)
-    for v in values_at_0:
-        t.witness_field_elements(v)
-    _checkpoint(
-        4, "evaluations", [values_at_z, values_at_z_omega, values_at_0]
-    )
-    deep_ch = t.get_ext_challenge()
-    _checkpoint(4, "deep_challenge", deep_ch)
+    with _span("host.transcript"):
+        for v in values_at_z:
+            t.witness_field_elements(v)
+        for v in values_at_z_omega:
+            t.witness_field_elements(v)
+        for v in values_at_0:
+            t.witness_field_elements(v)
+        _checkpoint(
+            4, "evaluations", [values_at_z, values_at_z_omega, values_at_0]
+        )
+        deep_ch = t.get_ext_challenge()
+        _checkpoint(4, "deep_challenge", deep_ch)
 
     # ---- round 5: DEEP + FRI ---------------------------------------------
     clock.start("round5_deep_fri")
@@ -2225,35 +2241,44 @@ def _prove_impl(
         )
         dp0 = np.array([p[0] for p in dp], dtype=np.uint64)
         dp1 = np.array([p[1] for p in dp], dtype=np.uint64)
-        c0s = RES.host_planes(dp0[:B])
-        c1s = RES.host_planes(dp1[:B])
-        y0s = RES.host_planes(
-            np.array([v[0] for v in values_at_z], dtype=np.uint64)
-        )
-        y1s = RES.host_planes(
-            np.array([v[1] for v in values_at_z], dtype=np.uint64)
-        )
-        inv_xz = deep_prep["inv_xz"]
-        inv_xzw = deep_prep["inv_xzw"]
         E = 2 + num_lk + num_pi
-        ch0e = RES.host_planes(dp0[B : B + E])
-        ch1e = RES.host_planes(dp1[B : B + E])
-        y_zw = (
-            RES.host_planes(
-                np.array([v[0] for v in values_at_z_omega], dtype=np.uint64)
+        # twelve host arrays, two u32 planes each
+        with _transfer.upload(
+            "deep_challenges",
+            8 * (
+                4 * B + 2 * E
+                + 2 * len(values_at_z_omega) + 2 * len(values_at_0)
             ),
-            RES.host_planes(
-                np.array([v[1] for v in values_at_z_omega], dtype=np.uint64)
-            ),
-        )
-        y_lk0 = (
-            RES.host_planes(
-                np.array([v[0] for v in values_at_0], dtype=np.uint64)
-            ),
-            RES.host_planes(
-                np.array([v[1] for v in values_at_0], dtype=np.uint64)
-            ),
-        )
+            24,
+        ):
+            c0s = RES.host_planes(dp0[:B])
+            c1s = RES.host_planes(dp1[:B])
+            y0s = RES.host_planes(
+                np.array([v[0] for v in values_at_z], dtype=np.uint64)
+            )
+            y1s = RES.host_planes(
+                np.array([v[1] for v in values_at_z], dtype=np.uint64)
+            )
+            inv_xz = deep_prep["inv_xz"]
+            inv_xzw = deep_prep["inv_xzw"]
+            ch0e = RES.host_planes(dp0[B : B + E])
+            ch1e = RES.host_planes(dp1[B : B + E])
+            y_zw = (
+                RES.host_planes(
+                    np.array([v[0] for v in values_at_z_omega], dtype=np.uint64)
+                ),
+                RES.host_planes(
+                    np.array([v[1] for v in values_at_z_omega], dtype=np.uint64)
+                ),
+            )
+            y_lk0 = (
+                RES.host_planes(
+                    np.array([v[0] for v in values_at_0], dtype=np.uint64)
+                ),
+                RES.host_planes(
+                    np.array([v[1] for v in values_at_0], dtype=np.uint64)
+                ),
+            )
         _streamed_deep = any(
             isinstance(s, MonomialPlanesSource) for s in deep_sources
         )
@@ -2288,12 +2313,13 @@ def _prove_impl(
     elif fused:
         deep_pows = AlphaPows(deep_ch, num_deep_terms)
         c0s, c1s = deep_pows.take(B)
-        y0s = jnp.asarray(
-            np.array([v[0] for v in values_at_z], dtype=np.uint64)
-        )
-        y1s = jnp.asarray(
-            np.array([v[1] for v in values_at_z], dtype=np.uint64)
-        )
+        with _transfer.upload("deep_challenges", 16 * B, 2):
+            y0s = jnp.asarray(
+                np.array([v[0] for v in values_at_z], dtype=np.uint64)
+            )
+            y1s = jnp.asarray(
+                np.array([v[1] for v in values_at_z], dtype=np.uint64)
+            )
         # the challenge-independent prep — 1/(x-z), 1/(x-z*omega) (one
         # build + ONE batched inversion), single-column regens for the
         # remaining terms, public-input denominators — was dispatched
@@ -2301,14 +2327,19 @@ def _prove_impl(
         inv_xz = deep_prep["inv_xz"]
         inv_xzw = deep_prep["inv_xzw"]
         ch0e, ch1e = deep_pows.take(2 + num_lk + num_pi)
-        y_zw = (
-            jnp.asarray(np.array([v[0] for v in values_at_z_omega], dtype=np.uint64)),
-            jnp.asarray(np.array([v[1] for v in values_at_z_omega], dtype=np.uint64)),
-        )
-        y_lk0 = (
-            jnp.asarray(np.array([v[0] for v in values_at_0], dtype=np.uint64)),
-            jnp.asarray(np.array([v[1] for v in values_at_0], dtype=np.uint64)),
-        )
+        with _transfer.upload(
+            "deep_challenges",
+            16 * (len(values_at_z_omega) + len(values_at_0)),
+            4,
+        ):
+            y_zw = (
+                jnp.asarray(np.array([v[0] for v in values_at_z_omega], dtype=np.uint64)),
+                jnp.asarray(np.array([v[1] for v in values_at_z_omega], dtype=np.uint64)),
+            )
+            y_lk0 = (
+                jnp.asarray(np.array([v[0] for v in values_at_0], dtype=np.uint64)),
+                jnp.asarray(np.array([v[1] for v in values_at_0], dtype=np.uint64)),
+            )
         _streamed_deep = any(
             isinstance(s, MonomialSource) for s in deep_sources
         )
@@ -2357,12 +2388,13 @@ def _prove_impl(
     else:
         deep_pows = AlphaPows(deep_ch, num_deep_terms)
         c0s, c1s = deep_pows.take(B)
-        y0s = jnp.asarray(
-            np.array([v[0] for v in values_at_z], dtype=np.uint64)
-        )
-        y1s = jnp.asarray(
-            np.array([v[1] for v in values_at_z], dtype=np.uint64)
-        )
+        with _transfer.upload("deep_challenges", 16 * B, 2):
+            y0s = jnp.asarray(
+                np.array([v[0] for v in values_at_z], dtype=np.uint64)
+            )
+            y1s = jnp.asarray(
+                np.array([v[1] for v in values_at_z], dtype=np.uint64)
+            )
         # 1/(x - z), 1/(x - z*omega) over the domain (ext)
         x_minus_z = (gf.sub(xs_lde, jnp.uint64(z_chal[0])),
                      jnp.broadcast_to(jnp.uint64(gl.neg(z_chal[1])), xs_lde.shape))
@@ -2416,105 +2448,122 @@ def _prove_impl(
 
     # ---- queries ----------------------------------------------------------
     clock.start("queries")
-    bs = BitSource(log_full)
-    # draw ALL query indices first (same transcript sequence the verifier
-    # replays), then extract every oracle batched: one device gather per
-    # storage / per tree level instead of per-query element reads (each
-    # read is a blocking device-to-host transfer)
-    idxs = [bs.get_index(t, log_full) for _ in range(config.num_queries)]
-    _checkpoint(5, "query_indices", idxs)
-    idx_dev = jnp.asarray(np.array(idxs, dtype=np.int64))
+    # the query phase in its three parts: `queries.plan` (the index draw to
+    # the last deferred gather, the path indices' uploads inside it),
+    # `query_gather` (the one dispatch and its pull) and `queries.assemble`
+    with _span("queries.plan"):
+        # draw ALL query indices first (same transcript sequence the verifier
+        # replays), then extract every oracle batched: one device gather per
+        # storage / per tree level instead of per-query element reads (each
+        # read is a blocking device-to-host transfer)
+        with _span("host.transcript"):
+            bs = BitSource(log_full)
+            idxs = [bs.get_index(t, log_full) for _ in range(config.num_queries)]
+            _checkpoint(5, "query_indices", idxs)
+        with _transfer.upload("query_indices", 8 * len(idxs)):
+            idx_dev = jnp.asarray(np.array(idxs, dtype=np.int64))
 
-    # PLAN every query gather (leaf rows + all tree path levels, all
-    # oracles), execute them in ONE fused dispatch, and pay ONE host
-    # transfer — hundreds of small blocking transfers otherwise dominate
-    # the whole query phase.
-    plans: list = []  # (array, index array, axis tag)
-    plan_shapes: list = []  # result shape per plan (single source of truth)
-    _dummy_idx = jnp.zeros((0,), jnp.int64)
+        # PLAN every query gather (leaf rows + all tree path levels, all
+        # oracles), execute them in ONE fused dispatch, and pay ONE host
+        # transfer — hundreds of small blocking transfers otherwise dominate
+        # the whole query phase.
+        plans: list = []  # (array, index array, axis tag)
+        plan_shapes: list = []  # result shape per plan (single source of truth)
+        _dummy_idx = jnp.zeros((0,), jnp.int64)
 
-    def _defer(arr, ix, axis):
-        if axis == 2:
-            shape = tuple(arr.shape)
-            ix = _dummy_idx
-        elif axis == 1:
-            shape = (int(arr.shape[0]), int(ix.shape[0]))
-        else:
-            shape = (int(ix.shape[0]),) + tuple(arr.shape[1:])
-        plans.append((arr, ix, axis))
-        plan_shapes.append(shape)
-        return len(plans) - 1, shape
+        def _defer(arr, ix, axis):
+            if axis == 2:
+                shape = tuple(arr.shape)
+                ix = _dummy_idx
+            elif axis == 1:
+                shape = (int(arr.shape[0]), int(ix.shape[0]))
+            else:
+                shape = (int(ix.shape[0]),) + tuple(arr.shape[1:])
+            plans.append((arr, ix, axis))
+            plan_shapes.append(shape)
+            return len(plans) - 1, shape
 
-    def _defer_vals(leaves_cols):
-        """Leaf-value gather handle: ("one", h) for u64 storages, or
-        ("pair", h_lo, h_hi) for resident plane pairs — the pair joins on
-        HOST in _take_vals (the query-opening edge of the residency
-        contract; no device u64 ever exists)."""
-        if isinstance(leaves_cols, MonomialSource):
-            vals = _stream_gather_fused(
-                leaves_cols.mono, idx_dev, leaves_cols.L
-            )
-            return ("one", _defer(vals, None, 2))
-        if isinstance(leaves_cols, MonomialPlanesSource):
-            vlo, vhi = RES._stream_gather_p(
-                leaves_cols.mono, idx_dev, leaves_cols.L
-            )
-            return ("pair", _defer(vlo, None, 2), _defer(vhi, None, 2))
-        if isinstance(leaves_cols, tuple):
-            return (
-                "pair",
-                _defer(leaves_cols[0], idx_dev, 1),
-                _defer(leaves_cols[1], idx_dev, 1),
-            )
-        return ("one", _defer(leaves_cols, idx_dev, 1))
+        def _defer_vals(leaves_cols):
+            """Leaf-value gather handle: ("one", h) for u64 storages, or
+            ("pair", h_lo, h_hi) for resident plane pairs — the pair joins on
+            HOST in _take_vals (the query-opening edge of the residency
+            contract; no device u64 ever exists)."""
+            if isinstance(leaves_cols, MonomialSource):
+                vals = _stream_gather_fused(
+                    leaves_cols.mono, idx_dev, leaves_cols.L
+                )
+                return ("one", _defer(vals, None, 2))
+            if isinstance(leaves_cols, MonomialPlanesSource):
+                vlo, vhi = RES._stream_gather_p(
+                    leaves_cols.mono, idx_dev, leaves_cols.L
+                )
+                return ("pair", _defer(vlo, None, 2), _defer(vhi, None, 2))
+            if isinstance(leaves_cols, tuple):
+                return (
+                    "pair",
+                    _defer(leaves_cols[0], idx_dev, 1),
+                    _defer(leaves_cols[1], idx_dev, 1),
+                )
+            return ("one", _defer(leaves_cols, idx_dev, 1))
 
-    def _defer_oracle(leaves_cols, tree):
-        vals_h = _defer_vals(leaves_cols)
-        gplans, assemble = tree.proof_gather_plans(idxs)
-        level_hs = [
-            _defer(layer, jnp.asarray(ix), 0) for layer, ix in gplans
-        ]
-        return vals_h, level_hs, assemble
+        def _defer_oracle(leaves_cols, tree):
+            vals_h = _defer_vals(leaves_cols)
+            gplans, assemble = tree.proof_gather_plans(idxs)
+            with _transfer.upload(
+                "query_path_indices",
+                sum(ix.nbytes for _layer, ix in gplans), len(gplans),
+            ):
+                level_hs = [
+                    _defer(layer, jnp.asarray(ix), 0) for layer, ix in gplans
+                ]
+            return vals_h, level_hs, assemble
 
-    if res:
-        _q_flat = (q_lde[0].reshape(2 * Q, N), q_lde[1].reshape(2 * Q, N))
-        _setup_tree = RES.setup_tree_planes(setup)
-    else:
-        _q_flat = q_lde.reshape(2 * Q, N)
-        _setup_tree = setup.setup_tree
-    oracle_handles = [
-        _defer_oracle(wit_lde_all, wit_tree),
-        _defer_oracle(s2_lde_flat, s2_tree),
-        _defer_oracle(_q_flat, q_tree),
-        _defer_oracle(setup_lde_flat, _setup_tree),
-    ]
-    fri_handles = []
-    fidxs = np.array(idxs, dtype=np.int64)
-    for r, tree in enumerate(fri.trees):
-        k = fri.schedule[r]
-        block = 1 << k
-        leaf_idx = fidxs >> k
-        v0, v1 = fri.values[r]
-        rows = (
-            leaf_idx[:, None] * block + np.arange(block)[None, :]
-        ).reshape(-1)
-        rows_dev = jnp.asarray(rows)
         if res:
-            g0_h = ("pair", _defer(v0[0], rows_dev, 0),
-                    _defer(v0[1], rows_dev, 0))
-            g1_h = ("pair", _defer(v1[0], rows_dev, 0),
-                    _defer(v1[1], rows_dev, 0))
+            _q_flat = (q_lde[0].reshape(2 * Q, N), q_lde[1].reshape(2 * Q, N))
+            # a setup's first prove pulls its tree's cap here (kept after)
+            with _transfer.pull_site("setup_cap"):
+                _setup_tree = RES.setup_tree_planes(setup)
         else:
-            g0_h = ("one", _defer(v0, rows_dev, 0))
-            g1_h = ("one", _defer(v1, rows_dev, 0))
-        gplans, assemble = tree.proof_gather_plans(
-            [int(p) for p in leaf_idx]
-        )
-        level_hs = [
-            _defer(layer, jnp.asarray(ix), 0) for layer, ix in gplans
+            _q_flat = q_lde.reshape(2 * Q, N)
+            _setup_tree = setup.setup_tree
+        oracle_handles = [
+            _defer_oracle(wit_lde_all, wit_tree),
+            _defer_oracle(s2_lde_flat, s2_tree),
+            _defer_oracle(_q_flat, q_tree),
+            _defer_oracle(setup_lde_flat, _setup_tree),
         ]
-        fri_handles.append((g0_h, g1_h, level_hs, assemble, block))
-        fidxs = leaf_idx
+        fri_handles = []
+        fidxs = np.array(idxs, dtype=np.int64)
+        for r, tree in enumerate(fri.trees):
+            k = fri.schedule[r]
+            block = 1 << k
+            leaf_idx = fidxs >> k
+            v0, v1 = fri.values[r]
+            rows = (
+                leaf_idx[:, None] * block + np.arange(block)[None, :]
+            ).reshape(-1)
+            with _transfer.upload("fri_rows", rows.nbytes):
+                rows_dev = jnp.asarray(rows)
+            if res:
+                g0_h = ("pair", _defer(v0[0], rows_dev, 0),
+                        _defer(v0[1], rows_dev, 0))
+                g1_h = ("pair", _defer(v1[0], rows_dev, 0),
+                        _defer(v1[1], rows_dev, 0))
+            else:
+                g0_h = ("one", _defer(v0, rows_dev, 0))
+                g1_h = ("one", _defer(v1, rows_dev, 0))
+            gplans, assemble = tree.proof_gather_plans(
+                [int(p) for p in leaf_idx]
+            )
+            with _transfer.upload(
+                "query_path_indices",
+                sum(ix.nbytes for _layer, ix in gplans), len(gplans),
+            ):
+                level_hs = [
+                    _defer(layer, jnp.asarray(ix), 0) for layer, ix in gplans
+                ]
+            fri_handles.append((g0_h, g1_h, level_hs, assemble, block))
+            fidxs = leaf_idx
 
     # ONE fused gather dispatch + ONE host transfer
     arrs_, idxs_, axes_ = zip(*plans)
@@ -2555,65 +2604,66 @@ def _prove_impl(
 
         arrs_ = tuple(_demesh(a) for a in arrs_)
     _metrics.count("query.gather_plans", len(plans))
-    with _span("query_gather"):
+    with _span("query_gather"), _transfer.pull_site("query_gather"):
         flat = host_np(
             _gather_flat_fused(tuple(arrs_), tuple(idxs_), tuple(axes_))
         )
-    _plan_offsets = np.concatenate(
-        [[0], np.cumsum([int(np.prod(s)) for s in plan_shapes])]
-    )
+    with _span("queries.assemble"):
+        _plan_offsets = np.concatenate(
+            [[0], np.cumsum([int(np.prod(s)) for s in plan_shapes])]
+        )
 
-    def _take(handle):
-        i, shape = handle
-        return flat[_plan_offsets[i] : _plan_offsets[i + 1]].reshape(shape)
+        def _take(handle):
+            i, shape = handle
+            return flat[_plan_offsets[i] : _plan_offsets[i + 1]].reshape(shape)
 
-    def _take_vals(handle):
-        if handle[0] == "one":
-            return _take(handle[1])
-        from ..field.limbs import join_np as _join_np
+        def _take_vals(handle):
+            if handle[0] == "one":
+                return _take(handle[1])
+            from ..field.limbs import join_np as _join_np
 
-        return _join_np(_take(handle[1]), _take(handle[2]))
+            return _join_np(_take(handle[1]), _take(handle[2]))
 
-    def _oracle_queries(handle):
-        vals_h, level_hs, assemble = handle
-        vals = _take_vals(vals_h)
-        paths = assemble([_take(h) for h in level_hs])
-        return [
-            OracleQuery(
-                leaf_values=[int(x) for x in vals[:, q]], path=paths[q]
+        def _oracle_queries(handle):
+            vals_h, level_hs, assemble = handle
+            vals = _take_vals(vals_h)
+            paths = assemble([_take(h) for h in level_hs])
+            return [
+                OracleQuery(
+                    leaf_values=[int(x) for x in vals[:, q]], path=paths[q]
+                )
+                for q in range(len(idxs))
+            ]
+
+        wit_qs, s2_qs, q_qs, setup_qs = map(_oracle_queries, oracle_handles)
+        fri_qs_per_round = []
+        num_q = len(idxs)
+        for g0_h, g1_h, level_hs, assemble, block in fri_handles:
+            gathered = np.stack([_take_vals(g0_h), _take_vals(g1_h)])
+            paths = assemble([_take(h) for h in level_hs])
+            fri_qs_per_round.append(
+                [
+                    OracleQuery(
+                        leaf_values=[
+                            int(gathered[c, q * block + j])
+                            for j in range(block)
+                            for c in (0, 1)
+                        ],
+                        path=paths[q],
+                    )
+                    for q in range(num_q)
+                ]
+            )
+        queries = [
+            SingleRoundQueries(
+                witness=wit_qs[q],
+                stage2=s2_qs[q],
+                quotient=q_qs[q],
+                setup=setup_qs[q],
+                fri=[fri_qs_per_round[r][q] for r in range(len(fri.trees))],
             )
             for q in range(len(idxs))
         ]
-
-    wit_qs, s2_qs, q_qs, setup_qs = map(_oracle_queries, oracle_handles)
-    fri_qs_per_round = []
-    num_q = len(idxs)
-    for g0_h, g1_h, level_hs, assemble, block in fri_handles:
-        gathered = np.stack([_take_vals(g0_h), _take_vals(g1_h)])
-        paths = assemble([_take(h) for h in level_hs])
-        fri_qs_per_round.append(
-            [
-                OracleQuery(
-                    leaf_values=[
-                        int(gathered[c, q * block + j])
-                        for j in range(block)
-                        for c in (0, 1)
-                    ],
-                    path=paths[q],
-                )
-                for q in range(num_q)
-            ]
-        )
-    queries = [
-        SingleRoundQueries(
-            witness=wit_qs[q],
-            stage2=s2_qs[q],
-            quotient=q_qs[q],
-            setup=setup_qs[q],
-            fri=[fri_qs_per_round[r][q] for r in range(len(fri.trees))],
-        )
-        for q in range(len(idxs))
-    ]
 
     return Proof(
         public_inputs=pi_values,

@@ -52,6 +52,7 @@ from ..field import limbs
 from ..ntt import limb_ntt as LN
 from ..ntt.ntt import _powers_np, bitreverse_indices
 from ..utils import metrics as _metrics
+from ..utils import transfer as _transfer
 from ..utils.spans import span as _span
 
 _MASK = 0xFFFFFFFF
@@ -444,7 +445,7 @@ def stage2_stack_fn_p(assembly, selector_paths):
         mk_path = None
 
     @jax.jit
-    def fn(z, partials_stacked, lk_inv, multiplicities, consts_dev):
+    def _stage2_stack_p(z, partials_stacked, lk_inv, multiplicities, consts_dev):
         lo_rows = [z[0][0], z[1][0]]
         hi_rows = [z[0][1], z[1][1]]
         for j in range(num_chunks - 1):
@@ -475,8 +476,8 @@ def stage2_stack_fn_p(assembly, selector_paths):
             hi_rows += [t0[1], t1[1]]
         return jnp.stack(lo_rows), jnp.stack(hi_rows)
 
-    assembly._stage2_stack_p_jit = fn
-    return fn
+    assembly._stage2_stack_p_jit = _stage2_stack_p
+    return _stage2_stack_p
 
 
 # ---------------------------------------------------------------------------
@@ -779,8 +780,8 @@ def _deep_extras_fn_p(num_zw: int, num_lk: int, num_pi: int):
     """Plane twin of prover._deep_extras_fn."""
 
     @jax.jit
-    def fn(h, cols_zw, cols_lk, cols_pi, inv_xzw, inv_x, pi_denoms,
-           y_zw, y_lk0, pi_vals, ch0, ch1):
+    def _deep_extras_p(h, cols_zw, cols_lk, cols_pi, inv_xzw, inv_x,
+                       pi_denoms, y_zw, y_lk0, pi_vals, ch0, ch1):
         shape = h[0][0].shape
         t = 0
         for i in range(num_zw):
@@ -832,7 +833,7 @@ def _deep_extras_fn_p(num_zw: int, num_lk: int, num_pi: int):
             t += 1
         return h
 
-    return fn
+    return _deep_extras_p
 
 
 @partial(jax.jit, static_argnums=(1, 2))
@@ -871,7 +872,8 @@ def deep_round5_prep_p(
     if isinstance(s2_lde_flat_p, MonomialPlanesSource):
         s2_cols = _cols_from_mono_p(s2_mono_p, tuple(s2_idxs), L)
     else:
-        sel = jnp.asarray(np.array(s2_idxs))
+        with _transfer.upload("deep_prep", 8 * len(s2_idxs)):
+            sel = jnp.asarray(np.array(s2_idxs))
         s2_cols = (s2_lde_flat_p[0][sel], s2_lde_flat_p[1][sel])
     if lookups:
         inv_x = inv_xs_brev_p(log_n, L)
@@ -883,23 +885,29 @@ def deep_round5_prep_p(
         if isinstance(wit_lde_all_p, MonomialPlanesSource):
             cols_pi = _cols_from_mono_p(wit_mono_p, tuple(pi_cols_idx), L)
         else:
-            sel = jnp.asarray(np.array(pi_cols_idx))
+            with _transfer.upload("deep_prep", 8 * num_pi):
+                sel = jnp.asarray(np.array(pi_cols_idx))
             cols_pi = (wit_lde_all_p[0][sel], wit_lde_all_p[1][sel])
-        pi_points = host_planes(
-            np.array(
-                [gl.pow_(omega, r) for (_c, r, _v) in assembly.public_inputs],
-                dtype=np.uint64,
+        with _transfer.upload("deep_prep", 8 * num_pi, 2):
+            pi_points = host_planes(
+                np.array(
+                    [
+                        gl.pow_(omega, r)
+                        for (_c, r, _v) in assembly.public_inputs
+                    ],
+                    dtype=np.uint64,
+                )
             )
-        )
         pi_denoms = lop.counted(
             lop.batch_inverse_jit, _pi_denom_sub_jit(xs_lde_p, pi_points)
         )
-        pi_vals = host_planes(
-            np.array(
-                [v for (_c, _r, v) in assembly.public_inputs],
-                dtype=np.uint64,
+        with _transfer.upload("deep_prep", 8 * num_pi, 2):
+            pi_vals = host_planes(
+                np.array(
+                    [v for (_c, _r, v) in assembly.public_inputs],
+                    dtype=np.uint64,
+                )
             )
-        )
     else:
         e = jnp.zeros((0, N), jnp.uint32)
         cols_pi = (e, e)

@@ -21,6 +21,7 @@ from .field import gl
 from .field.spec import BABYBEAR as _BB_SPEC
 from .field.spec import GOLDILOCKS as _GL_SPEC
 from .hashes.poseidon2 import poseidon2_permutation_host
+from .utils import metrics as _metrics
 
 
 class Poseidon2Transcript:
@@ -36,6 +37,11 @@ class Poseidon2Transcript:
         self.buffer = []
         self.available = []
 
+    def _permute(self):
+        # the work behind the prover's `host.transcript` spans, counted
+        _metrics.count("transcript.permutations")
+        self.state = self._PERMUTATION(self.state)
+
     def witness_field_elements(self, els):
         p = self._SPEC.p
         self.buffer.extend(int(e) % p for e in els)
@@ -49,7 +55,7 @@ class Poseidon2Transcript:
         if not self.buffer:
             if self.available:
                 return self.available.pop(0)
-            self.state = self._PERMUTATION(self.state)
+            self._permute()
             self.available = list(self.state[:rate])
             return self.available.pop(0)
         # rescue-prime padding: trailing 1, then zeros to a multiple of rate
@@ -59,7 +65,7 @@ class Poseidon2Transcript:
             to_absorb.append(0)
         for i in range(0, len(to_absorb), rate):
             self.state[:rate] = to_absorb[i : i + rate]
-            self.state = self._PERMUTATION(self.state)
+            self._permute()
         self.available = list(self.state[:rate])
         return self.available.pop(0)
 

@@ -15,8 +15,18 @@ Memory sources, best-effort by design:
   backend exposes it (TPU does; XLA:CPU usually returns None) — guarded,
   absent keys are simply omitted from the report.
 - `jax.live_arrays()` census (count + total bytes) — works on every
-  backend and is what the old BOOJUM_TPU_MEMLOG printed; here it lands in
-  per-stage `boundaries` entries so HBM growth is attributable to a stage.
+  backend; it lands in per-stage `boundaries` entries so HBM growth is
+  attributable to a stage.
+- `mem.in_use_mib.<stage>` (ISSUE 37): the allocator's `bytes_in_use` read
+  at the open of every `host.sync` and `host.upload` span
+  (utils/transfer.py), folded as a MAXIMUM into one counter a stage.
+  Allocation happens at dispatch, so at a sync's open everything the host
+  has queued is counted. A stage can reach more between two samples than
+  at either (round 3 queues a coset's evaluations and its sweep between
+  two uploads), so where the allocator's own `peak_bytes_in_use` rose
+  while a stage was open, the stage's close folds that peak in too: the
+  stage whose counter is highest is the one that set the process's peak.
+  Under a registry only.
 """
 
 from __future__ import annotations
@@ -24,6 +34,8 @@ from __future__ import annotations
 import contextvars
 import threading
 import time
+
+IN_USE_PREFIX = "mem.in_use_mib."
 
 
 class MetricsRegistry:
@@ -33,10 +45,21 @@ class MetricsRegistry:
         self.counters: dict[str, int] = {}
         self.gauges: dict[str, float] = {}
         self.boundaries: list[dict] = []
+        # the stage span open now (set by `boundary`, cleared by
+        # `stage_closed`): what `sample_in_use` files its reading under,
+        # and the allocator's peak as the stage found it
+        self.stage: str | None = None
+        self.stage_peak: int | None = None
 
     def count(self, name: str, n: int = 1):
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + int(n)
+
+    def count_max(self, name: str, v: int):
+        """A counter that keeps the largest value it was handed."""
+        with self._lock:
+            if v > self.counters.get(name, 0):
+                self.counters[name] = int(v)
 
     def gauge_set(self, name: str, v: float):
         with self._lock:
@@ -56,6 +79,8 @@ class MetricsRegistry:
         the backend exposes it) device memory stats; also folds the peak
         readings into gauges so the report's summary carries high-water
         marks without walking the boundary list."""
+        self.stage = label
+        self.stage_peak = None
         entry: dict = {
             "label": label,
             "t_s": round(time.perf_counter() - self._t0, 4),
@@ -69,6 +94,7 @@ class MetricsRegistry:
             entry["device_memory"] = dm
             peak = dm.get("peak_bytes_in_use")
             if peak is not None:
+                self.stage_peak = peak
                 self.gauge_max("mem.device_peak_bytes_in_use", peak)
             in_use = dm.get("bytes_in_use")
             if in_use is not None:
@@ -87,7 +113,10 @@ class MetricsRegistry:
         snap = other.to_dict()
         with self._lock:
             for k, v in (snap.get("counters") or {}).items():
-                self.counters[k] = self.counters.get(k, 0) + int(v)
+                if k.startswith(IN_USE_PREFIX):
+                    self.counters[k] = max(self.counters.get(k, 0), int(v))
+                else:
+                    self.counters[k] = self.counters.get(k, 0) + int(v)
             self.gauges.update(snap.get("gauges") or {})
 
     def to_dict(self) -> dict:
@@ -172,27 +201,31 @@ def count_upload(x):
     prover's explicit upload seams — prover._dev_cached, the sequenced
     stage-2 table uploads); passes `x` through. A (lo, hi) limb plane
     pair (the resident prove's upload unit) counts both planes."""
-    reg = current_registry()
-    if reg is not None:
-        try:
-            if isinstance(x, tuple):
-                count_bytes_h2d(
-                    sum(int(a.size) * a.dtype.itemsize for a in x)
-                )
-            else:
-                count_bytes_h2d(int(x.size) * x.dtype.itemsize)
-        except Exception:
-            pass
+    if current_registry() is not None:
+        count_bytes_h2d(upload_nbytes(x))
     return x
 
 
-def count_bytes_h2d(nbytes: int):
+def upload_nbytes(x) -> int:
+    """Bytes of an uploaded device array or (lo, hi) plane pair, from its
+    shape and dtype (0 for what has neither)."""
+    try:
+        if isinstance(x, tuple):
+            return sum(int(a.size) * a.dtype.itemsize for a in x)
+        return int(x.size) * x.dtype.itemsize
+    except Exception:
+        return 0
+
+
+def count_bytes_h2d(nbytes: int, ops: int = 1):
     """Host->device upload accounting (counted at the prover's explicit
-    upload seams; transfers inside compiled graphs are invisible here)."""
+    upload seams; transfers inside compiled graphs are invisible here):
+    `ops` uploads of `nbytes` together. Numbers only: the site says what
+    it hands over (utils/transfer.upload), no array passes through."""
     reg = current_registry()
     if reg is not None:
         reg.count("transfer.h2d_bytes", nbytes)
-        reg.count("transfer.h2d_ops")
+        reg.count("transfer.h2d_ops", ops)
 
 
 def count_bytes_d2h(nbytes: int):
@@ -313,6 +346,38 @@ def stage_boundary(label: str):
     reg = current_registry()
     if reg is not None:
         reg.boundary(label)
+
+
+def _mib_up(nbytes: int) -> int:
+    return -(-int(nbytes) // (1 << 20))
+
+
+def stage_closed():
+    """The open stage ends. Where the allocator's peak rose while it was
+    open, the stage reached that much, whatever its samples saw: folded
+    into its `mem.in_use_mib.<stage>`."""
+    reg = current_registry()
+    if reg is None or reg.stage is None:
+        return
+    stage, reg.stage = reg.stage, None
+    if reg.stage_peak is None:
+        return
+    peak = (device_memory_stats() or {}).get("peak_bytes_in_use")
+    if peak is not None and peak > reg.stage_peak:
+        reg.count_max(IN_USE_PREFIX + stage, _mib_up(peak))
+
+
+def sample_in_use():
+    """Fold the allocator's `bytes_in_use` now (MiB, rounded up) into the
+    open stage's `mem.in_use_mib.<stage>` as a maximum. Without a
+    registry, outside a stage, or where the backend reports no memory
+    (XLA:CPU): nothing."""
+    reg = current_registry()
+    if reg is None or reg.stage is None:
+        return
+    room = device_memory_room()
+    if room is not None:
+        reg.count_max(IN_USE_PREFIX + reg.stage, _mib_up(room[1]))
 
 
 # -- memory probes -----------------------------------------------------------

@@ -316,6 +316,20 @@ def span_attr(name: str, value):
         sp.setdefault("attrs", {})[name] = value
 
 
+def recording() -> bool:
+    """Whether a span opened now would land anywhere: a recorder, the
+    profiler's annotations (BOOJUM_TPU_JAX_TRACE), the stage log or the
+    stage sink. `span()`'s own test, for call sites that open several
+    spans at once and want ONE check when nothing listens
+    (utils/transfer.py's upload and sync sites)."""
+    return (
+        current_recorder() is not None
+        or os.environ.get("BOOJUM_TPU_JAX_TRACE") is not None
+        or _prof.profiling_enabled()
+        or _prof._STAGE_SINK is not None
+    )
+
+
 @contextlib.contextmanager
 def span(name: str, stage: bool = False, **attrs):
     """Record one span. Yields the span dict (or None when not recording).
@@ -330,16 +344,11 @@ def span(name: str, stage: bool = False, **attrs):
     # any span open is Python-level forward motion: reset the blackbox
     # stall clock even on the cheap not-recording path
     _bb.tick()
-    rec = current_recorder()
-    trace_dir = os.environ.get("BOOJUM_TPU_JAX_TRACE")
-    if (
-        rec is None
-        and trace_dir is None
-        and not _prof.profiling_enabled()
-        and _prof._STAGE_SINK is None
-    ):
+    if not recording():
         yield None
         return
+    rec = current_recorder()
+    trace_dir = os.environ.get("BOOJUM_TPU_JAX_TRACE")
     ctx = contextlib.nullcontext()
     if trace_dir:
         import jax

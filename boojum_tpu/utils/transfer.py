@@ -23,10 +23,20 @@ never what is absorbed into the transcript):
 Every blocking wait counts into `host.blocking_syncs` (one per `to_host`,
 one per `HostFetch` batch regardless of batch size); tests/test_overlap.py
 pins the count of a 2^10 prove.
+
+What the host was doing (ISSUE 37): every blocking wait is a span
+`host.sync` with one child `d2h.<site>` (`sync`, opened inside `to_host`,
+`HostFetch.wait` and at the prover's barriers), every upload site a span
+`host.upload` with one child `h2d.<site>` (`upload`, which also feeds
+`transfer.h2d_ops` / `transfer.h2d_bytes`). Both wrap calls that stay as
+they are, keep numbers and never an array, and cost one check when
+nothing records.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
 import time
 
@@ -107,7 +117,70 @@ def _addressable_nbytes(x) -> int:
         return 0
 
 
-def to_host(x):
+# the site a blocking pull is made for, where the pull itself sits in code
+# that does not know it (a tree's constructor pulls its cap): the caller
+# names it with `pull_site`, `to_host` files its wait under `d2h.<site>`
+_PULL_SITE: contextvars.ContextVar[str | None] = contextvars.ContextVar(
+    "boojum_tpu.pull_site", default=None
+)
+
+
+@contextlib.contextmanager
+def pull_site(label: str):
+    token = _PULL_SITE.set(label)
+    try:
+        yield
+    finally:
+        _PULL_SITE.reset(token)
+
+
+@contextlib.contextmanager
+def sync(label: str | None = None):
+    """One blocking wait of the host for the device: span `host.sync` with
+    the child `d2h.<label>` (the label given, else the `pull_site` open,
+    else `unlabelled`). The span's time is the time the host waited."""
+    if not _spans.recording():
+        yield
+        return
+    with _spans.span("host.sync"):
+        _metrics.sample_in_use()
+        with _spans.span(
+            "d2h." + (label or _PULL_SITE.get() or "unlabelled")
+        ):
+            yield
+
+
+@contextlib.contextmanager
+def upload(what: str, nbytes: int, ops: int = 1):
+    """One upload site: span `host.upload` with the child `h2d.<what>`
+    around the call(s) that hand host data to the device, counted as `ops`
+    uploads of `nbytes` together (`transfer.h2d_ops`, `transfer.h2d_bytes`).
+    Yields the `h2d` span's record, or None when no recorder is installed."""
+    _metrics.count_bytes_h2d(nbytes, ops)
+    if not _spans.recording():
+        yield None
+        return
+    with _spans.span("host.upload"):
+        _metrics.sample_in_use()
+        with _spans.span(
+            "h2d." + what, bytes=int(nbytes), ops=int(ops)
+        ) as sp:
+            yield sp
+
+
+def uploaded(what: str, build):
+    """`build()` as one upload site whose size is only known from what it
+    returns (a device array or a (lo, hi) plane pair; prover._dev_cached)."""
+    with upload(what, 0, 0) as sp:
+        x = build()
+        nbytes = _metrics.upload_nbytes(x)
+        _metrics.count_bytes_h2d(nbytes)
+        if sp is not None:
+            sp["attrs"].update(bytes=nbytes, ops=1)
+    return x
+
+
+def to_host(x, label: str | None = None):
     """Blocking device->host pull; np.asarray that also works for
     MULTI-PROCESS global arrays (a sharded jax.Array spanning
     non-addressable devices cannot be fetched directly — gather it to
@@ -128,9 +201,10 @@ def to_host(x):
         try:
             from jax.experimental import multihost_utils
 
-            out = np.asarray(
-                multihost_utils.process_allgather(x, tiled=True)
-            )
+            with sync(label):
+                out = np.asarray(
+                    multihost_utils.process_allgather(x, tiled=True)
+                )
         except Exception as e:
             import jax
 
@@ -152,10 +226,12 @@ def to_host(x):
         _metrics.count_bytes_d2h(out.nbytes)
         _metrics.count("host.blocking_syncs")
         return out
-    out = np.asarray(x)
-    if was_device:
-        _metrics.count_bytes_d2h(out.nbytes)
-        _metrics.count("host.blocking_syncs")
+    if not was_device:
+        return np.asarray(x)
+    with sync(label):
+        out = np.asarray(x)
+    _metrics.count_bytes_d2h(out.nbytes)
+    _metrics.count("host.blocking_syncs")
     return out
 
 
@@ -195,17 +271,20 @@ class HostFetch:
         out = []
         nbytes = 0
         any_device = False
-        for a in self.arrays:
-            if _is_device_array(a):
-                if _needs_allgather(a):
-                    out.append(to_host(a))  # counts its own sync
-                    continue
-                any_device = True
-                h = np.asarray(a)
-                nbytes += h.nbytes
-                out.append(h)
-            else:
-                out.append(np.asarray(a))
+        waits = any(_is_device_array(a) for a in self.arrays)
+        with sync(self.label) if waits else contextlib.nullcontext():
+            for a in self.arrays:
+                if _is_device_array(a):
+                    if _needs_allgather(a):
+                        # counts its own sync
+                        out.append(to_host(a, self.label))
+                        continue
+                    any_device = True
+                    h = np.asarray(a)
+                    nbytes += h.nbytes
+                    out.append(h)
+                else:
+                    out.append(np.asarray(a))
         if any_device:
             _metrics.count_bytes_d2h(nbytes)
             _metrics.count("host.blocking_syncs")
