@@ -13,6 +13,7 @@ Usage: python bench_micro.py  (JSON lines on stdout; backend = ambient JAX)
        python bench_micro.py poseidon2  (the Poseidon2 section alone)
        python bench_micro.py binv       (the batch-inversion section alone)
        python bench_micro.py ntt        (the forward NTT above 2^16 rows alone)
+       python bench_micro.py transcript (the host permutation under the transcript)
 """
 
 import json
@@ -843,8 +844,48 @@ def mesh_section(backend):
     emit_pair("pivot_all_to_all", dt_g, dt_s, B * N)
 
 
+def transcript_section():
+    """The transcript's permutation on this host, no device (PR 38): the
+    one Python permutation, and the prover's engine (native where the
+    library loaded) a permutation of its own and inside the 68-block absorb
+    of the evaluations at z. Best of 7."""
+    from boojum_tpu import transcript as T
+    from boojum_tpu.hashes.poseidon2 import poseidon2_permutation_host
+
+    def best_ms(step, per):
+        best = float("inf")
+        for _ in range(7):
+            t0 = time.perf_counter()
+            step()
+            best = min(best, time.perf_counter() - t0)
+        return best * 1e3 / per
+
+    def python_chain():
+        s = list(range(1, 13))
+        for _ in range(200):
+            s = poseidon2_permutation_host(s)
+
+    emit("transcript_perm_python", best_ms(python_chain, 200), "ms")
+    t = T.make_prover_transcript("poseidon2")
+    engine = type(t).__name__
+
+    def squeezes():
+        for _ in range(200):
+            t._permute()
+
+    def absorb():
+        t.witness_field_elements(range(8 * 68 - 1))
+        t.get_challenge()
+
+    emit("transcript_perm_prover", best_ms(squeezes, 200), "ms", engine=engine)
+    emit("transcript_perm_prover_absorb68", best_ms(absorb, 68), "ms",
+         engine=engine)
+
+
 if __name__ == "__main__":
-    if sys.argv[1:] == ["poseidon2"]:  # that section alone, one chip call
+    if sys.argv[1:] == ["transcript"]:
+        transcript_section()
+    elif sys.argv[1:] == ["poseidon2"]:  # that section alone, one chip call
         poseidon2_section(jax.default_backend())
     elif sys.argv[1:] == ["binv"]:
         batch_inverse_section(jax.default_backend())

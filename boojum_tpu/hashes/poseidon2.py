@@ -315,50 +315,104 @@ def node_hash(left: jax.Array, right: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _sbox7_s(x):
-    x2 = gl.sqr(x)
-    x3 = gl.mul(x2, x)
-    return gl.mul(gl.sqr(x2), x3)
+# The arithmetic is written out: plain `+` and `*` on Python ints, which
+# do not overflow, and one `% P` where a value is next multiplied or a row
+# of a linear layer ends; a function call a field operation (about 2,600 a
+# permutation) costs more than the arithmetic itself. This is the ONE
+# Python implementation: the reference for the device kernels, the gate and
+# native/resolver.cpp's permutation, and what runs where no library loads.
 
-
-def _block_m4_s(x0, x1, x2, x3):
-    t0 = gl.add(x0, x1)
-    t1 = gl.add(x2, x3)
-    t2 = gl.add(gl.add(x1, x1), t1)
-    t3 = gl.add(gl.add(x3, x3), t0)
-    t4 = gl.add(gl.add(gl.add(t1, t1), gl.add(t1, t1)), t3)
-    t5 = gl.add(gl.add(gl.add(t0, t0), gl.add(t0, t0)), t2)
-    return gl.add(t3, t5), t5, gl.add(t2, t4), t4
+_P = gl.P
+_RC_ROWS = [
+    tuple(params.ALL_ROUND_CONSTANTS[12 * r : 12 * r + 12]) for r in range(30)
+]
+_DIAG_INTS = tuple(params.M_I_DIAGONAL)
 
 
 def _external_mds_s(s):
-    blocks = [_block_m4_s(*s[4 * b : 4 * b + 4]) for b in range(3)]
-    sums = [
-        gl.add(gl.add(blocks[0][i], blocks[1][i]), blocks[2][i]) for i in range(4)
+    """circ(2*M4, M4, M4) over twelve non-negative ints of any size (no
+    entry of the matrix exceeds 14); the outputs are canonical."""
+    p = _P
+    x0, x1, x2, x3, x4, x5, x6, x7, x8, x9, x10, x11 = s
+    # M4 = [[5,7,1,3],[4,6,1,1],[1,3,5,7],[1,1,4,6]] by its add/double chain
+    t0 = x0 + x1
+    t1 = x2 + x3
+    t2 = x1 + x1 + t1
+    t3 = x3 + x3 + t0
+    a3 = 4 * t1 + t3
+    a1 = 4 * t0 + t2
+    a0 = t3 + a1
+    a2 = t2 + a3
+    t0 = x4 + x5
+    t1 = x6 + x7
+    t2 = x5 + x5 + t1
+    t3 = x7 + x7 + t0
+    b3 = 4 * t1 + t3
+    b1 = 4 * t0 + t2
+    b0 = t3 + b1
+    b2 = t2 + b3
+    t0 = x8 + x9
+    t1 = x10 + x11
+    t2 = x9 + x9 + t1
+    t3 = x11 + x11 + t0
+    c3 = 4 * t1 + t3
+    c1 = 4 * t0 + t2
+    c0 = t3 + c1
+    c2 = t2 + c3
+    s0 = a0 + b0 + c0
+    s1 = a1 + b1 + c1
+    s2 = a2 + b2 + c2
+    s3 = a3 + b3 + c3
+    return [
+        (a0 + s0) % p, (a1 + s1) % p, (a2 + s2) % p, (a3 + s3) % p,
+        (b0 + s0) % p, (b1 + s1) % p, (b2 + s2) % p, (b3 + s3) % p,
+        (c0 + s0) % p, (c1 + s1) % p, (c2 + s2) % p, (c3 + s3) % p,
     ]
-    return [gl.add(blocks[b][i], sums[i]) for b in range(3) for i in range(4)]
 
 
-def _internal_mds_s(s):
-    total = 0
-    for v in s:
-        total = gl.add(total, v)
-    return [gl.add(gl.mul(s[i], params.M_I_DIAGONAL[i]), total) for i in range(12)]
+def _full_round_s(s, rc):
+    """Add the round's constants, x^7 on every lane (the products go
+    unreduced into the linear layer, which reduces its rows), external
+    matrix."""
+    p = _P
+    out = []
+    for v, c in zip(s, rc):
+        v += c
+        v3 = v * v * v % p
+        out.append(v3 * v3 * v)
+    return _external_mds_s(out)
 
 
 def poseidon2_permutation_host(state: list) -> list:
-    s = _external_mds_s(list(state))
+    """Twelve ints (reduced or not) in, twelve canonical ints out."""
+    p = _P
+    rc = _RC_ROWS
+    d0, d1, d2, d3, d4, d5, d6, d7, d8, d9, d10, d11 = _DIAG_INTS
+    s = _external_mds_s(state)
     for r in range(4):
-        s = [gl.add(v, int(_RC[r, i])) for i, v in enumerate(s)]
-        s = [_sbox7_s(v) for v in s]
-        s = _external_mds_s(s)
+        s = _full_round_s(s, rc[r])
+    s0, s1, s2, s3, s4, s5, s6, s7, s8, s9, s10, s11 = s
     for r in range(4, 26):
-        s[0] = _sbox7_s(gl.add(s[0], int(_RC[r, 0])))
-        s = _internal_mds_s(s)
+        v = s0 + rc[r][0]
+        v3 = v * v * v % p
+        s0 = v3 * v3 * v % p
+        # M_I = all-ones + diag(d): out_i = d_i * x_i + sum_j x_j
+        t = s0 + s1 + s2 + s3 + s4 + s5 + s6 + s7 + s8 + s9 + s10 + s11
+        s0 = (s0 * d0 + t) % p
+        s1 = (s1 * d1 + t) % p
+        s2 = (s2 * d2 + t) % p
+        s3 = (s3 * d3 + t) % p
+        s4 = (s4 * d4 + t) % p
+        s5 = (s5 * d5 + t) % p
+        s6 = (s6 * d6 + t) % p
+        s7 = (s7 * d7 + t) % p
+        s8 = (s8 * d8 + t) % p
+        s9 = (s9 * d9 + t) % p
+        s10 = (s10 * d10 + t) % p
+        s11 = (s11 * d11 + t) % p
+    s = [s0, s1, s2, s3, s4, s5, s6, s7, s8, s9, s10, s11]
     for r in range(26, 30):
-        s = [gl.add(v, int(_RC[r, i])) for i, v in enumerate(s)]
-        s = [_sbox7_s(v) for v in s]
-        s = _external_mds_s(s)
+        s = _full_round_s(s, rc[r])
     return s
 
 

@@ -1,9 +1,14 @@
-"""Native (C++) witness-resolution engine: build + ctypes bindings.
+"""Native (C++) host code: build + ctypes bindings. One library, one rule.
 
-Counterpart of the reference's compiled resolver runtime (the Rust
-`MtCircuitResolver` machinery, /root/reference/src/dag/). The shared library
-is built on demand with g++ and cached next to the source; if no compiler is
-available the framework silently falls back to the pure-python resolver.
+It holds the witness-resolution engine, the counterpart of the reference's
+compiled resolver runtime (the Rust `MtCircuitResolver` machinery,
+/root/reference/src/dag/), and the Poseidon2 sponge steps under the PROVER's
+transcript (`poseidon2_permute`, `poseidon2_absorb`; PR 38: the resolver's
+permutation, whose constants `get_lib` registers once from
+`hashes/poseidon2_params`). The shared library is built on demand with g++
+and cached next to the source; where no compiler is available or
+`BOOJUM_TPU_NO_NATIVE` is set `get_lib()` returns None, and the framework
+falls back to the pure-python resolver and the pure-python permutation.
 """
 
 from __future__ import annotations
@@ -73,6 +78,10 @@ def get_lib():
         i64p, i64p,
     ]
     lib.execute_tape.restype = ctypes.c_int64
+    lib.poseidon2_permute.argtypes = [u64p]
+    lib.poseidon2_permute.restype = ctypes.c_int
+    lib.poseidon2_absorb.argtypes = [u64p, u64p, ctypes.c_int64]
+    lib.poseidon2_absorb.restype = ctypes.c_int
     # one-time poseidon2 constants
     from ..hashes import poseidon2_params as p2
 

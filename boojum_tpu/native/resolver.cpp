@@ -198,6 +198,28 @@ static void poseidon2_flat(const u64 *in, u64 *out12, u64 *aux106) {
   std::memcpy(out12, s, sizeof(s));
 }
 
+// The prover's transcript (boojum_tpu/transcript.py), over a caller-owned
+// state of 12 canonical words. Both return -1 before register_poseidon2.
+extern "C" int poseidon2_permute(u64 *state) {
+  if (!g_p2_ready) return -1;
+  u64 aux[106];
+  poseidon2_flat(state, state, aux);
+  return 0;
+}
+
+// Overwrite-mode absorb of k rate-blocks of 8 canonical words: each
+// overwrites state[0..8) and is followed by one permutation, so a whole
+// buffer (the evaluations at z are about 68 blocks) is one call.
+extern "C" int poseidon2_absorb(u64 *state, const u64 *blocks, i64 k) {
+  if (!g_p2_ready) return -1;
+  u64 aux[106];
+  for (i64 b = 0; b < k; b++) {
+    std::memcpy(state, blocks + 8 * b, 8 * sizeof(u64));
+    poseidon2_flat(state, state, aux);
+  }
+  return 0;
+}
+
 // ---------------------------------------------------------------------------
 // Tape execution
 // ---------------------------------------------------------------------------

@@ -45,7 +45,7 @@ from ..ntt import (
     monomial_from_values,
     powers_device,
 )
-from ..transcript import BitSource, make_transcript
+from ..transcript import BitSource, make_prover_transcript
 from .config import ProofConfig
 from .fri import fri_prove
 from .pow import pow_grind
@@ -1352,7 +1352,7 @@ def _prove_impl(
             )
 
     with _span("host.transcript"):
-        t = make_transcript(setup.vk.transcript)
+        t = make_prover_transcript(setup.vk.transcript)
         t.witness_merkle_tree_cap(setup.vk.setup_merkle_cap)
         _checkpoint(0, "setup_cap", setup.vk.setup_merkle_cap)
         pi_values = [v for (_c, _r, v) in assembly.public_inputs]

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from boojum_tpu import native
 from boojum_tpu import transcript as T
 from boojum_tpu.prover import prove
 from boojum_tpu.utils import metrics, report, spans, transfer
@@ -228,7 +229,10 @@ def _profiled(counts, key):
 def fresh():
     """Two proves of the shared circuit, one plain and one under the flight
     recorder, each under the profiler, with the transcript's permutation
-    counted from outside and a device that reports memory: 6 MiB free at
+    counted from outside (so on the Python engine, where the permutation is
+    a function one can wrap: no native library for these two proves;
+    tests/test_native_transcript.py holds the native engine's counter to
+    this one) and a device that reports memory: 6 MiB free at
     every reading (round 3 gets a barrier), and in use 100 MiB and a byte,
     with 4 MiB more a stage."""
     asm, setup, config = small_parts()
@@ -249,6 +253,7 @@ def fresh():
         return _inner(state)
 
     mp.setattr(metrics, "device_memory_room", room)
+    mp.setattr(native, "get_lib", lambda: None)
     mp.setattr(
         T.Poseidon2Transcript, "_PERMUTATION", staticmethod(permutation)
     )
