@@ -233,6 +233,26 @@ def poseidon2_permutation_planes(state_p):
     return poseidon2_permutation_planes_xla(state_p)
 
 
+def poseidon2_permutation_planes_cm(state_p):
+    """`poseidon2_permutation_planes` on a column-major (12, N) state: the
+    batch along the lanes, which is the fused kernel's own layout, so on the
+    TPU nothing is transposed (and no (8, 128) tile is padded out from 12
+    lanes, as an (N, 12) array's are)."""
+    lo, hi = state_p
+    n = lo.shape[1]
+    if _pallas_ready(n):
+        from . import pallas_poseidon2 as pp2
+
+        R = n // pp2._LANE
+        olo, ohi = pp2._permute_planes(
+            lo.reshape(12, R, pp2._LANE), hi.reshape(12, R, pp2._LANE),
+            pp2.step_rows(1, R), False,
+        )
+        return olo.reshape(12, n), ohi.reshape(12, n)
+    out = poseidon2_permutation_planes_xla((lo.T, hi.T))
+    return out[0].T, out[1].T
+
+
 def leaf_hash_planes(values_p):
     """Plane twin of `leaf_hash`: (N, L) planes -> (N, 4) digest planes."""
     vlo = values_p[0]

@@ -259,23 +259,57 @@ _COSET_EVAL_FORWARD = _forward_programs("_coset_eval_hybrid")
 def _hybrid_inv_p(p, log_n: int):
     """2^17..2^22, inverse: per-block MXU kernels, then plane XLA outer
     radix-2 stages (mxu_ntt._ifft_hybrid twin)."""
+    return hybrid_inv_outer_p(hybrid_inv_kernels_p(p, log_n), log_n)
+
+
+def hybrid_inv_kernels_p(p, log_n: int):
+    """The first half of `_hybrid_inv_p`: the inverse matmul kernel over
+    every 2^MAX_LOG_N block."""
     from . import mxu_ntt
 
-    n = 1 << log_n
     outer = log_n - mxu_ntt.MAX_LOG_N
-    ctx = PlaneNTTContext(log_n)
     lead = p[0].shape[:-1]
     blocks = (
         p[0].reshape(lead + (1 << outer, 1 << mxu_ntt.MAX_LOG_N)),
         p[1].reshape(lead + (1 << outer, 1 << mxu_ntt.MAX_LOG_N)),
     )
     out = _mxu_fft_p(blocks, True)
-    out = (
-        out[0].reshape(lead + (n,)),
-        out[1].reshape(lead + (n,)),
+    return (
+        out[0].reshape(lead + (1 << log_n,)),
+        out[1].reshape(lead + (1 << log_n,)),
     )
-    out = dit_stages_p(out, ctx, mxu_ntt.MAX_LOG_N, log_n)
+
+
+def hybrid_inv_outer_p(p, log_n: int):
+    """The second half: the outer radix-2 DIT stages and the 1/2^outer."""
+    from . import mxu_ntt
+
+    outer = log_n - mxu_ntt.MAX_LOG_N
+    out = dit_stages_p(p, PlaneNTTContext(log_n), mxu_ntt.MAX_LOG_N, log_n)
     return limbs.mul_const(out, limbs.const_pair(gl.inv(1 << outer)))
+
+
+# Outer DIT stages that ONE program has held beside the per-block inverse
+# kernels and returned on the v5e: 5, at 2^21 (the quotient interpolation
+# of every 2^18-row cell, 36 ms). With 6, at 2^22 (2^19 rows under an
+# 8-coset quotient), `_quotient_interp_p` did not return in 600 s (my chip
+# run, PR 39; PERF.md Open question 10: the third program of XLA stages +
+# a matmul kernel to stall at a size nobody had run). Past it a caller
+# dispatches the two halves as programs of their own. An OBSERVED boundary,
+# set from that one stall and not from its cause: revisit it with Open
+# question 10, which asks why such a program stalls.
+INVERSE_FUSED_OUTER_STAGES = 5
+
+
+def inverse_is_two_programs(n: int) -> bool:
+    """True where the inverse transform of size n must be dispatched as
+    its two halves (`hybrid_inv_kernels_p`, `hybrid_inv_outer_p`), each in
+    a program of its own."""
+    from . import mxu_ntt
+
+    n = int(n)
+    outer = n.bit_length() - 1 - mxu_ntt.MAX_LOG_N
+    return _mxu_ntt_ready(n, None) and outer > INVERSE_FUSED_OUTER_STAGES
 
 
 def fft_natural_to_bitreversed_p(p):
@@ -351,11 +385,16 @@ def _hybrid_fwd_p(p, log_n: int, programs, scale=None, start=None, size=None):
 def _count_fused_stages(log_n: int, transforms: int):
     """`ntt.fused_outer_stages`: the radix-2 stages the kernel of one
     forward dispatch absorbed, times its column transforms (0 a transform
-    up to 2^MAX_LOG_N rows, where there is no outer stage)."""
-    from .mxu_ntt import fused_outer_stages
+    up to 2^MAX_LOG_N rows, where there is no outer stage); and its twin
+    `ntt.leading_outer_stages`: the XLA stages of the `outer` program
+    before it (0 up to 2^(MAX_LOG_N + 2) rows)."""
+    from .mxu_ntt import fused_outer_stages, leading_outer_stages
 
     _metrics.count(
         "ntt.fused_outer_stages", fused_outer_stages(log_n) * transforms
+    )
+    _metrics.count(
+        "ntt.leading_outer_stages", leading_outer_stages(log_n) * transforms
     )
 
 
@@ -411,6 +450,13 @@ def _lde_one_p(p, lde_factor: int, coset: int):
             out[1].reshape(lead + (lde_factor, n)),
         )
     return _lde_p_jit(p, lde_factor, coset)
+
+
+def lde_chunk_sizes(b: int, n: int, lde_factor: int) -> list[int]:
+    """The column counts of the chunks `lde_from_monomial_p` walks over a
+    (b, n) stack: one forward dispatch each."""
+    per = _col_chunks(b, n * 8 * lde_factor) or b
+    return [min(per, b - i) for i in range(0, b, per)]
 
 
 def lde_from_monomial_p(

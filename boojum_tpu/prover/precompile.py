@@ -117,6 +117,20 @@ def _round3_eval_plan(sds, groups, N: int, L: int, Q: int, smm):
     return picked, transformed + [("zs", 2)]
 
 
+def _streamed_single_columns(assembly, S, B_wit, num_partials, num_lk):
+    """(tag, oracle columns, column indices) of the single columns round 5
+    regenerates from a streamed oracle's monomials (`_deep_round5_prep`):
+    stage 2's z pair and lookup sums, the witness's public-input columns."""
+    ab_off = 2 + 2 * num_partials
+    s2_idxs = (0, 1) + tuple(ab_off + j for j in range(2 * num_lk))
+    pi_idxs = tuple(c_ for (c_, _r, _v) in assembly.public_inputs)
+    return [
+        (tag, B, idxs)
+        for tag, B, idxs in (("s2", S, s2_idxs), ("pi", B_wit, pi_idxs))
+        if idxs
+    ]
+
+
 def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
     """The shape-keyed kernel library for a fused prove of `assembly`
     under `config` — meshless, or per-chip shard_map when a shard_map
@@ -134,9 +148,10 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
     circuit STRUCTURE is read (placements, gates, geometry, lookup
     params) — the witness values and the setup's sigma columns are never
     touched, so this runs before generate_setup. Deliberately skipped
-    (cheap, query-dependent shapes): the fused query gather, streamed
-    single-column opens, the replicated Merkle tail after the cap
-    all_gather, and the PoW grind (host-side)."""
+    (cheap, query-dependent shapes): the fused query gather, the
+    replicated Merkle tail after the cap all_gather, and the PoW grind
+    (host-side). A streamed prove's leaf-value gathers and single-column
+    opens are listed: each holds a forward transform."""
     from ..merkle import leaf_digests_device, node_layers_device
     from ..field import extension as ext_f
     from ..ntt.ntt import _ext_powers_jit, ntt_kernel_specs
@@ -254,9 +269,11 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
 
     commit_specs("wit", B_wit, stream)
     commit_specs("s2", S, stream)
-    # the quotient LDE is always materialized, and its monomials come from
-    # _quotient_interp rather than monomial_from_values — no imono kernel
-    commit_specs("q", B_q, False, mono=False)
+    # the quotient's monomials come from _quotient_interp rather than
+    # monomial_from_values — no imono kernel; it streams with the prove's
+    # other commits (the shard_map tail always materializes it)
+    stream_q = stream and smm is None
+    commit_specs("q", B_q, stream_q, mono=False)
     commit_specs("setup", B_setup, stream_setup)
     # streamed commits are double-buffered: the block LDE (on the mesh
     # with its pivot) and the absorb are separate dispatches
@@ -381,8 +398,10 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
     # the setup oracle streams in the DEEP phase iff it was COMMITTED
     # streamed (prover follows setup.setup_lde, decided per-setup by
     # generate_setup), independently of the prove-wide stream flag
+    per = max(1, P._DEEP_BLOCK_BUDGET // (N * 8))
     for B, streamed_src in (
-        (B_wit, stream), (B_setup, stream_setup), (S, stream)
+        (B_wit, stream), (B_setup, stream_setup), (S, stream),
+        (B_q, stream_q),
     ):
         if streamed_src:
             for i in range(0, B, COL_BLOCK):
@@ -393,13 +412,19 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
                     b32, log_n, L, mono=False
                 ):
                     add(f"deep_regen:{nm}", fn, *args)
+            # the query phase's leaf values, one program an oracle
+            add(
+                f"stream_gather_b{B}", P._stream_gather_fused, _sds(B, n),
+                jax.ShapeDtypeStruct((config.num_queries,), jnp.int64), L,
+            )
         else:
-            per = max(1, P._DEEP_BLOCK_BUDGET // (N * 8))
             for i in range(0, B, per):
                 deep_blocks.add(min(per, B - i))
-    per = max(1, P._DEEP_BLOCK_BUDGET // (N * 8))
-    for i in range(0, B_q, per):
-        deep_blocks.add(min(per, B_q - i))
+    if stream:
+        for tag, B, idxs in _streamed_single_columns(
+            assembly, S, B_wit, num_partials, num_lk
+        ):
+            add(f"deep_cols_{tag}", P._cols_from_mono, _sds(B, n), idxs, L)
     if smm is not None and not (stream or stream_setup):
         # the sm round 5: ONE shard_map graph for main sum + extras
         # (shard_sweep.deep_codeword_sm) — the per-block meshless deep
@@ -553,8 +578,10 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
     from .shape_key import shape_bucket
     from .streaming import (
         COL_BLOCK,
+        _absorb_cols_cm_p,
+        _absorb_cols_digests_p,
         _absorb_cols_p,
-        _lde_block_cols_p,
+        block_chunk_sizes,
         use_streamed_lde,
     )
     from . import prover as P
@@ -615,15 +642,24 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
         if streamed:
             for i in range(0, B, COL_BLOCK):
                 absorb_blocks.add(min(COL_BLOCK, B - i))
+            # the block transforms, DEEP's regenerations and the query
+            # gather (resident.stream_kernel_specs: oracles share blocks)
+            for nm, fn, args in RES.stream_kernel_specs(
+                B, n, L, config.num_queries
+            ):
+                add(nm, fn, *args)
         else:
             add(
                 f"{tag}:leaf_digests_limbres", leaf_digests_planes,
                 _sdsp(B, L, n),
             )
 
+    # the quotient streams with the prove's other commits (the shard_map
+    # tail, shard_sweep.commit_from_mono_sm_p, always materializes it)
+    stream_q = stream and smm is None
     commit_specs("wit", B_wit, stream)
     commit_specs("s2", S, stream)
-    commit_specs("q", B_q, False, mono=False)
+    commit_specs("q", B_q, stream_q, mono=False)
     commit_specs("setup", B_setup, stream_setup)
     for b in sorted(absorb_blocks):
         if smm is not None:
@@ -632,15 +668,17 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
                 SS._lde_pivot_cols_fn_p(smm, L, b),
                 _sdsp(SS.padded_cols(b, D), n),
             )
-        else:
             add(
-                f"lde_block_cols_limbres_b{b}", _lde_block_cols_p,
-                _sdsp(b, n), L,
+                f"absorb_cols_limbres_b{b}", _absorb_cols_p,
+                _sdsp(N, 12), _sdsp(N, b),
             )
-        add(
-            f"absorb_cols_limbres_b{b}", _absorb_cols_p,
-            _sdsp(N, 12), _sdsp(N, b),
-        )
+        else:  # column-major: leaves along the lanes (streaming.py)
+            add(
+                f"absorb_cols_limbres_b{b}", _absorb_cols_cm_p,
+                _sdsp(12, N), _sdsp(b, N),
+            )
+    if absorb_blocks and smm is None:
+        add("absorb_cols_digests_limbres", _absorb_cols_digests_p, _sdsp(12, N))
     if smm is None:
         add("node_layers_limbres", node_layers_planes, _sdsp(N, 4), cap)
     else:
@@ -741,12 +779,8 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
         _sdsp(B_wit, n), _sdsp(B_setup, n), _sdsp(S, n), _sdsp(2, n),
         _i32(), _sdsp(Q * n), _sdsp(Q * n), _sdsp(Q * n), _u32(4, S_cols),
     )
-    add(
-        "quotient_interp_limbres", RES._quotient_interp_p,
-        tuple(_sdsp(n) for _ in range(Q)),
-        tuple(_sdsp(n) for _ in range(Q)),
-        Q, n,
-    )
+    for nm, fn, args in RES.quotient_interp_kernel_specs(Q, n):
+        add(nm, fn, *args)
 
     # ---- rounds 4-5 plane twins ------------------------------------------
     num_lk = (R_args + 1) if lookups else 0
@@ -759,24 +793,32 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
     add("deep_denoms_limbres", RES._deep_denoms_p, _sdsp(N), sc4, sc4)
     add("ext_binv_deep_limbres", lop.ext_batch_inverse_jit, pairp(2, N))
     deep_blocks: set[int] = set()
+    per = max(1, RES._DEEP_BLOCK_BUDGET // (N * 8))
     for B, streamed_src in (
-        (B_wit, stream), (B_setup, stream_setup), (S, stream)
+        (B_wit, stream), (B_setup, stream_setup), (S, stream),
+        (B_q, stream_q),
     ):
         if streamed_src:
-            for i in range(0, B, COL_BLOCK):
-                b32 = min(COL_BLOCK, B - i)
-                deep_blocks.add(b32)
-                for nm, fn, args in plane_ntt_kernel_specs(
-                    b32, log_n, L, mono=False
+            # regenerated a block at a time (its transforms are listed
+            # with the oracle's commit above; a shard_map prove streams
+            # its commits per chip and regenerates here off the mesh)
+            deep_blocks.update(block_chunk_sizes(B, n, L))
+            if smm is not None:
+                for nm, fn, args in RES.stream_kernel_specs(
+                    B, n, L, config.num_queries
                 ):
-                    add(f"deep_regen:{nm}", fn, *args)
+                    add(nm, fn, *args)
         else:
-            per = max(1, RES._DEEP_BLOCK_BUDGET // (N * 8))
             for i in range(0, B, per):
                 deep_blocks.add(min(per, B - i))
-    per = max(1, RES._DEEP_BLOCK_BUDGET // (N * 8))
-    for i in range(0, B_q, per):
-        deep_blocks.add(min(per, B_q - i))
+    if stream:
+        for tag, B, idxs in _streamed_single_columns(
+            assembly, S, B_wit, num_partials, num_lk
+        ):
+            for nm, fn, args in RES.cols_from_mono_kernel_specs(
+                tag, B, n, L, idxs
+            ):
+                add(nm, fn, *args)
     if smm is not None and not (stream or stream_setup):
         capE = 2 + num_lk + num_pi
         add(

@@ -91,6 +91,7 @@ class _StageClock:
 from .streaming import (
     MonomialPlanesSource,
     MonomialSource,
+    count_lde_columns,
     deep_source_blocks,
     use_streamed_lde,
 )
@@ -351,8 +352,7 @@ def _commit_pipeline(values, L: int, cap: int, stream: bool, sm_mesh=None):
     explicit all_to_all layout pivot, per-chip leaf sponges and an
     explicit cap all_gather — same return contract, bit-identical
     digests."""
-    from ..merkle import commit_layers_device, node_layers_device
-    from .streaming import streamed_leaf_digests_blocks
+    from ..merkle import commit_layers_device
 
     if sm_mesh is not None:
         from ..parallel.shard_sweep import commit_pipeline_sm
@@ -363,13 +363,23 @@ def _commit_pipeline(values, L: int, cap: int, stream: bool, sm_mesh=None):
         mono = monomial_from_values(values)
         _metrics.count("ntt.monomial_from_values")
         if stream:
-            digests = streamed_leaf_digests_blocks(mono, L)
-            _metrics.count("merkle.streamed_commits")
-            return mono, None, node_layers_device(digests, cap)
+            return mono, None, _streamed_commit_layers(mono, L, cap)
         lde = lde_from_monomial(mono, L)
         _metrics.count("ntt.lde_from_monomial")
         _metrics.count("merkle.commits")
         return mono, lde, commit_layers_device(lde, cap)
+
+
+def _streamed_commit_layers(mono, L: int, cap: int):
+    """The tree layers of a commit streamed from u64 monomials: the rate-L
+    storage is never made (resident.streamed_commit_layers_p's twin)."""
+    from ..merkle import node_layers_device
+    from .streaming import streamed_leaf_digests_blocks
+
+    with _span("stream.commit", columns=int(mono.shape[0])):
+        digests = streamed_leaf_digests_blocks(mono, L)
+        _metrics.count("merkle.streamed_commits")
+        return node_layers_device(digests, cap)
 
 
 def _tree_from_layers(layers, cap):
@@ -565,6 +575,60 @@ def _sweep_barrier_stride(Q: int, working_set_bytes: int) -> int:
     if Q * working_set_bytes <= room:
         return 0
     return max(1, int(room // working_set_bytes))
+
+
+def _input_caches_fit_by_plan(
+    working_set_bytes: int, setup_storage_bytes: int, cache_bytes: int
+) -> bool:
+    """Whether a streamed prove may keep its device-input caches through
+    round 3, BY PLAN: the allocator's limit and bytes that follow from the
+    shapes alone, never `bytes_in_use`, so that the same circuit on the same
+    chip decides the same in every prove of every process. Resident by plan
+    in round 3: the monomials of the sweep's groups (half a working set:
+    the working set counts each group twice), the setup's rate-L storage
+    where it was committed materialized, and the caches. They stay where,
+    beside that, one coset's working set still fits the share of the rest
+    that round 3 may queue (`_sweep_barrier_stride`'s rule on planned
+    bytes). A backend that reports no limit (XLA:CPU) keeps them."""
+    memory = _metrics.device_memory_room()
+    if memory is None:
+        return True
+    planned = working_set_bytes // 2 + setup_storage_bytes + cache_bytes
+    _metrics.gauge_max("prover.round3_planned_bytes", planned)
+    return _SWEEP_QUEUE_SHARE * (memory[0] - planned) >= working_set_bytes
+
+
+def _drop_input_caches_if_short(
+    assembly, setup, keys, working_set_bytes, setup_storage_bytes
+):
+    """A streamed prove regenerates everything from monomials, so the
+    values-form device-input caches (witness columns, sigmas, table stack:
+    1.3 GB at 2^19 rows of the Era geometry) only spare the next prove
+    their upload. That upload is 4.5 s of host time a prove there (my chip
+    run, PR 39), so they go only where round 3 needs their room by plan
+    (`_input_caches_fit_by_plan`: at 2^19 rows of the Era geometry 4.3 GB
+    planned and 6.3 GB of queue share against a working set of 3.2 GB, so
+    they stay; at 2^20 rows, 8.7 and 4.1 against 6.5, they go). The caches
+    are counted whole, so on a mesh, where each chip holds a share of
+    them, the rule errs towards dropping."""
+    caches = [
+        (cache, k)
+        for obj, obj_keys in zip((assembly, setup), keys)
+        for cache in (getattr(obj, "_dev_cache", None),) if cache
+        for k in obj_keys if k in cache
+    ]
+    cache_bytes = sum(
+        int(a.nbytes) for cache, k in caches
+        for a in jax.tree_util.tree_leaves(cache[k])
+    )
+    _metrics.gauge_max("prover.input_cache_bytes", cache_bytes)
+    fit = _input_caches_fit_by_plan(
+        working_set_bytes, setup_storage_bytes, cache_bytes
+    )
+    _metrics.count("prover.input_caches_dropped", 0 if fit else 1)
+    if not fit:
+        for cache, k in caches:
+            del cache[k]
 
 
 def _gate_sweep_stats(assembly, selector_paths, planes: bool):
@@ -789,8 +853,10 @@ def _quotient_interp(T0_parts, T1_parts, Q: int, n: int):
     return jnp.stack(q_cols)
 
 
-def _quotient_tail_fused(T0_parts, T1_parts, Q: int, n: int, L: int, cap: int):
-    """Quotient interpolation + chunk split + LDE + commit.
+def _quotient_tail_fused(T0_parts, T1_parts, Q: int, n: int, L: int, cap: int,
+                         stream: bool = False):
+    """Quotient interpolation + chunk split + LDE + commit (streamed from
+    the monomials where the prove's commits stream).
 
     Deliberately SEPARATE dispatches (interp / LDE / leaf sponge / node
     stack): at 2^20 rows one fused graph's working set — the size-Q*n
@@ -803,6 +869,8 @@ def _quotient_tail_fused(T0_parts, T1_parts, Q: int, n: int, L: int, cap: int):
     from ..merkle import commit_layers_device
 
     q_mono = _quotient_interp(tuple(T0_parts), tuple(T1_parts), Q, n)
+    if stream:
+        return q_mono, None, _streamed_commit_layers(q_mono, L, cap)
     q_lde = lde_from_monomial(q_mono, L)
     return q_mono, q_lde, commit_layers_device(q_lde, cap)
 
@@ -836,11 +904,17 @@ def _deep_denoms_fused(xs_lde, z01, zw01):
 
 @partial(jax.jit, static_argnums=(1, 2))
 def _cols_from_mono(mono, idxs: tuple, L: int):
-    """Regenerate a handful of rate-L columns from monomials (streamed
-    oracles' round-5 single-column opens), one dispatch."""
     sel = mono[jnp.asarray(np.array(idxs, dtype=np.int64))]
     lde = lde_from_monomial(sel, L)
     return lde.reshape(len(idxs), -1)
+
+
+def cols_from_mono(mono, idxs: tuple, L: int):
+    """Regenerate a handful of rate-L columns from monomials (streamed
+    oracles' round-5 single-column opens), one dispatch."""
+    count_lde_columns("deep", len(idxs))
+    with _span("stream.deep_regen", columns=len(idxs)):
+        return _cols_from_mono(mono, idxs, L)
 
 
 @lru_cache(maxsize=8)
@@ -1059,7 +1133,7 @@ def _deep_round5_prep(
     ab_off = 2 + 2 * num_partials
     s2_idxs = [0, 1] + [ab_off + j for j in range(2 * num_lk)]
     if isinstance(s2_lde_flat, MonomialSource):
-        s2_cols = _cols_from_mono(s2_mono, tuple(s2_idxs), L)
+        s2_cols = cols_from_mono(s2_mono, tuple(s2_idxs), L)
     else:
         with _transfer.upload("deep_prep", 8 * len(s2_idxs)):
             s2_cols = s2_lde_flat[jnp.asarray(np.array(s2_idxs))]
@@ -1069,7 +1143,7 @@ def _deep_round5_prep(
     if num_pi:
         pi_cols_idx = [c_ for (c_, _r, _v) in assembly.public_inputs]
         if isinstance(wit_lde_all, MonomialSource):
-            cols_pi = _cols_from_mono(wit_mono, tuple(pi_cols_idx), L)
+            cols_pi = cols_from_mono(wit_mono, tuple(pi_cols_idx), L)
         else:
             with _transfer.upload("deep_prep", 8 * num_pi):
                 cols_pi = wit_lde_all[jnp.asarray(np.array(pi_cols_idx))]
@@ -1326,6 +1400,24 @@ def _prove_impl(
     Q_est = setup.vk.effective_quotient_degree()
     total_cols = (Ct + W + M) + (Ct + K + TW) + S_est + 2 * Q_est
     stream = fused and use_streamed_lde(total_cols, N)
+
+    _chips = 1 if sm_mesh is None else sm_mesh.size
+
+    def _round3_ws(stage2_columns: int) -> int:
+        """Round 3's working set a coset, from the four groups' columns."""
+        return _sweep_working_set_bytes(
+            (Ct + W + M) + (Ct + K + TW) + stage2_columns + 2, n, _chips
+        )
+
+    # the setup oracle was committed alone: its rate-L storage is resident
+    # through the prove unless it streamed (setup_lde None)
+    _setup_storage_bytes = (
+        0 if setup.setup_lde is None else 8 * (Ct + K + TW) * N // _chips
+    )
+    # present in every recording, so that a prove that streams nothing
+    # says so (0) and not nothing
+    _metrics.count("merkle.streamed_commits", 0)
+    _metrics.count("stream.regen_columns", 0)
     if fused:
         # dispatch everything challenge-independent — witness H2D chunks,
         # the sigma/table uploads, domain/twiddle/FRI caches — while the
@@ -1488,17 +1580,14 @@ def _prove_impl(
         num_all = den_all = den_inv_all = lk_inv = dens = mult_dev = None
         z_pp = None
         if stream:
-            for _obj, _keys in (
+            _drop_input_caches_if_short(
+                assembly, setup,
                 (
-                    assembly,
                     ("witness_planes", "table_stack_planes", "mult_planes"),
+                    ("sigma_planes",),
                 ),
-                (setup, ("sigma_planes",)),
-            ):
-                _c = getattr(_obj, "_dev_cache", None)
-                if _c is not None:
-                    for _k in _keys:
-                        _c.pop(_k, None)
+                _round3_ws(int(s2_mono[0].shape[0])), _setup_storage_bytes,
+            )
     elif fused:
         sigma_dev = shard_cols(
             _dev_cached(setup, "sigma", lambda: jnp.asarray(setup.sigma_cols))
@@ -1584,18 +1673,11 @@ def _prove_impl(
         num_all = den_all = den_inv_all = lk_inv = dens = mult_dev = None
         z_pp = None
         if stream:
-            # streamed proves regenerate everything from monomials; the
-            # values-form device-input caches (witness columns, sigmas,
-            # table stack — ~1.5 GB at 2^20) only save warm-rep H2D time
-            # and that residency is what the big-trace mode cannot afford
-            for _obj, _keys in (
-                (assembly, ("witness_cols", "table_stack", "mult")),
-                (setup, ("sigma",)),
-            ):
-                _c = getattr(_obj, "_dev_cache", None)
-                if _c is not None:
-                    for _k in _keys:
-                        _c.pop(_k, None)
+            _drop_input_caches_if_short(
+                assembly, setup,
+                (("witness_cols", "table_stack", "mult"), ("sigma",)),
+                _round3_ws(int(s2_mono.shape[0])), _setup_storage_bytes,
+            )
     else:
         sigma_dev = shard_cols(
             _dev_cached(setup, "sigma", lambda: jnp.asarray(setup.sigma_cols))
@@ -1817,15 +1899,10 @@ def _prove_impl(
         # from what the device reports and the working set's size; the
         # flight recording carries what it saw and how many barriers it put.
         _setup_eval_mono = _setup_mono_p if res else setup.setup_monomials
-        _sweep_ws = _sweep_working_set_bytes(
-            sum(
-                int((g[0] if res else g).shape[0])
-                for g in (wit_mono, _setup_eval_mono, s2_mono, zs_mono)
-            ),
-            n, 1 if sm_mesh is None else sm_mesh.size,
-        )
+        _sweep_ws = _round3_ws(int((s2_mono[0] if res else s2_mono).shape[0]))
         _barrier_stride = _sweep_barrier_stride(Q, _sweep_ws)
         _metrics.gauge_max("quotient.sweep_working_set_bytes", _sweep_ws)
+        _metrics.count("quotient.sweep_barrier_stride", _barrier_stride)
         _metrics.count("quotient.sweep_barriers", 0)
         # what the gates cost the sweep, from the plan alone: the field
         # operations of one row, and how many gates are replayed from a
@@ -1942,7 +2019,7 @@ def _prove_impl(
             if res:
                 from ..parallel.shard_sweep import commit_from_mono_sm_p
 
-                q_mono = RES._quotient_interp_p(
+                q_mono = RES.quotient_interp_p(
                     tuple(T_parts0), tuple(T_parts1), Q, n
                 )
                 q_lde, layers = commit_from_mono_sm_p(
@@ -1957,11 +2034,11 @@ def _prove_impl(
                 q_lde, layers = commit_from_mono_sm(q_mono, L, cap, sm_mesh)
         elif res:
             q_mono, q_lde, layers = RES._quotient_tail_p(
-                tuple(T_parts0), tuple(T_parts1), Q, n, L, cap
+                tuple(T_parts0), tuple(T_parts1), Q, n, L, cap, stream
             )
         else:
             q_mono, q_lde, layers = _quotient_tail_fused(
-                tuple(T_parts0), tuple(T_parts1), Q, n, L, cap
+                tuple(T_parts0), tuple(T_parts1), Q, n, L, cap, stream
             )
         del T_parts0, T_parts1
         _prefetch_r(layers[-1])
@@ -2217,15 +2294,17 @@ def _prove_impl(
         q_lde = _demesh(q_lde)
         xs_lde = _demesh(xs_lde)
 
+    def _quotient_oracle():
+        """What rounds 5 and the queries hold of the quotient's commitment:
+        its monomials where the commit streamed (no storage was made)."""
+        if q_lde is None:
+            return (MonomialPlanesSource if res else MonomialSource)(q_mono, L)
+        if res:
+            return (q_lde[0].reshape(2 * Q, N), q_lde[1].reshape(2 * Q, N))
+        return q_lde.reshape(2 * Q, N)
+
     deep_sources = [
-        wit_lde_all,
-        setup_lde_flat,
-        s2_lde_flat,
-        (
-            (q_lde[0].reshape(2 * Q, N), q_lde[1].reshape(2 * Q, N))
-            if res
-            else q_lde.reshape(2 * Q, N)
-        ),
+        wit_lde_all, setup_lde_flat, s2_lde_flat, _quotient_oracle(),
     ]
     num_deep_terms = (
         B + 2
@@ -2489,14 +2568,16 @@ def _prove_impl(
             HOST in _take_vals (the query-opening edge of the residency
             contract; no device u64 ever exists)."""
             if isinstance(leaves_cols, MonomialSource):
-                vals = _stream_gather_fused(
-                    leaves_cols.mono, idx_dev, leaves_cols.L
-                )
+                count_lde_columns("queries", leaves_cols.shape[0])
+                with _span(
+                    "stream.query_regen", columns=leaves_cols.shape[0]
+                ):
+                    vals = _stream_gather_fused(
+                        leaves_cols.mono, idx_dev, leaves_cols.L
+                    )
                 return ("one", _defer(vals, None, 2))
             if isinstance(leaves_cols, MonomialPlanesSource):
-                vlo, vhi = RES._stream_gather_p(
-                    leaves_cols.mono, idx_dev, leaves_cols.L
-                )
+                vlo, vhi = RES.stream_gather_p(leaves_cols, idx_dev)
                 return ("pair", _defer(vlo, None, 2), _defer(vhi, None, 2))
             if isinstance(leaves_cols, tuple):
                 return (
@@ -2518,13 +2599,12 @@ def _prove_impl(
                 ]
             return vals_h, level_hs, assemble
 
+        _q_flat = _quotient_oracle()
         if res:
-            _q_flat = (q_lde[0].reshape(2 * Q, N), q_lde[1].reshape(2 * Q, N))
             # a setup's first prove pulls its tree's cap here (kept after)
             with _transfer.pull_site("setup_cap"):
                 _setup_tree = RES.setup_tree_planes(setup)
         else:
-            _q_flat = q_lde.reshape(2 * Q, N)
             _setup_tree = setup.setup_tree
         oracle_handles = [
             _defer_oracle(wit_lde_all, wit_tree),

@@ -578,22 +578,24 @@ def coset_eval_kernel_specs(tag: str, B: int, n: int, Q: int) -> list:
     return specs
 
 
-@partial(jax.jit, static_argnums=(2, 3))
-def _quotient_interp_p(T0_parts, T1_parts, Q: int, n: int):
-    """Plane twin of prover._quotient_interp."""
-    g_inv = gl.inv(gl.MULTIPLICATIVE_GENERATOR)
-    T0 = (
-        jnp.concatenate([t[0] for t in T0_parts]),
-        jnp.concatenate([t[1] for t in T0_parts]),
+def _quotient_parts_p(T0_parts, T1_parts):
+    return tuple(
+        (
+            jnp.concatenate([t[0] for t in parts]),
+            jnp.concatenate([t[1] for t in parts]),
+        )
+        for parts in (T0_parts, T1_parts)
     )
-    T1 = (
-        jnp.concatenate([t[0] for t in T1_parts]),
-        jnp.concatenate([t[1] for t in T1_parts]),
-    )
-    T_mono = tuple(
-        LN.distribute_powers_p(LN.ifft_bitreversed_to_natural_p(t), g_inv)
-        for t in (T0, T1)
-    )
+
+
+def _quotient_unshift_p(t):
+    """An inverse transform over the coset -> the monomials: times g^-i."""
+    return LN.distribute_powers_p(t, gl.inv(gl.MULTIPLICATIVE_GENERATOR))
+
+
+def _quotient_rows_p(T_mono, Q: int, n: int):
+    """The two components' monomials -> the (2 * Q, n) rows of the
+    quotient's chunks."""
     lo_rows, hi_rows = [], []
     for i in range(Q):
         for comp in (0, 1):
@@ -602,11 +604,71 @@ def _quotient_interp_p(T0_parts, T1_parts, Q: int, n: int):
     return jnp.stack(lo_rows), jnp.stack(hi_rows)
 
 
-def _quotient_tail_p(T0_parts, T1_parts, Q: int, n: int, L: int, cap: int):
+@partial(jax.jit, static_argnums=(2, 3))
+def _quotient_interp_p(T0_parts, T1_parts, Q: int, n: int):
+    """Plane twin of prover._quotient_interp."""
+    T_mono = tuple(
+        _quotient_unshift_p(LN.ifft_bitreversed_to_natural_p(t))
+        for t in _quotient_parts_p(T0_parts, T1_parts)
+    )
+    return _quotient_rows_p(T_mono, Q, n)
+
+
+@partial(jax.jit, static_argnums=(2,))
+def _quotient_interp_kernels_p(T0_parts, T1_parts, log_qn: int):
+    return tuple(
+        LN.hybrid_inv_kernels_p(t, log_qn)
+        for t in _quotient_parts_p(T0_parts, T1_parts)
+    )
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _quotient_interp_outer_p(T_blocks, Q: int, n: int):
+    log_qn = (Q * n).bit_length() - 1
+    T_mono = tuple(
+        _quotient_unshift_p(LN.hybrid_inv_outer_p(t, log_qn))
+        for t in T_blocks
+    )
+    return _quotient_rows_p(T_mono, Q, n)
+
+
+def quotient_interp_p(T0_parts, T1_parts, Q: int, n: int):
+    """The quotient's monomials from the sweeps' outputs: ONE program
+    (`_quotient_interp_p`) while the inverse transform's outer stages and
+    its kernels return from one; past that
+    (`limb_ntt.inverse_is_two_programs`: 2^22 on the v5e) the kernels over
+    the joined parts and the outer stages with the rows, a program each."""
+    if not LN.inverse_is_two_programs(Q * n):
+        return _quotient_interp_p(T0_parts, T1_parts, Q, n)
+    blocks = _quotient_interp_kernels_p(
+        T0_parts, T1_parts, (Q * n).bit_length() - 1
+    )
+    return _quotient_interp_outer_p(blocks, Q, n)
+
+
+def quotient_interp_kernel_specs(Q: int, n: int) -> list:
+    """(name, fn, args) of what `quotient_interp_p` dispatches."""
+    parts = tuple(LN.sdsp(n) for _ in range(Q))
+    if not LN.inverse_is_two_programs(Q * n):
+        return [("quotient_interp_limbres", _quotient_interp_p,
+                 (parts, parts, Q, n))]
+    whole = (LN.sdsp(Q * n), LN.sdsp(Q * n))
+    return [
+        ("quotient_interp_limbres:kernels", _quotient_interp_kernels_p,
+         (parts, parts, (Q * n).bit_length() - 1)),
+        ("quotient_interp_limbres:outer", _quotient_interp_outer_p,
+         (whole, Q, n)),
+    ]
+
+
+def _quotient_tail_p(T0_parts, T1_parts, Q: int, n: int, L: int, cap: int,
+                     stream: bool = False):
     """Plane twin of prover._quotient_tail_fused (same dispatch split)."""
     from ..merkle import commit_layers_planes
 
-    q_mono = _quotient_interp_p(tuple(T0_parts), tuple(T1_parts), Q, n)
+    q_mono = quotient_interp_p(tuple(T0_parts), tuple(T1_parts), Q, n)
+    if stream:
+        return q_mono, None, streamed_commit_layers_p(q_mono, L, cap)
     q_lde = LN.lde_from_monomial_p(q_mono, L)
     return q_mono, q_lde, commit_layers_planes(q_lde, cap)
 
@@ -743,12 +805,13 @@ def _deep_combine_p(t0, t1, y0s_p, y1s_p, c0s_p, c1s_p, inv_xz):
 
 def deep_source_blocks_p(sources, per_bytes: int):
     """Plane twin of streaming.deep_source_blocks."""
-    from .streaming import MonomialPlanesSource
+    from .streaming import MonomialPlanesSource, count_lde_columns
 
     off = 0
     for src in sources:
         if isinstance(src, MonomialPlanesSource):
-            for i, flat in src.blocks():
+            count_lde_columns("deep", src.shape[0])
+            for i, flat in src.blocks(span="stream.deep_regen"):
                 yield flat, off + i
             off += src.shape[0]
         else:
@@ -836,23 +899,116 @@ def _deep_extras_fn_p(num_zw: int, num_lk: int, num_pi: int):
     return _deep_extras_p
 
 
-@partial(jax.jit, static_argnums=(1, 2))
-def _cols_from_mono_p(mono_p, idxs: tuple, L: int):
-    """Plane twin of prover._cols_from_mono."""
+def _mono_rows_p(mono_p, idxs: tuple):
     sel_idx = jnp.asarray(np.array(idxs, dtype=np.int64))
-    sel = (mono_p[0][sel_idx], mono_p[1][sel_idx])
-    lde = LN.lde_from_monomial_p(sel, L)
-    return (
-        lde[0].reshape(len(idxs), -1),
-        lde[1].reshape(len(idxs), -1),
-    )
+    return mono_p[0][sel_idx], mono_p[1][sel_idx]
 
 
-@partial(jax.jit, static_argnums=(2,))
-def _stream_gather_p(mono_p, idx_dev, L: int):
-    from .streaming import MonomialPlanesSource
+@partial(jax.jit, static_argnums=(1,))
+def _deep_cols_take_p(mono_p, idxs: tuple):
+    return _mono_rows_p(mono_p, idxs)
 
-    return MonomialPlanesSource(mono_p, L).gather_rows(idx_dev)
+
+def cols_from_mono_p(mono_p, idxs: tuple, L: int):
+    """Plane twin of prover.cols_from_mono: the handful of single columns
+    round 5 opens at shifted points, regenerated at rate L from a streamed
+    oracle's monomials. The picked columns are a block of their own: their
+    cut, then the commit's walk (`streaming.lde_block_cols_p`)."""
+    from .streaming import count_lde_columns, lde_block_cols_p
+
+    count_lde_columns("deep", len(idxs))
+    with _span("stream.deep_regen", columns=len(idxs)):
+        sel = _deep_cols_take_p(mono_p, idxs)
+        return lde_block_cols_p(sel, 0, len(idxs), L)
+
+
+def stream_gather_p(source, idx_dev):
+    """The query phase's (B, num_queries) leaf values of a streamed
+    oracle: the source's own host-driven blocks, a gather a block and
+    their join."""
+    from .streaming import count_lde_columns
+
+    count_lde_columns("queries", source.shape[0])
+    with _span("stream.query_regen", columns=source.shape[0]):
+        return source.gather_rows(idx_dev)
+
+
+def stream_kernel_specs(B: int, n: int, L: int, num_queries: int) -> list:
+    """(name, fn, args) of what one streamed oracle of B columns dispatches
+    on planes beside its absorbs and DEEP sums: the commit's block
+    transforms, the regenerations of DEEP and the query gather, in the
+    form `forward_is_own_program(n)` gives each (prover/streaming.py).
+    Block and chunk programs are keyed on the block alone, so oracles
+    share them: a caller drops the repeats."""
+    from . import streaming as ST
+
+    sdsp = LN.sdsp
+    N, log_n = n * L, n.bit_length() - 1
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    idx = jax.ShapeDtypeStruct((num_queries,), jnp.int64)
+    blocks = sorted({min(ST.COL_BLOCK, B - i) for i in range(0, B, ST.COL_BLOCK)})
+
+    def lde(c):
+        return [
+            (f"stream:{nm}", fn, args)
+            for nm, fn, args in LN.plane_ntt_kernel_specs(
+                c, log_n, L, mono=False
+            )
+        ]
+
+    chunks = ST.block_chunk_sizes(B, n, L)
+    specs = [(
+        f"stream_gather_join_limbres_b{B}", ST._stream_gather_join_p,
+        (tuple(sdsp(c, num_queries) for c in chunks),),
+    )]
+    for b in blocks:
+        specs.append((
+            f"lde_block_cols_join_limbres_b{b}", ST._lde_block_cols_join_p,
+            (tuple(sdsp(c, L, n) for c in LN.lde_chunk_sizes(b, n, L)),),
+        ))
+    for c in sorted(set(chunks)):
+        if c != B:
+            specs.append((
+                f"lde_block_cols_take_limbres_b{B}_c{c}",
+                ST._lde_block_cols_take_p, (sdsp(B, n), i32, c),
+            ))
+        specs += lde(c)
+        specs.append((
+            f"stream_gather_block_limbres_c{c}", ST._stream_gather_block_p,
+            (sdsp(c, N), idx),
+        ))
+    return specs
+
+
+def cols_from_mono_kernel_specs(tag: str, B: int, n: int, L: int,
+                                idxs: tuple) -> list:
+    """(name, fn, args) of what `cols_from_mono_p` dispatches for the
+    columns `idxs` of a streamed oracle of B columns."""
+    from . import streaming as ST
+
+    sdsp = LN.sdsp
+    k = len(idxs)
+    name = f"deep_cols_{tag}_limbres"
+    chunks = LN.lde_chunk_sizes(k, n, L)
+    specs = [
+        (f"{name}:take", _deep_cols_take_p, (sdsp(B, n), idxs)),
+        (f"{name}:join", ST._lde_block_cols_join_p,
+         (tuple(sdsp(c, L, n) for c in chunks),)),
+    ]
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    for c in sorted(set(chunks)):
+        if c != k:
+            specs.append((
+                f"{name}:take_c{c}", ST._lde_block_cols_take_p,
+                (sdsp(k, n), i32, c),
+            ))
+        specs += [
+            (f"{name}:{nm}", fn, args)
+            for nm, fn, args in LN.plane_ntt_kernel_specs(
+                c, n.bit_length() - 1, L, mono=False
+            )
+        ]
+    return specs
 
 
 def deep_round5_prep_p(
@@ -870,7 +1026,7 @@ def deep_round5_prep_p(
     ab_off = 2 + 2 * num_partials
     s2_idxs = [0, 1] + [ab_off + j for j in range(2 * num_lk)]
     if isinstance(s2_lde_flat_p, MonomialPlanesSource):
-        s2_cols = _cols_from_mono_p(s2_mono_p, tuple(s2_idxs), L)
+        s2_cols = cols_from_mono_p(s2_mono_p, tuple(s2_idxs), L)
     else:
         with _transfer.upload("deep_prep", 8 * len(s2_idxs)):
             sel = jnp.asarray(np.array(s2_idxs))
@@ -883,7 +1039,7 @@ def deep_round5_prep_p(
     if num_pi:
         pi_cols_idx = [c_ for (c_, _r, _v) in assembly.public_inputs]
         if isinstance(wit_lde_all_p, MonomialPlanesSource):
-            cols_pi = _cols_from_mono_p(wit_mono_p, tuple(pi_cols_idx), L)
+            cols_pi = cols_from_mono_p(wit_mono_p, tuple(pi_cols_idx), L)
         else:
             with _transfer.upload("deep_prep", 8 * num_pi):
                 sel = jnp.asarray(np.array(pi_cols_idx))
@@ -944,11 +1100,22 @@ def _pi_denom_sub_jit(xs_lde_p, pi_points_p):
 # ---------------------------------------------------------------------------
 
 
+def streamed_commit_layers_p(mono_p, L: int, cap: int):
+    """The tree layers of a commit streamed from monomial planes: the
+    rate-L storage is never made (prover/streaming.py)."""
+    from ..merkle import node_layers_planes
+    from .streaming import streamed_leaf_digests_blocks_p
+
+    with _span("stream.commit", columns=int(mono_p[0].shape[0])):
+        digests = streamed_leaf_digests_blocks_p(mono_p, L)
+        _metrics.count("merkle.streamed_commits")
+        return node_layers_planes(digests, cap)
+
+
 def commit_pipeline_p(values_p, L: int, cap: int, stream: bool, sm_mesh=None):
     """Plane twin of prover._commit_pipeline: values over H (B, n) planes
     -> (mono planes, lde planes | None, plane tree layers)."""
-    from ..merkle import commit_layers_planes, node_layers_planes
-    from .streaming import streamed_leaf_digests_blocks_p
+    from ..merkle import commit_layers_planes
 
     if sm_mesh is not None:
         from ..parallel.shard_sweep import commit_pipeline_sm_p
@@ -960,10 +1127,8 @@ def commit_pipeline_p(values_p, L: int, cap: int, stream: bool, sm_mesh=None):
         _metrics.count("ntt.monomial_from_values")
         _metrics.count("ntt.resident_transforms")
         if stream:
-            digests = streamed_leaf_digests_blocks_p(mono, L)
-            _metrics.count("merkle.streamed_commits")
             _metrics.count("merkle.resident_commits")
-            return mono, None, node_layers_planes(digests, cap)
+            return mono, None, streamed_commit_layers_p(mono, L, cap)
         lde = LN.lde_from_monomial_p(mono, L)
         _metrics.count("ntt.lde_from_monomial")
         _metrics.count("merkle.commits")

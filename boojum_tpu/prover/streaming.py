@@ -2,30 +2,56 @@
 storages.
 
 The reference's long-trace posture is cache-friendly blocked processing
-(SURVEY §5); on an accelerator the binding constraint is HBM: at 2^20 rows
-the materialized rate-L storages (witness + setup + stage-2 + quotient)
-exceed the chip even at the Era commit rate. This module streams them in
-column blocks straight from the (always-resident) monomials:
+(SURVEY §5); on an accelerator the binding constraint is HBM: at 2^19 rows
+of the Era geometry the materialized rate-L storages (witness + setup +
+stage-2 + quotient, 399 columns x 2^20 x 8 B = 3.35 GB) and round 3's
+working sets beside them exceed the chip.
 
-- commit: blocks of <= 64 columns LDE-transform, transpose to rows, and
-  absorb 8 columns at a time into a CARRIED sponge state (N, 12) — the
-  digest stream feeds `MerkleTreeWithCap.from_digests`, so the full
-  (N, total_cols) leaf matrix never exists. Absorption order equals
-  `leaf_hash` over whole rows, so trees (and proofs) are BIT-IDENTICAL to
-  the materialized path.
-- DEEP / query gathers: the same block generator re-evaluates each column
-  block at query time (one extra LDE pass each — FLOPs traded for the
-  ~4 GB of residency the materialized path pins).
+What streams, and what stays resident. The monomials of every oracle stay
+on the device through a prove (they are what rounds 3-5 evaluate from).
+The commits of ONE prove stream together, witness, stage 2 and quotient
+(`merkle.streamed_commits` 3): their rate-L storages are never made.
+The setup oracle is decided ALONE, by `generate_setup`
+(`use_streamed_lde(B_setup, N)`): under the threshold it is committed
+materialized and stays resident, beside streamed proves too (a MIXED prove:
+`keccak256-era-512k`, 1.40 GB of setup storage under 2.1 GB), and round 3
+reads its committed cosets (`prover.coset_is_committed`) while the streamed
+groups transform on every coset.
 
-Streaming engages when the committed-storage footprint would exceed an
-eighth of the device's memory as its allocator reports it (1.5 GiB where
-the backend reports no limit, and never less); BOOJUM_TPU_STREAM_LDE
-overrides the choice ("1" forces on, "0" off, a number is a byte
-threshold) — small traces keep the materialized fast path.
+- commit: blocks of COL_BLOCK columns LDE-transform and absorb 8 columns
+  at a time into a CARRIED sponge state of 12 words a leaf — the
+  digest stream feeds the node stack, so the full (N, total_cols) leaf
+  matrix never exists. Absorption order equals `leaf_hash` over whole rows,
+  so trees (and proofs) are BIT-IDENTICAL to the materialized path.
+- DEEP / query gathers: the same blocks are re-evaluated from the monomials
+  (one extra LDE pass each, `stream.regen_columns` — FLOPs traded for the
+  residency the materialized path pins), and round 5's few single columns
+  once more (`cols_from_mono`).
+
+On limb planes every streamed routine is host-driven, at every size: the
+forward NTT above 2^16 rows is a device program of its own that no jit may
+hold (`limb_ntt._hybrid_fwd_p`), so the host dispatches a block as a cut
+(`_lde_block_cols_take_p`), one forward transform a chunk of
+`lde_from_monomial_p`'s walk (16 columns at 2^19 rows) and their join
+(`_lde_block_cols_join_p`); below that size the same three steps run with
+the size's own transform. The commit is double-buffered, and every loop over
+blocks keeps at most BLOCK_LEAD of them in flight (`BlockLead`: dispatch
+allocates, and the host runs ahead). On planes the block and the carried
+sponge state are column-major, (b, N) and (12, N): leaves along the lanes,
+as the transform leaves them and the permutation kernel takes them
+(`_absorb_cols_cm_p`); the shard_map commit keeps the row-major
+`_absorb_cols_p` for its pivoted blocks.
+
+The threshold. Streaming engages when the committed-storage footprint would
+exceed an eighth of the device's memory as its allocator reports it (2.1 GB
+on a v5e; 1.5 GiB where the backend reports no limit, and never less);
+BOOJUM_TPU_STREAM_LDE overrides the choice ("1" forces on, "0" off, a number
+is a byte threshold) — small traces keep the materialized fast path.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 
@@ -84,6 +110,54 @@ def use_streamed_lde(total_cols: int, domain_size: int) -> bool:
     return total_cols * domain_size * 8 > stream_threshold_bytes()
 
 
+# Blocks of a streamed loop the host may have in flight. Dispatch allocates:
+# a host that runs ahead of the device (it always does: a block is a dozen
+# launches, 3 ms of host time, and 10 to 130 ms of device time) would hold
+# every block of an oracle at once, 155 columns' transforms and their
+# temporaries at 2^19 rows, which is the storage streaming exists not to
+# hold. A fixed number, so that the peak does not follow the host's pace.
+BLOCK_LEAD = 2
+
+
+class BlockLead:
+    """Holds a streamed loop to BLOCK_LEAD blocks in flight: `done(x)` is
+    told what each block produced, in order, and waits for the block
+    BLOCK_LEAD back (one `host.sync` span, `d2h.stream_block`, and one
+    tick of `host.blocking_syncs` a wait). The device still has the blocks
+    between queued when the host wakes, so it does not idle."""
+
+    def __init__(self):
+        self._produced = []
+
+    def done(self, x):
+        from ..utils import metrics as _metrics
+        from ..utils import transfer as _transfer
+
+        self._produced.append(x)
+        if len(self._produced) > BLOCK_LEAD:
+            _metrics.count("host.blocking_syncs")
+            with _transfer.sync("stream_block"):
+                jax.block_until_ready(self._produced.pop(0))
+
+
+def count_lde_columns(phase: str, columns: int):
+    """`stream.lde_columns.<phase>`: columns evaluated from monomials at
+    rate L because no storage holds them, counted where the host
+    dispatches the transforms (`commit`, `deep`, `queries`); the two
+    regenerations also add up in `stream.regen_columns`."""
+    from ..utils import metrics as _metrics
+
+    _metrics.count(f"stream.lde_columns.{phase}", columns)
+    if phase != "commit":
+        _metrics.count("stream.regen_columns", columns)
+
+
+def _block_span(name: str | None, columns: int):
+    from ..utils.spans import span as _span
+
+    return _span(name, columns=columns) if name else contextlib.nullcontext()
+
+
 class MonomialSource:
     """A committed oracle's columns, represented by monomials + rate.
 
@@ -98,10 +172,13 @@ class MonomialSource:
     def shape(self):
         return (self.mono.shape[0], self.mono.shape[-1] * self.L)
 
-    def blocks(self, per: int = COL_BLOCK):
+    def blocks(self, per: int = COL_BLOCK, span: str | None = None):
+        """`span` names a span opened around each block's dispatch (never
+        across the `yield`: the consumer's work is not the block's)."""
         B = self.mono.shape[0]
         for i in range(0, B, per):
-            lde = lde_from_monomial(self.mono[i : i + per], self.L)
+            with _block_span(span, min(per, B - i)):
+                lde = lde_from_monomial(self.mono[i : i + per], self.L)
             yield i, lde.reshape(lde.shape[0], -1)  # (b, N)
 
     def column(self, i: int):
@@ -175,6 +252,7 @@ def streamed_leaf_digests_blocks(mono, L: int):
 
     def _lde(i):
         b = min(COL_BLOCK, B - i)
+        count_lde_columns("commit", b)
         blk = jax.lax.dynamic_slice_in_dim(mono, i, b, axis=0)
         return _lde_block_cols(blk, L)
 
@@ -199,6 +277,7 @@ def double_buffered_absorb(state, starts, produce_cols, absorb=None):
     if absorb is None:
         absorb = _absorb_cols
     starts = list(starts)
+    lead = BlockLead()
     nxt = produce_cols(starts[0])
     for k in range(len(starts)):
         cols, nxt = nxt, (
@@ -206,6 +285,7 @@ def double_buffered_absorb(state, starts, produce_cols, absorb=None):
         )
         _metrics.count("stream.double_buffered_blocks")
         state = absorb(state, cols)
+        lead.done(state)
     return state
 
 
@@ -249,7 +329,11 @@ def _absorb_cols(state, cols):
 
 class MonomialPlanesSource:
     """MonomialSource twin over plane monomials: stands in for a resident
-    oracle's materialized (B, L*n) plane pair in the DEEP/query phases."""
+    oracle's materialized (B, L*n) plane pair in the DEEP/query phases.
+
+    A block is `lde_rows_p`'s two dispatches, made from the host: no jit
+    may hold the forward transform above 2^16 rows
+    (limb_ntt._hybrid_fwd_p), and one form serves every size."""
 
     def __init__(self, mono_p, L: int):
         self.mono = mono_p
@@ -259,32 +343,72 @@ class MonomialPlanesSource:
     def shape(self):
         return (self.mono[0].shape[0], self.mono[0].shape[-1] * self.L)
 
-    def blocks(self, per: int = COL_BLOCK):
-        from ..ntt.limb_ntt import lde_from_monomial_p
-
-        B = self.mono[0].shape[0]
-        for i in range(0, B, per):
-            blk = (self.mono[0][i : i + per], self.mono[1][i : i + per])
-            lde = lde_from_monomial_p(blk, self.L)
-            b = lde[0].shape[0]
+    def blocks(self, span: str | None = None):
+        """(first column, (b, N) planes) over the whole oracle; `span` as
+        `MonomialSource.blocks` takes it."""
+        B, n = self.mono[0].shape
+        lead = BlockLead()  # bounded like the commit
+        i = 0
+        for b in block_chunk_sizes(B, n, self.L):
+            with _block_span(span, b):
+                lde = lde_rows_p(self.mono, i, b, self.L)
+                lead.done(lde)
             yield i, (lde[0].reshape(b, -1), lde[1].reshape(b, -1))
-
-    def column(self, i: int):
-        from ..ntt.limb_ntt import lde_from_monomial_p
-
-        blk = (self.mono[0][i : i + 1], self.mono[1][i : i + 1])
-        lde = lde_from_monomial_p(blk, self.L)
-        return lde[0].reshape(-1), lde[1].reshape(-1)
+            i += b
 
     def gather_rows(self, idx_dev):
-        parts = [
-            (flat[0][:, idx_dev], flat[1][:, idx_dev])
-            for _, flat in self.blocks()
-        ]
-        return (
-            jnp.concatenate([p[0] for p in parts], axis=0),
-            jnp.concatenate([p[1] for p in parts], axis=0),
-        )
+        """(B, num_queries) leaf-value planes, blockwise."""
+        return _stream_gather_join_p(tuple(
+            _stream_gather_block_p(flat, idx_dev) for _, flat in self.blocks()
+        ))
+
+
+def block_chunk_sizes(B: int, n: int, L: int) -> list[int]:
+    """Column counts of the blocks a streamed oracle of B columns is
+    regenerated in: COL_BLOCK columns, each in the chunks
+    `lde_from_monomial_p` walks, one forward dispatch each (at most 128 MiB
+    of output: 16 columns at 2^19 rows under L = 2)."""
+    from ..ntt.limb_ntt import lde_chunk_sizes
+
+    return [
+        c
+        for i in range(0, B, COL_BLOCK)
+        for c in lde_chunk_sizes(min(COL_BLOCK, B - i), n, L)
+    ]
+
+
+@_partial(jax.jit, static_argnums=(2,))
+def _lde_block_cols_take_p(mono_p, start, b: int):
+    """Rows [start, start + b) of a monomial stack, `start` a device
+    scalar: the input of one forward dispatch."""
+    return tuple(
+        jax.lax.dynamic_slice_in_dim(a, start, b, axis=0) for a in mono_p
+    )
+
+
+def lde_rows_p(mono_p, start: int, b: int, L: int):
+    """(b, L, n) rate-L planes of columns [start, start + b) of `mono_p`:
+    the cut, then ONE forward dispatch (b is a chunk of `lde_chunk_sizes`),
+    each keyed on the chunk alone, so that every streamed oracle shares
+    them in the commit, in DEEP and in the queries."""
+    from ..ntt.limb_ntt import lde_from_monomial_p
+
+    if b != mono_p[0].shape[0]:
+        mono_p = _lde_block_cols_take_p(mono_p, jnp.int32(start), b)
+    return lde_from_monomial_p(mono_p, L)
+
+
+@jax.jit
+def _stream_gather_block_p(flat_p, idx_dev):
+    return flat_p[0][:, idx_dev], flat_p[1][:, idx_dev]
+
+
+@jax.jit
+def _stream_gather_join_p(parts):
+    return (
+        jnp.concatenate([p[0] for p in parts], axis=0),
+        jnp.concatenate([p[1] for p in parts], axis=0),
+    )
 
 
 @jax.jit
@@ -320,42 +444,83 @@ def _absorb_cols_p(state_p, cols_p):
     return state_p
 
 
-@_partial(jax.jit, static_argnums=(1,))
-def _lde_block_cols_p(mono_blk_p, L: int):
-    """Plane twin of _lde_block_cols: (b, n) monomial planes ->
-    (N, b) leaf-column planes."""
-    from ..ntt.limb_ntt import lde_from_monomial_p
+@jax.jit
+def _lde_block_cols_join_p(chunks):
+    """The chunks of one column block, each (c, L, n) planes, as the
+    block's (b, N) leaf-column planes, COLUMN-MAJOR (leaves along the
+    lanes, as `_absorb_cols_cm_p` takes them)."""
+    return tuple(
+        jnp.concatenate(
+            [c[k].reshape(c[k].shape[0], -1) for c in chunks], axis=0
+        )
+        for k in (0, 1)
+    )
 
-    b = mono_blk_p[0].shape[0]
-    lde = lde_from_monomial_p(mono_blk_p, L)
-    return lde[0].reshape(b, -1).T, lde[1].reshape(b, -1).T
+
+def lde_block_cols_p(mono_p, start: int, b: int, L: int):
+    """(b, N) leaf-column planes of columns [start, start + b) of the
+    monomial stack: the host dispatches the transform of each chunk
+    (`lde_rows_p`) and one program joins them."""
+    from ..ntt.limb_ntt import lde_chunk_sizes
+
+    n = mono_p[0].shape[-1]
+    chunks, i = [], start
+    for c in lde_chunk_sizes(b, n, L):
+        chunks.append(lde_rows_p(mono_p, i, c, L))
+        i += c
+    return _lde_block_cols_join_p(tuple(chunks))
+
+
+@jax.jit
+def _absorb_cols_cm_p(state_p, cols_p):
+    """`_absorb_cols_p` on COLUMN-MAJOR planes: the carried state is
+    (12, N) and the block (b, N), leaves along the lanes. That is how the
+    transform leaves a block and how the permutation kernel takes its
+    state, so nothing is transposed; and an (N, 12) or (N, 32) array on the
+    TPU is held in (8, 128) tiles padded out to 128 lanes, 11 and 4 times
+    its bytes, at 2^20 leaves a GB a state and a GB a block in flight.
+    Same chunks, same finalize rule, same digests."""
+    from ..hashes.poseidon2 import poseidon2_permutation_planes_cm
+
+    b, N = cols_p[0].shape
+    for k in range(0, b, 8):
+        rows = min(8, b - k)
+        pad = jnp.zeros((8 - rows, N), jnp.uint32)
+        state_p = poseidon2_permutation_planes_cm(tuple(
+            jnp.concatenate([c[k : k + rows], pad, s[8:]], axis=0)
+            for c, s in zip(cols_p, state_p)
+        ))
+    return state_p
+
+
+@jax.jit
+def _absorb_cols_digests_p(state_p):
+    """The carried (12, N) state's first four words as the (N, 4) digest
+    planes the node stack takes."""
+    return state_p[0][:4].T, state_p[1][:4].T
 
 
 def streamed_leaf_digests_blocks_p(mono_p, L: int):
     """Plane twin of streamed_leaf_digests_blocks: (N, 4) digest planes,
-    double-buffered exactly like the u64 form."""
+    double-buffered exactly like the u64 form, on column-major planes
+    (`_absorb_cols_cm_p`)."""
     assert COL_BLOCK % 8 == 0
     n = mono_p[0].shape[-1]
     B = mono_p[0].shape[0]
     state = (
-        jnp.zeros((n * L, 12), jnp.uint32),
-        jnp.zeros((n * L, 12), jnp.uint32),
+        jnp.zeros((12, n * L), jnp.uint32),
+        jnp.zeros((12, n * L), jnp.uint32),
     )
 
-    def _blk(i):
+    def _block(i):
         b = min(COL_BLOCK, B - i)
-        return (
-            jax.lax.dynamic_slice_in_dim(mono_p[0], i, b, axis=0),
-            jax.lax.dynamic_slice_in_dim(mono_p[1], i, b, axis=0),
-        )
+        count_lde_columns("commit", b)
+        return lde_block_cols_p(mono_p, i, b, L)
 
     state = double_buffered_absorb(
-        state,
-        range(0, B, COL_BLOCK),
-        lambda i: _lde_block_cols_p(_blk(i), L),
-        absorb=_absorb_cols_p,
+        state, range(0, B, COL_BLOCK), _block, absorb=_absorb_cols_cm_p
     )
-    return state[0][:, :4], state[1][:, :4]
+    return _absorb_cols_digests_p(state)
 
 
 def commit_streaming(mono, L: int, cap_size: int) -> MerkleTreeWithCap:
@@ -371,7 +536,8 @@ def deep_source_blocks(sources, per_bytes: int):
     off = 0
     for src in sources:
         if isinstance(src, MonomialSource):
-            for i, flat in src.blocks():
+            count_lde_columns("deep", src.shape[0])
+            for i, flat in src.blocks(span="stream.deep_regen"):
                 yield flat, off + i
             off += src.shape[0]
         else:
