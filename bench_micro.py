@@ -12,7 +12,9 @@ a reading is the chip's time and not the host's per-launch overhead.
 Usage: python bench_micro.py  (JSON lines on stdout; backend = ambient JAX)
        python bench_micro.py poseidon2  (the Poseidon2 section alone)
        python bench_micro.py binv       (the batch-inversion section alone)
-       python bench_micro.py ntt        (the forward NTT above 2^16 rows alone)
+       python bench_micro.py ntt        (the transforms above 2^16 rows alone:
+                                         the forward, then the commits' inverse)
+       python bench_micro.py ntt inverse [log_n ..] (the commits' inverse alone)
        python bench_micro.py transcript (the host permutation under the transcript)
 """
 
@@ -243,6 +245,95 @@ def ntt_section(backend, log_n=18):
             f"ntt_forward_{name}_staged_over_fused",
             round((dt_scale + dt_outer + dt_mxu) / dt_fused, 3), "x", **line,
         )
+
+
+# the commits' chunks (PERF.md section 5): a whole chunk and the tree
+# cell's remainder at 2^18 rows, a whole chunk and Keccak's remainder at
+# 2^19; and 64 columns at 2^16 rows, where the commits keep the XLA stages
+NTT_INVERSE_SHAPES = ((64, 18), (2, 18), (32, 19), (27, 19), (64, 16))
+NTT_STEP_DEADLINE_S = 300  # a stalled program costs minutes, not the call
+
+
+def ntt_inverse_section(backend, log_ns=()):
+    """The commits' inverse transform on limb planes, a chunk at a time.
+    Above 2^16 rows: the matmul kernel on the values as they lie
+    (`limb_ntt._imono_kernel_p`: fused, and the trailing stage at 2^19)
+    beside the XLA stages it replaced (`_imono_p_jit`) and beside the form
+    the u64 path has (a bit-reversal gather, the per-block inverse kernels
+    and the outer DIT stages, three programs rebuilt here from the pieces
+    the library keeps). At 2^16 rows, where the commits dispatch the XLA
+    stages: those beside the gather and the one inverse kernel of that
+    size, so that a later PR knows what the XLA form costs there. All held
+    equal to `_imono_p_jit` to the bit. Every step runs under a deadline
+    that dumps the stacks and exits. `log_ns`: those sizes alone."""
+    if backend != "tpu":
+        return  # the matmul kernel is native on the TPU alone
+    import faulthandler
+
+    from boojum_tpu.ntt import limb_ntt as LN
+    from boojum_tpu.ntt import mxu_ntt
+
+    def step(fn, args, reps=5):
+        faulthandler.dump_traceback_later(NTT_STEP_DEADLINE_S, exit=True)
+        dt = timed_call(fn, args, reps=reps)
+        faulthandler.cancel_dump_traceback_later()
+        return dt
+
+    @jax.jit
+    def brev_p(p):
+        brev = LN.PlaneNTTContext(p[0].shape[-1].bit_length() - 1).brev
+        return p[0][..., brev], p[1][..., brev]
+
+    blocks_p = jax.jit(LN.hybrid_inv_kernels_p, static_argnums=(1,))
+    outer_p = jax.jit(LN.hybrid_inv_outer_p, static_argnums=(1,))
+    rng = np.random.default_rng(61)
+
+    def planes(*shape):
+        return tuple(
+            jnp.asarray(rng.integers(0, hi, shape, dtype=np.uint32))
+            for hi in (1 << 32, (1 << 32) - 1)  # canonical: hi < 2^32 - 1
+        )
+
+    def equal(a, b):
+        return all(bool(jnp.array_equal(x, y)) for x, y in zip(a, b))
+
+    for cols, log_n in NTT_INVERSE_SHAPES:
+        if log_ns and log_n not in log_ns:
+            continue
+        n = 1 << log_n
+        p = planes(cols, n)
+        line = dict(shape=[cols, n], backend=backend)
+        parts = {"xla_stages": step(LN._imono_p_jit, (p,), reps=2)}
+        want = LN._imono_p_jit(p)
+        if LN.inverse_is_own_program(n):
+            parts["kernel"] = step(LN._imono_kernel_p, (p,))
+            fused = LN._imono_p_fused(p, None, None)
+            parts["kernel_fused"] = step(LN._imono_p_fused, (p, None, None))
+            if mxu_ntt.leading_outer_stages(log_n):
+                parts["kernel_trailing"] = step(LN._imono_p_trailing, (fused,))
+            del fused
+            line["equal_to_xla_stages"] = equal(LN._imono_kernel_p(p), want)
+        reversed_p = brev_p(p)
+        parts["gather"] = step(brev_p, (p,))
+        got = blocks_p(reversed_p, log_n)
+        parts["gather_blocks"] = step(blocks_p, (reversed_p, log_n))
+        if log_n > mxu_ntt.MAX_LOG_N:
+            parts["gather_outer"] = step(outer_p, (got, log_n))
+            got = outer_p(got, log_n)
+        line["gather_form_equal"] = equal(got, want)
+        del reversed_p, got, want
+        if not line.get("equal_to_xla_stages", True):
+            raise SystemExit(f"the kernel's monomials differ at {line}")
+        for part, dt in parts.items():
+            emit(
+                f"ntt_inverse_{part}_ms", round(dt * 1e3, 3), "ms",
+                us_per_transform=round(dt * 1e6 / cols, 2), **line,
+            )
+        if "kernel" in parts:
+            emit(
+                "ntt_inverse_xla_stages_over_kernel",
+                round(parts["xla_stages"] / parts["kernel"], 3), "x", **line,
+            )
 
 
 P2_TILES = (8, 16, 32, 64, 128, 256)
@@ -891,5 +982,8 @@ if __name__ == "__main__":
         batch_inverse_section(jax.default_backend())
     elif sys.argv[1:] == ["ntt"]:
         ntt_section(jax.default_backend())
+        ntt_inverse_section(jax.default_backend())
+    elif sys.argv[1:3] == ["ntt", "inverse"]:
+        ntt_inverse_section(jax.default_backend(), [int(a) for a in sys.argv[3:]])
     else:
         main()

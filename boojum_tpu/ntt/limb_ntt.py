@@ -481,20 +481,127 @@ def lde_from_monomial_p(
     )
 
 
+def inverse_is_own_program(n: int) -> bool:
+    """True where the inverse transform of n values in natural order is the
+    matmul kernel's (`_imono_kernel_p`: above 2^MAX_LOG_N rows, as far as
+    the kernel's radix stage and one trailing stage reach)."""
+    from . import mxu_ntt
+
+    n = int(n)
+    log_n = n.bit_length() - 1
+    return (
+        _mxu_ntt_ready(n, None)
+        and log_n > mxu_ntt.MAX_LOG_N
+        and mxu_ntt.leading_outer_stages(log_n) <= mxu_ntt.MAX_TRAILING_OUTER
+    )
+
+
+@partial(jax.jit, static_argnums=(2,))
+def _imono_p_fused(p, start, size):
+    """Columns [start, start + size) of the (B, n) values `p` (`start` a
+    device scalar; None: all of `p`) through the inverse matmul kernel,
+    whose radix stage is the last `fused_outer_stages(log_n)` outer stages
+    -> their monomials, (size, n); above 2^(MAX_LOG_N + 2) rows the
+    kernel's own (size, 2^trailing, R, 2^k C), for `_imono_p_trailing`."""
+    from . import mxu_ntt
+
+    if start is not None:
+        p = tuple(jax.lax.dynamic_slice_in_dim(a, start, size, 0) for a in p)
+    ctx = mxu_ntt.get_mxu_ctx(mxu_ntt.MAX_LOG_N)
+    b, n = p[0].shape
+    log_n = n.bit_length() - 1
+    trailing = mxu_ntt.leading_outer_stages(log_n)
+    out = mxu_ntt._inv_radix_planes(
+        tuple(a.reshape(b, ctx.C, -1) for a in p),
+        mxu_ntt.fused_outer_stages(log_n), trailing, False,
+    )
+    return out if trailing else tuple(a.reshape(b, n) for a in out)
+
+
+@jax.jit
+def _imono_p_trailing(parts):
+    """The last outer DIT stage, over the monomials of a column's even and
+    odd values as `_imono_p_fused` leaves them, (b, 2, R, 2^k C): both in
+    natural order, so the stage is elementwise. A program of its own:
+    XLA stages and a matmul kernel in one program have stalled on the
+    v5e (PERF.md, Open question 10)."""
+    b, _two, rows, cols = parts[0].shape
+    n = 2 * rows * cols
+    itw = PlaneNTTContext(n.bit_length() - 1).itw
+    even = tuple(a[:, 0] for a in parts)
+    odd = limbs.mul(
+        tuple(a[:, 1] for a in parts),
+        tuple(w.reshape(rows, cols) for w in itw),
+    )
+    top, bot = limbs.add(even, odd), limbs.sub(even, odd)
+    return tuple(
+        jnp.stack([t, u], axis=1).reshape(b, n) for t, u in zip(top, bot)
+    )
+
+
+def _imono_kernel_p(p, start=None, size=None):
+    """One chunk of `monomial_from_values_p` where the inverse is the
+    kernel's: ONE dispatch, and the trailing stage's above 2^18 rows."""
+    from . import mxu_ntt
+
+    log_n = int(p[0].shape[-1]).bit_length() - 1
+    out = _imono_p_fused(p, start, size)
+    if mxu_ntt.leading_outer_stages(log_n):
+        out = _imono_p_trailing(out)
+    _count_inverse_stages(log_n, out[0].shape[0])
+    return out
+
+
+def _count_inverse_stages(log_n: int, transforms: int):
+    """`ntt.fused_inverse_stages`: the outer radix-2 stages that the
+    kernel's radix stage took in one dispatch of the commits' inverse
+    transform, times the column transforms that went through the kernel
+    (none where the XLA form ran: up to 2^MAX_LOG_N rows and off the TPU);
+    its twin `ntt.trailing_outer_stages`: those of `_imono_p_trailing`."""
+    from .mxu_ntt import fused_outer_stages, leading_outer_stages
+
+    _metrics.count(
+        "ntt.fused_inverse_stages", fused_outer_stages(log_n) * transforms
+    )
+    _metrics.count(
+        "ntt.trailing_outer_stages", leading_outer_stages(log_n) * transforms
+    )
+
+
+def imono_chunks(B: int, n: int) -> dict:
+    """{first column: columns} of the chunks `monomial_from_values_p`
+    walks over a (B, n) stack."""
+    per = _col_chunks(B, n * 8) or B
+    return {i: min(per, B - i) for i in range(0, B, per)}
+
+
 def monomial_from_values_p(p):
-    """Values over H -> monomial coefficients, on planes (chunked)."""
+    """Values over H -> monomial coefficients, on planes (chunked). Above
+    2^MAX_LOG_N rows, where the MXU transforms are native, a chunk is the
+    inverse matmul kernel on the values as they lie (`_imono_kernel_p`);
+    elsewhere, and under a tracer, the XLA stages of `_imono_p_jit`."""
     lo, hi = p
     if lo.ndim < 2:
         return _imono_p_jit(p)
-    B = lo.shape[0]
-    per = _col_chunks(B, lo.size // B * 8)
-    if per is None:
-        return _imono_p_jit(p)
-    return _assemble_chunks_p(
-        lo.shape,
-        lambda i: _imono_p_jit((lo[i : i + per], hi[i : i + per])),
-        range(0, B, per),
-    )
+    B, n = lo.shape[0], lo.shape[-1]
+    chunks = imono_chunks(B, lo.size // B)
+    traced = isinstance(lo, jax.core.Tracer)
+    kernel = lo.ndim == 2 and not traced and inverse_is_own_program(n)
+    if not (kernel or traced):  # present and 0: nothing went to the kernel
+        _count_inverse_stages(n.bit_length() - 1, 0)
+
+    def produce(i):
+        size = chunks[i]
+        if kernel:
+            cut = (None, None) if size == B else (jnp.int32(i), size)
+            return _imono_kernel_p(p, *cut)
+        return _imono_p_jit(
+            p if size == B else (lo[i : i + size], hi[i : i + size])
+        )
+
+    if len(chunks) == 1:
+        return produce(0)
+    return _assemble_chunks_p(lo.shape, produce, chunks)
 
 
 # ---------------------------------------------------------------------------
@@ -525,6 +632,31 @@ def hybrid_fwd_kernel_specs(name: str, call: tuple, log_n: int,
     ]
 
 
+def imono_kernel_specs(B: int, log_n: int) -> list:
+    """What `monomial_from_values_p` dispatches for a (B, 2^log_n) stack
+    where the inverse is the kernel's: for each size of chunk the fused
+    program (the chunk's first column is a device scalar, so chunks of one
+    size share it) and, above 2^(MAX_LOG_N + 2) rows, the trailing stage
+    on what it returns."""
+    from . import mxu_ntt
+
+    n = 1 << log_n
+    ctx = mxu_ntt.get_mxu_ctx(mxu_ntt.MAX_LOG_N)
+    k = mxu_ntt.fused_outer_stages(log_n)
+    trailing = mxu_ntt.leading_outer_stages(log_n)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    sizes = set(imono_chunks(B, n).values())
+    specs = []
+    for b in sorted(sizes):
+        call = (sdsp(B, n), None, None) if b == B else (sdsp(B, n), i32, b)
+        name = f"imono_kernel_limbres_b{b}_n{n}"
+        specs.append((f"{name}:fused", _imono_p_fused, call))
+        if trailing:
+            staged = sdsp(b, 1 << trailing, ctx.R, ctx.C << k)
+            specs.append((f"{name}:trailing", _imono_p_trailing, (staged,)))
+    return specs
+
+
 def plane_ntt_kernel_specs(B: int, log_n: int, lde_factor: int | None = None,
                            coset: int = int(gl.MULTIPLICATIVE_GENERATOR),
                            mono: bool = True) -> list:
@@ -535,7 +667,9 @@ def plane_ntt_kernel_specs(B: int, log_n: int, lde_factor: int | None = None,
 
     n = 1 << log_n
     specs = []
-    if mono:
+    if mono and inverse_is_own_program(n):
+        specs += imono_kernel_specs(B, log_n)
+    elif mono:
         specs += [
             (f"imono_limbres_b{b}_n{n}", _imono_p_jit, (sdsp(b, n),))
             for b in chunk_shapes(B, n * 8)

@@ -41,7 +41,7 @@ constant bias contribution is subtracted at the end.
 Sizes 2^14..2^16 run as single fused kernels; 2^17..2^22 run the leading
 (resp. trailing) radix-2 stages in XLA and drop bit-exactly into per-block
 2^16 kernels (DIF stage s only combines elements 2^16 apart for s < log_n-16);
-the forward on limb planes takes its last two into the kernel (end of file).
+on limb planes the forward and the commits' inverse take two into the kernel.
 
 Outputs are bit-identical to the staged-XLA path (`ntt.py`): same twiddle
 constants, exact integer arithmetic, canonical representatives.
@@ -806,3 +806,198 @@ def _fwd_radix_planes(planes, scale_planes, k: int, interpret: bool):
         interpret=interpret,
         compiler_params=None if interpret else _COMPILER_PARAMS,
     )(ctx.dr, ctx.dct, *ctx.tw, *tables, *(scale_planes or ()), lo, hi)
+
+
+# ---------------------------------------------------------------------------
+# 2^17 .. 2^19 rows inverse, NATURAL order in and out, as ONE kernel: the
+# outer radix-2 DIT stages are a radix-2^k stage of the matmul kernel (PR 40)
+# ---------------------------------------------------------------------------
+# A column of h * 2^k * R * C values v[t] (h = 2^trailing) is h decimated
+# sub-columns u_e[t'] = v[h t' + e] of n' = 2^k R C values; with G = 2^k
+#
+#   t' = tc (G R) + g R + tr      tc < C, g < G, tr < R
+#   j' = jr (G C) + q C + jc      jr < R, q < G, jc < C
+#   w^(-t' j') = wC^(-tc jc) . w^(-R g jc) . wG^(-g q) . w^(-tr (q C + jc))
+#                . wR^(-tr jr)                      (w = omega of n' values)
+#
+# so the sub-column's inverse is: a product with the PLAIN C x C inverse DFT
+# matrix over tc, the table w^(-R g jc), a radix-G butterfly across g, the
+# table w^(-tr (q C + jc)), and a product with the plain R x R matrix over
+# tr (n^-1 folded in). v.reshape(C, G R h) is [tc][g R h + tr h + e]: the G
+# parts are LANE blocks of the column as it lies in memory, the first
+# product is a left-multiply at N = G R h, and the values reach the second
+# as [tr][q C + jc] through one 2-D transpose a part (rows tr h + e: the h
+# sub-columns are strided row reads). Its result (R, G C) is the natural
+# order of the monomials. No bit reversal anywhere: the digit reversal of
+# a natural-order transform is that one transpose. The h sub-columns'
+# results meet in `limb_ntt`'s trailing stage, a program of its own.
+
+# `leading_outer_stages(log_n)` counts, for this inverse, the outer DIT
+# stages that the radix stage does not take and an XLA program runs AFTER
+# the kernel: sub-columns a grid step holds, 2 at most (VMEM).
+MAX_TRAILING_OUTER = 1
+# Rows of a part a step of the radix stage holds: (64, 2^18) in 7.08 ms at
+# 8, 7.04 at 16, 7.05 at 32 (my chip run, PR 40): no difference.
+_RADIX_STAGE_ROWS = 16
+_MATMUL_LANES = 1024  # lanes a product takes at a time (`_TARGET_N`)
+
+
+@lru_cache(maxsize=None)
+def _inv_radix_consts(log_block: int, k: int, trailing: int):
+    """(e, f, tw1, tw2) of the header: the two plain inverse DFT matrices
+    as int8 digit planes (f times the inverse of ALL the rows,
+    2^(log_block + k + trailing)); tw1 (2^k - 1, C, 128): w^(-R m jc) for
+    m = 1 .. 2^k - 1, constant along the lanes; tw2 (2^k, C, R h):
+    w^(-tr (q C + jc)) at [q][jc][tr h + e]."""
+    ctx = get_mxu_ctx(log_block)
+    R, C = ctx.R, ctx.C
+    G, H = 1 << k, 1 << trailing
+    n_sub = G * R * C
+    w = gl.omega(log_block + k)
+    # the radix-4 butterfly multiplies by i = w^(n'/4) with shifts alone
+    assert k < 2 or gl.pow_(w, n_sub // 4) == 1 << 48
+    pows = _pow_table(gl.inv(w), n_sub)
+    jc = np.arange(C, dtype=np.int64)
+    jr = np.arange(R, dtype=np.int64)
+    E = pows[(G * R * jc[:, None] * jc[None, :]) % n_sub]
+    F = gl.mul_np(
+        pows[(G * C * jr[:, None] * jr[None, :]) % n_sub],
+        np.uint64(gl.inv(n_sub << trailing)),
+    )
+    m = np.arange(1, G, dtype=np.int64)
+    tw1 = pows[(R * m[:, None] * jc[None, :]) % n_sub]
+    tw1 = np.broadcast_to(tw1[:, :, None], (G - 1, C, 128))
+    q = np.arange(G, dtype=np.int64)
+    tw2 = pows[
+        (jr[None, None, :] * (q[:, None, None] * C + jc[None, :, None]))
+        % n_sub
+    ]
+    with jax.ensure_compile_time_eval():
+        return (
+            _digits8_np(E), _digits8_np(F), _pair_np(np.ascontiguousarray(tw1)),
+            _pair_np(np.repeat(tw2, H, axis=-1)),
+        )
+
+
+def radix_inverse_stage(z, w1, w2):
+    """The 2^k parts `z` of a column between the inverse's two products:
+    part g times `w1[g - 1]`, the inverse radix-2^k butterfly across the
+    parts, result q times `w2[q]`; k = 1 or 2. Pairs in and out, exact."""
+    z = [z[0]] + [limbs.mul(zi, wi) for zi, wi in zip(z[1:], w1)]
+    if len(z) == 2:
+        a, b = z
+        y = [limbs.add(a, b), limbs.sub(a, b)]
+    else:
+        a, b, c, d = z
+        ac, bd = limbs.add(a, c), limbs.add(b, d)
+        ca, db = limbs.sub(a, c), _mul_i(limbs.sub(b, d))
+        # i^-1 = -i: the forward's butterfly with its odd results swapped
+        y = [
+            limbs.add(ac, bd), limbs.sub(ca, db),
+            limbs.sub(ac, bd), limbs.add(ca, db),
+        ]
+    return [limbs.mul(yi, wi) for yi, wi in zip(y, w2)]
+
+
+def _inv_radix_kernel(ctx, G, H, e, f, t1l, t1h, t2l, t2h, xl, xh, ol, oh,
+                      sl, sh, ul, uh):
+    """One column a grid step. `s` (C, G R H) holds it between the
+    products, `u` its parts' transposes, (G R H, C) in lane tiles."""
+    R, C = ctx.R, ctx.C
+    W = R * H  # lanes of a part
+    L = G * W
+    for first in range(0, L, _MATMUL_LANES):
+        lanes = slice(first, min(first + _MATMUL_LANES, L))
+        y = _gl_matmul((xl[0, :, lanes], xh[0, :, lanes]), e, "left")
+        sl[:, lanes] = y[0]
+        sh[:, lanes] = y[1]
+
+    def part(g):
+        return slice(g * W, (g + 1) * W)
+
+    def step(r, _):
+        # i32 arithmetic: under x64 a bare product is i64, which Mosaic refuses
+        rows = pl.ds(
+            pl.multiple_of(jnp.int32(_RADIX_STAGE_ROWS) * r, _RADIX_STAGE_ROWS),
+            _RADIX_STAGE_ROWS,
+        )
+
+        def lanes_of(t):  # (rows, 128), constant along the lanes -> (rows, W)
+            return jnp.concatenate([t] * (W // 128), axis=1)
+
+        z = [(sl[rows, part(g)], sh[rows, part(g)]) for g in range(G)]
+        w1 = [
+            (lanes_of(t1l[m, rows, :]), lanes_of(t1h[m, rows, :]))
+            for m in range(G - 1)
+        ]
+        w2 = [(t2l[q, rows, :], t2h[q, rows, :]) for q in range(G)]
+        for q, y in enumerate(radix_inverse_stage(z, w1, w2)):
+            sl[rows, part(q)] = y[0]
+            sh[rows, part(q)] = y[1]
+
+    steps = jnp.int32(C // _RADIX_STAGE_ROWS)
+    jax.lax.fori_loop(jnp.int32(0), steps, step, None)
+    # a strided row read wants a base 128 lanes wide: `u` is (C / 128, G R H,
+    # 128), the transposes' lane tiles side by side
+    tiles = range(C // 128)
+    for q in range(G):
+        for s, u in ((sl, ul), (sh, uh)):
+            t = s[:, part(q)].T
+            for c in tiles:
+                u[c, part(q), :] = t[:, c * 128:(c + 1) * 128]
+    for h in range(H):
+        def rows_of(u):  # sub-column h of every part: (R, G C)
+            return jnp.concatenate(
+                [
+                    u[c, pl.ds(q * W + h, R, stride=H), :]
+                    for q in range(G) for c in tiles
+                ],
+                axis=1,
+            )
+
+        z = _gl_matmul((rows_of(ul), rows_of(uh)), f, "left")
+        ol[0, h] = z[0]
+        oh[0, h] = z[1]
+
+
+@partial(jax.jit, static_argnums=(1, 2, 3))
+def _inv_radix_planes(planes, k: int, trailing: int, interpret: bool):
+    """Inverse transforms of B columns of 2^(MAX_LOG_N + k + trailing)
+    values in natural order, `planes` (B, C, 2^(k + trailing) R) as the
+    columns lie in memory -> (B, 2^trailing, R, 2^k C): the monomials of
+    the decimated sub-columns, each in natural order, the 1/n of the whole
+    column in them."""
+    G, H = 1 << k, 1 << trailing
+    ctx = get_mxu_ctx(MAX_LOG_N)
+    R, C = ctx.R, ctx.C
+    lo, hi = planes
+    B, L = lo.shape[0], G * H * R
+    e, f, tw1, tw2 = _inv_radix_consts(MAX_LOG_N, k, trailing)
+    in_spec = pl.BlockSpec(
+        (1, C, L), imap32(lambda b: (b, 0, 0)), memory_space=pltpu.VMEM
+    )
+    out_spec = pl.BlockSpec(
+        (1, H, R, G * C), imap32(lambda b: (b, 0, 0, 0)),
+        memory_space=pltpu.VMEM,
+    )
+    out_shape = jax.ShapeDtypeStruct((B, H, R, G * C), jnp.uint32)
+    return pl.pallas_call(
+        partial(_inv_radix_kernel, ctx, G, H),
+        grid=(B,),
+        out_shape=[out_shape, out_shape],
+        in_specs=[
+            _const_spec((8, C, C)),
+            _const_spec((8, R, R)),
+            _const_spec((G - 1, C, 128)),
+            _const_spec((G - 1, C, 128)),
+            _const_spec((G, C, H * R)),
+            _const_spec((G, C, H * R)),
+            in_spec,
+            in_spec,
+        ],
+        out_specs=[out_spec, out_spec],
+        scratch_shapes=[pltpu.VMEM((C, L), jnp.uint32)] * 2
+        + [pltpu.VMEM((C // 128, L, 128), jnp.uint32)] * 2,
+        interpret=interpret,
+        compiler_params=None if interpret else _COMPILER_PARAMS,
+    )(e, f, *tw1, *tw2, lo, hi)

@@ -660,7 +660,9 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
     commit_specs("wit", B_wit, stream)
     commit_specs("s2", S, stream)
     commit_specs("q", B_q, stream_q, mono=False)
-    commit_specs("setup", B_setup, stream_setup)
+    # the setup's monomials are generate_setup's, on the u64 path: nothing
+    # hands them to `monomial_from_values_p` (a Mosaic compile a chunk shape)
+    commit_specs("setup", B_setup, stream_setup, mono=False)
     for b in sorted(absorb_blocks):
         if smm is not None:
             add(

@@ -278,3 +278,34 @@ def test_fused_forward_ntt_at_the_era_chunks(one_chip, form):
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes >> 20 == 128  # (64, n) or (32, 2, n) x 2
     assert mem.temp_size_in_bytes <= mem.output_size_in_bytes + (1 << 20)
+
+
+# the commits' chunks above 2^16 rows: 64 columns cut from the tree cell's
+# 130-column witness at 2^18 rows; a whole 32-column chunk at 2^19
+@pytest.mark.parametrize("B,b,log_n", [(130, 64, 18), (32, 32, 19)])
+def test_fused_inverse_ntt_at_the_era_chunks(one_chip, B, b, log_n):
+    """ISSUE 40: the commits' inverse transform as one program a chunk: the
+    inverse matmul kernel on the values as they lie, the chunk's slice and
+    the relayouts to and from the kernel's (b, 256, 2^k 256 h) inside the
+    same program, and at 2^19 rows the trailing stage as a program of its
+    own; each lowers and compiles for the chip with one Mosaic call (the
+    trailing program none) and holds no temporary beyond a chunk."""
+    from boojum_tpu.ntt import limb_ntt as LN
+    from boojum_tpu.ntt import mxu_ntt
+
+    n = 1 << log_n
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    call = (None, None) if b == B else (start, b)
+    compiled = LN._imono_p_fused.lower(_pair(one_chip, B, n), *call).compile()
+    assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") == 1
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >> 20 == 128  # (64, 2^18) or (32, 2^19) x 2
+    assert mem.temp_size_in_bytes <= mem.output_size_in_bytes + (1 << 20)
+    if not mxu_ntt.leading_outer_stages(log_n):
+        return
+    staged = _pair(one_chip, b, 2, 256, 1024)
+    compiled = LN._imono_p_trailing.lower(staged).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >> 20 == 128
+    assert mem.temp_size_in_bytes <= mem.output_size_in_bytes + (1 << 20)

@@ -382,3 +382,20 @@ def test_era_library_lists_one_fused_transform_a_chunk_at_the_cells_size(
         f"lde_hybrid_limbres_b{b}_n{n}_L2:fused" for b in (27, 32)
     ]
     assert {s[1].__name__ for s in lde} == {"_lde_planes_hybrid_fused_p"}
+    # ISSUE 40: the commits' inverse transform likewise: the inverse matmul
+    # kernel's program for each size of chunk (witness 155 = 64 + 64 + 27,
+    # stage 2 62), and at 2^19 rows the trailing stage with it
+    # (155 = 4 x 32 + 27, 62 = 32 + 30); `imono_p` is the families' name
+    for log_n, parts, chunks in (
+        (18, ("fused",), {155: (27, 64), 62: (62,)}),
+        (19, ("fused", "trailing"), {155: (27, 32), 62: (30, 32)}),
+    ):
+        for B, sizes in chunks.items():
+            mono = LN.plane_ntt_kernel_specs(B, log_n)
+            assert [s[0] for s in mono] == [
+                f"imono_kernel_limbres_b{b}_n{1 << log_n}:{part}"
+                for b in sizes for part in parts
+            ]
+            assert {s[1].__name__ for s in mono} == {
+                f"_imono_p_{part}" for part in parts
+            }
