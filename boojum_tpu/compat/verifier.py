@@ -153,7 +153,13 @@ def compute_fri_schedule(
     return new_pow_bits, num_queries, schedule, 1 << degree
 
 
-def _verify_merkle_path(leaf_elements, path, cap, idx):
+def _verify_merkle_path(
+    leaf_elements, path, cap, idx, tree_hasher="poseidon2"
+):
+    if tree_hasher == "blake2s":
+        from .blake2s_tree import verify_path
+
+        return verify_path(leaf_elements, path, cap, idx)
     cur = tuple(Poseidon2SpongeHost.hash_leaf(leaf_elements))
     i = idx
     for sib in path:

@@ -110,6 +110,14 @@ def recursive_verify(cs, vk, proof, gates):
     into `cs`. `gates` is the inner circuit's gate list (the verifier is
     built from the same gate configuration, reference
     recursive_verifier_builder.rs)."""
+    from ...prover.config import require_poseidon2_tree
+
+    # a Blake2s tree is the hasher of proofs nothing recurses over: the
+    # circuit hashes leaves and nodes with the Poseidon2 gate alone
+    require_poseidon2_tree(
+        getattr(vk, "tree_hasher", "poseidon2"),
+        "as the inner key of recursive_verify",
+    )
     first_row = cs.next_row
     with _span("recursion.allocate_proof"):
         ap = AllocatedProof(cs, proof)

@@ -1,4 +1,4 @@
-"""Merkle tree with cap over Poseidon2 digests — device construction.
+"""Merkle tree with cap — device construction, under the key's tree hasher.
 
 Counterpart of `/root/reference/src/cs/oracle/merkle_tree.rs:17` (construct
 `:78`, get_proof `:462`, verify_proof_over_cap `:482`). Leaves are rows of a
@@ -8,17 +8,26 @@ batched sponge over the whole array, node layers are batched 2-to-1 hashes.
 The cap (top 2^k nodes) replaces the single root. Query-path extraction
 gathers from the stored device layers on host at query time (queries are rare:
 ~100 per proof).
+
+Two tree hashers (`ProofConfig.tree_hasher`, kept in the key): Poseidon2
+over Goldilocks, the default and the one a circuit can verify, and
+Blake2s-256 (`hashes/blake2s.py`), upstream's for proofs nothing recurses
+over. A digest is four u64 words either way, so the trees, caps, paths and
+proofs keep their shapes. `tree_hasher(name)` hands a prove its hasher's
+programs, once; the Poseidon2 record holds the functions below themselves.
 """
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .field import limbs as _limbs
+from .hashes import blake2s as _b2s
 from .hashes.poseidon2 import (
     Poseidon2SpongeHost,
     leaf_hash,
@@ -164,6 +173,103 @@ def commit_layers_planes(lde_p, cap_size: int):
     return node_layers_planes(leaf_digests_planes(lde_p), cap_size)
 
 
+class TreeHasher(NamedTuple):
+    """One tree hasher's commit programs, by representation."""
+
+    name: str
+    leaf_digests_device: Callable
+    node_layers_device: Callable
+    commit_layers_device: Callable
+    leaf_digests_planes: Callable
+    node_layers_planes: Callable
+    commit_layers_planes: Callable
+
+    @property
+    def tag(self) -> str:
+        """What the kernel library's names carry: nothing for Poseidon2,
+        whose names are what they were."""
+        return "" if self.name == "poseidon2" else f"_{self.name}"
+
+
+POSEIDON2 = TreeHasher(
+    "poseidon2",
+    leaf_digests_device, node_layers_device, commit_layers_device,
+    leaf_digests_planes, node_layers_planes, commit_layers_planes,
+)
+
+
+# ---------------------------------------------------------------------------
+# Blake2s commit kernels: the same two shape-keyed dispatches a commit, on
+# the word vectors of hashes/blake2s.py (plain XLA: add, xor, rotate). The
+# columns are the message as they lie: no leaf-major transpose, no reshape.
+# Made when a Blake2s key first asks (`tree_hasher`), not at import: the
+# programs are jitted for the backend that is then known (_b2s.jit).
+# ---------------------------------------------------------------------------
+
+
+@lru_cache(maxsize=None)
+def _blake2s_hasher() -> TreeHasher:
+    @_b2s.jit
+    def leaf_digests_blake2s_planes(lde_p):
+        """(B, ...) column planes -> (N, 4) Blake2s digest planes."""
+        return _b2s.leaf_hash_planes(*lde_p)
+
+    @partial(_b2s.jit, static_argnums=(1,))
+    def node_layers_blake2s_planes(digests_p, cap_size: int):
+        """(N, 4) digest planes -> every layer down to the cap."""
+        return _b2s.node_layers_planes(digests_p, cap_size)
+
+    @_b2s.jit
+    def leaf_digests_blake2s_device(lde_cols):
+        """The u64 twin (split and joined inside the program)."""
+        return _b2s.leaf_hash_u64(lde_cols)
+
+    @partial(_b2s.jit, static_argnums=(1,))
+    def node_layers_blake2s_device(digests, cap_size: int):
+        return _b2s.node_layers_u64(digests, cap_size)
+
+    def counted(leaf_digests, node_layers):
+        def commit_layers(lde, cap_size: int):
+            shape = (lde[0] if isinstance(lde, tuple) else lde).shape
+            _metrics.count("merkle.commit_layer_builds")
+            _metrics.count(
+                "merkle.blake2s_compressions",
+                _b2s.compressions(
+                    shape[0], int(np.prod(shape[1:])), cap_size
+                ),
+            )
+            return node_layers(leaf_digests(lde), cap_size)
+
+        return commit_layers
+
+    return TreeHasher(
+        "blake2s",
+        leaf_digests_blake2s_device, node_layers_blake2s_device,
+        counted(leaf_digests_blake2s_device, node_layers_blake2s_device),
+        leaf_digests_blake2s_planes, node_layers_blake2s_planes,
+        counted(leaf_digests_blake2s_planes, node_layers_blake2s_planes),
+    )
+
+
+def tree_hasher(name: str = "poseidon2") -> TreeHasher:
+    """The hasher a key names (`vk.tree_hasher`; a key from before the
+    field is a Poseidon2 key)."""
+    if name == "poseidon2":
+        return POSEIDON2
+    if name == "blake2s":
+        return _blake2s_hasher()
+    raise ValueError(f"unknown tree hasher: {name!r}")
+
+
+def _paths_by_query(levels, num_queries: int):
+    """The gathered sibling levels, each (queries, 4) u64 on the host, as
+    one path of digest tuples a query. The conversion to Python ints is
+    numpy's own loop (`tolist`): the device is idle while the host builds
+    the openings, so this sits on a prove's critical path."""
+    rows = [np.asarray(level).tolist() for level in levels]
+    return [[tuple(r[q]) for r in rows] for q in range(num_queries)]
+
+
 def _cap_host_from_planes(cap_p):
     cap = _limbs.join_np(_host_np(cap_p[0]), _host_np(cap_p[1]))
     return [tuple(int(x) for x in row) for row in cap]
@@ -216,10 +322,7 @@ class PlaneMerkleTree:
                 _limbs.join_np(levels[2 * i], levels[2 * i + 1])
                 for i in range(len(levels) // 2)
             ]
-            return [
-                [tuple(int(x) for x in level[q]) for level in joined]
-                for q in range(len(idxs))
-            ]
+            return _paths_by_query(joined, len(idxs))
 
         return plans, assemble
 
@@ -297,10 +400,7 @@ class MerkleTreeWithCap:
             cur = cur >> 1
 
         def assemble(levels):
-            return [
-                [tuple(int(x) for x in level[q]) for level in levels]
-                for q in range(len(idxs))
-            ]
+            return _paths_by_query(levels, len(idxs))
 
         return plans, assemble
 
@@ -325,8 +425,17 @@ class MerkleTreeWithCap:
         return self.get_proofs([leaf_idx])[0]
 
 
-def verify_proof_over_cap(leaf_values, path, cap, leaf_idx: int) -> bool:
-    """Host-side path verification (python ints), reference `:482` semantics."""
+def verify_proof_over_cap(
+    leaf_values, path, cap, leaf_idx: int, hasher: str = "poseidon2"
+) -> bool:
+    """Host-side path verification (python ints), reference `:482`
+    semantics; a Blake2s path through `hashlib` (compat/blake2s_tree.py),
+    never through the device hash."""
+    if hasher == "blake2s":
+        from .compat.blake2s_tree import verify_path
+
+        return verify_path(leaf_values, path, cap, leaf_idx)
+    assert hasher == "poseidon2", hasher
     digest = Poseidon2SpongeHost.hash_leaf([int(v) for v in leaf_values])
     idx = leaf_idx
     for sib in path:

@@ -2,6 +2,24 @@
 
 from dataclasses import dataclass
 
+# Merkle tree hashers a key can name (merkle.tree_hasher resolves them)
+TREE_HASHERS = ("poseidon2", "blake2s")
+
+
+class TreeHasherNotSupported(NotImplementedError):
+    """A Blake2s tree asked of a path no deployment runs it on: a streamed
+    commit, a mesh, the BabyBear prover, the in-circuit verifier."""
+
+
+def require_poseidon2_tree(tree_hasher: str, where: str):
+    """Raise by name where only Poseidon2 trees run (`where` ends the
+    sentence: "under a mesh")."""
+    if tree_hasher != "poseidon2":
+        raise TreeHasherNotSupported(
+            f"tree_hasher={tree_hasher!r} is not supported {where}: "
+            "only Poseidon2 trees run there"
+        )
+
 
 @dataclass
 class ProofConfig:
@@ -24,8 +42,13 @@ class ProofConfig:
     quotient_degree: int | None = None
     # Fiat-Shamir transcript kind: poseidon2 (default, recursion-compatible)
     # | poseidon (legacy round function) | blake2s | keccak256 (reference
-    # transcript.rs:48,155,264 — the tree hasher stays Poseidon2)
+    # transcript.rs:48,155,264), chosen apart from the tree hasher
     transcript: str = "poseidon2"
+    # Merkle tree hasher of every oracle and of the setup: poseidon2
+    # (default; what a circuit can verify) | blake2s (upstream's
+    # non-recursive benches: leaf = Blake2s-256 of the elements' LE bytes,
+    # node = Blake2s-256 of left || right). Kept in the key.
+    tree_hasher: str = "poseidon2"
 
     def __post_init__(self):
         assert self.fri_lde_factor & (self.fri_lde_factor - 1) == 0
@@ -35,6 +58,7 @@ class ProofConfig:
         from ..transcript import TRANSCRIPTS
 
         assert self.transcript in TRANSCRIPTS, self.transcript
+        assert self.tree_hasher in TREE_HASHERS, self.tree_hasher
         if self.quotient_degree is not None:
             assert self.quotient_degree >= 1
             assert self.quotient_degree & (self.quotient_degree - 1) == 0

@@ -20,7 +20,7 @@ import numpy as np
 from ..field import gl
 from ..field import extension as ext_f
 from ..field import goldilocks as gf
-from ..merkle import MerkleTreeWithCap
+from ..merkle import POSEIDON2, MerkleTreeWithCap
 from ..utils import metrics as _metrics
 from ..utils import transfer as _transfer
 from ..utils.report import checkpoint as _checkpoint
@@ -265,6 +265,54 @@ def _fri_commit_fn_p(k: int, cap: int):
     return _fri_oracle_p
 
 
+def _fri_leaf_columns(c0, c1, k: int):
+    """The regrouped leaves column-major, (2 * 2^k, N >> k): element e of
+    leaf i is coordinate e % 2 of point i * 2^k + e // 2, the order the
+    row-major regrouping of `_fri_commit_fn` absorbs."""
+    rows = c0.shape[0] >> k
+    return jnp.stack(
+        [c0.reshape(rows, -1).T, c1.reshape(rows, -1).T], axis=1
+    ).reshape(-1, rows)
+
+
+@lru_cache(maxsize=None)
+def _fri_commit_fn_blake2s(k: int, cap: int):
+    """`_fri_commit_fn` under the Blake2s tree hasher (u64 words)."""
+    from ..hashes import blake2s as b2s
+
+    @b2s.jit
+    def _fri_oracle_blake2s(c0, c1):
+        digests = b2s.leaf_hash_u64(_fri_leaf_columns(c0, c1, k))
+        return b2s.node_layers_u64(digests, cap)
+
+    return _fri_oracle_blake2s
+
+
+@lru_cache(maxsize=None)
+def _fri_commit_fn_blake2s_p(k: int, cap: int):
+    """`_fri_commit_fn_p` under the Blake2s tree hasher (limb planes)."""
+    from ..hashes import blake2s as b2s
+
+    @b2s.jit
+    def _fri_oracle_blake2s_p(c0, c1):
+        digests_p = b2s.leaf_hash_planes(
+            _fri_leaf_columns(c0[0], c1[0], k),
+            _fri_leaf_columns(c0[1], c1[1], k),
+        )
+        return b2s.node_layers_planes(digests_p, cap)
+
+    return _fri_oracle_blake2s_p
+
+
+def fri_commit_fn(hasher, planes: bool):
+    """The oracle-commit program factory `(k, cap) -> jitted fn` of a tree
+    hasher in a representation; Poseidon2's are the two above themselves."""
+    if hasher is POSEIDON2:
+        return _fri_commit_fn_p if planes else _fri_commit_fn
+    assert hasher.name == "blake2s", hasher.name
+    return _fri_commit_fn_blake2s_p if planes else _fri_commit_fn_blake2s
+
+
 @lru_cache(maxsize=None)
 def _fri_fold_fn_p(k: int, mesh=None):
     """Resident k-fold for one schedule entry: the whole chain — including
@@ -321,7 +369,7 @@ def _fri_final_p(c0, c1, shift_inv: int):
 
 
 def fri_kernel_specs(
-    base_degree: int, config, planes: bool, smm=None
+    base_degree: int, config, planes: bool, smm=None, hasher=POSEIDON2
 ) -> list:
     """(name, jitted_fn, args) triples for every top-level executable a
     fused `fri_prove` dispatches for this (base_degree, config) in the
@@ -371,7 +419,7 @@ def fri_kernel_specs(
             else:
                 specs.append((
                     f"fri_commit_limbres_k{k}_n{cur}",
-                    _fri_commit_fn_p(k, cap),
+                    fri_commit_fn(hasher, True)(k, cap),
                     ext_p,
                 ))
             tables = tuple(
@@ -397,7 +445,7 @@ def fri_kernel_specs(
         else:
             specs.append((
                 f"fri_commit_k{k}_n{cur}",
-                _fri_commit_fn(k, cap),
+                fri_commit_fn(hasher, False)(k, cap),
                 (sds(cur), sds(cur)),
             ))
         tables = tuple(
@@ -426,7 +474,8 @@ def fri_kernel_specs(
 
 
 def fri_prove(
-    codeword, transcript, config, base_degree: int, variant
+    codeword, transcript, config, base_degree: int, variant,
+    hasher=POSEIDON2,
 ) -> FriOracles:
     """codeword: ext pair over full LDE domain (brev layout). `variant` is
     the prove's resolved KernelVariant (utils/pallas_util.py).
@@ -499,7 +548,7 @@ def fri_prove(
                         cur, k, config.merkle_tree_cap_size, mesh_k
                     )
                 else:
-                    layers = _fri_commit_fn_p(
+                    layers = fri_commit_fn(hasher, True)(
                         k, config.merkle_tree_cap_size
                     )(cur[0], cur[1])
                 with _transfer.pull_site(f"fri_cap_{r}"):
@@ -514,7 +563,7 @@ def fri_prove(
                         cur, k, config.merkle_tree_cap_size, mesh_k
                     )
                 else:
-                    layers = _fri_commit_fn(
+                    layers = fri_commit_fn(hasher, False)(
                         k, config.merkle_tree_cap_size
                     )(*cur)
                 with _transfer.pull_site(f"fri_cap_{r}"):

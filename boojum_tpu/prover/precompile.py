@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from ..utils import metrics as _metrics
 from ..utils.profiling import CompileLedger, current_compile_ledger
 from ..utils.spans import span as _span
+from .config import require_poseidon2_tree
 
 
 def trim_host_heap():
@@ -152,7 +153,7 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
     replicated Merkle tail after the cap all_gather, and the PoW grind
     (host-side). A streamed prove's leaf-value gathers and single-column
     opens are listed: each holds a forward transform."""
-    from ..merkle import leaf_digests_device, node_layers_device
+    from ..merkle import tree_hasher
     from ..field import extension as ext_f
     from ..ntt.ntt import _ext_powers_jit, ntt_kernel_specs
     from .fri import fri_kernel_specs
@@ -189,10 +190,17 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
     variant = resolve_variant(mesh)
     smm = mesh if variant.mesh == "shard_map" else None
     D = SS.mesh_devices(smm) if smm is not None else 1
+    # the key's tree hasher: its leaf, node and FRI-oracle programs are
+    # listed when, and only when, the configuration names it
+    hasher = tree_hasher(getattr(config, "tree_hasher", "poseidon2"))
+    htag = hasher.tag
+    if mesh is not None:
+        require_poseidon2_tree(hasher.name, "under a mesh")
     if variant.field == "babybear":
+        require_poseidon2_tree(hasher.name, "in the BabyBear prover")
         return _enumerate_babybear(assembly, config)
     if variant.planes:
-        return _enumerate_resident(assembly, config, smm, D)
+        return _enumerate_resident(assembly, config, smm, D, hasher)
 
     # ONE derivation of every shape-keyed quantity, shared with the
     # service admission queue and the compile-ledger tags (shape_key.py)
@@ -224,6 +232,8 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
     total_cols = B_all
     stream = use_streamed_lde(total_cols, N)
     stream_setup = use_streamed_lde(B_setup, N)
+    if stream or stream_setup:
+        require_poseidon2_tree(hasher.name, "on a streamed commit")
 
     specs: list[KernelSpec] = []
 
@@ -244,7 +254,10 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
             for i in range(0, B, COL_BLOCK):
                 absorb_blocks.add(min(COL_BLOCK, B - i))
         else:
-            add(f"{tag}:leaf_digests", leaf_digests_device, _sds(B, L, n))
+            add(
+                f"{tag}:leaf_digests{htag}", hasher.leaf_digests_device,
+                _sds(B, L, n),
+            )
 
     def commit_specs_sm(tag, B, streamed, mono=True):
         # the per-chip pipeline (shard_sweep.commit_pipeline_sm): local
@@ -288,7 +301,7 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
             add(f"lde_block_cols_b{b}", _lde_block_cols, _sds(b, n), L)
         add(f"absorb_cols_b{b}", _absorb_cols, _sds(N, 12), _sds(N, b))
     if smm is None:
-        add("node_layers", node_layers_device, _sds(N, 4), cap)
+        add(f"node_layers{htag}", hasher.node_layers_device, _sds(N, 4), cap)
     else:
         # per-chip node layers while digest pairs stay shard-local
         # (shard_sweep.node_layers_sm; the replicated tail past the
@@ -455,7 +468,7 @@ def enumerate_kernels(assembly, config, mesh_shape=None) -> list[KernelSpec]:
             pair(2), pair(num_lk), _sds(num_pi), _sds(2 + num_lk + num_pi),
             _sds(2 + num_lk + num_pi),
         )
-    for nm, fn, args in fri_kernel_specs(n, config, False, smm):
+    for nm, fn, args in fri_kernel_specs(n, config, False, smm, hasher):
         add(nm, fn, *args)
 
     # ---- cached domain tables (built once per geometry, but their batch
@@ -565,13 +578,12 @@ def _enumerate_babybear_full(sb) -> list[KernelSpec]:
     return specs
 
 
-def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
+def _enumerate_resident(assembly, config, smm, D, hasher) -> list[KernelSpec]:
     """The limb-RESIDENT kernel library (enumerate_kernels' plane twin):
     every executable a resident prove dispatches, with `_limbres`-tagged
     ledger names and (lo, hi) u32 plane-pair argument specs. Mirrors the
     derivations of prover._prove_impl's resident branches exactly."""
     from ..field import limb_ops as lop
-    from ..merkle import leaf_digests_planes, node_layers_planes
     from ..ntt.limb_ntt import plane_ntt_kernel_specs
     from .fri import fri_kernel_specs
     from .setup import build_selector_tree, non_residues_for_copy_permutation
@@ -589,6 +601,7 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
     from ..parallel import shard_sweep as SS
     from ..utils import transfer as _transfer
 
+    htag = hasher.tag
     sb = shape_bucket(assembly, config)
     n, log_n, L, N, cap = (
         sb.trace_len, sb.log_n, sb.lde_factor, sb.domain_len, sb.cap_size
@@ -609,6 +622,8 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
     non_residues = non_residues_for_copy_permutation(Ct)
     stream = use_streamed_lde(B_all, N)
     stream_setup = use_streamed_lde(B_setup, N)
+    if stream or stream_setup:
+        require_poseidon2_tree(hasher.name, "on a streamed commit")
 
     specs: list[KernelSpec] = []
 
@@ -650,8 +665,8 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
                 add(nm, fn, *args)
         else:
             add(
-                f"{tag}:leaf_digests_limbres", leaf_digests_planes,
-                _sdsp(B, L, n),
+                f"{tag}:leaf_digests{htag}_limbres",
+                hasher.leaf_digests_planes, _sdsp(B, L, n),
             )
 
     # the quotient streams with the prove's other commits (the shard_map
@@ -682,7 +697,10 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
     if absorb_blocks and smm is None:
         add("absorb_cols_digests_limbres", _absorb_cols_digests_p, _sdsp(12, N))
     if smm is None:
-        add("node_layers_limbres", node_layers_planes, _sdsp(N, 4), cap)
+        add(
+            f"node_layers{htag}_limbres", hasher.node_layers_planes,
+            _sdsp(N, 4), cap,
+        )
     else:
         steps, gather = SS.node_plan(N, cap, D)
         for cur in steps:
@@ -852,7 +870,7 @@ def _enumerate_resident(assembly, config, smm, D) -> list[KernelSpec]:
             pairp(2), pairp(num_lk), _sdsp(num_pi),
             _sdsp(2 + num_lk + num_pi), _sdsp(2 + num_lk + num_pi),
         )
-    for nm, fn, args in fri_kernel_specs(n, config, True, smm):
+    for nm, fn, args in fri_kernel_specs(n, config, True, smm, hasher):
         add(nm, fn, *args)
 
     # ---- cached plane domain tables' inversions --------------------------

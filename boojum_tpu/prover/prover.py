@@ -31,7 +31,7 @@ import numpy as np
 from ..field import gl
 from ..field import extension as ext_f
 from ..field import goldilocks as gf
-from ..merkle import MerkleTreeWithCap
+from ..merkle import POSEIDON2, MerkleTreeWithCap, tree_hasher
 from ..ntt import (
     bitreverse_indices,
     ext_powers_device,
@@ -46,7 +46,7 @@ from ..ntt import (
     powers_device,
 )
 from ..transcript import BitSource, make_prover_transcript
-from .config import ProofConfig
+from .config import ProofConfig, require_poseidon2_tree
 from .fri import fri_prove
 from .pow import pow_grind
 from .proof import OracleQuery, Proof, SingleRoundQueries
@@ -54,6 +54,7 @@ from ..utils import metrics as _metrics
 from ..utils import transfer as _transfer
 from ..utils.report import checkpoint as _checkpoint
 from ..utils.spans import span as _span
+from ..utils.spans import span_attr as _span_attr
 from ..utils.spans import sync_point as _sync_point
 
 
@@ -328,7 +329,8 @@ def _dev_cached(obj, name: str, build):
     return cache[name]
 
 
-def _commit_pipeline(values, L: int, cap: int, stream: bool, sm_mesh=None):
+def _commit_pipeline(values, L: int, cap: int, stream: bool, sm_mesh=None,
+                     hasher=POSEIDON2):
     """values over H (B, n) -> (mono, lde | None, tree layers).
 
     (Flight recorder: one `commit_pipeline` span per oracle, NTT/Merkle
@@ -351,9 +353,8 @@ def _commit_pipeline(values, L: int, cap: int, stream: bool, sm_mesh=None):
     parallel/shard_sweep.commit_pipeline_sm: per-chip iNTT/LDE, the
     explicit all_to_all layout pivot, per-chip leaf sponges and an
     explicit cap all_gather — same return contract, bit-identical
-    digests."""
-    from ..merkle import commit_layers_device
-
+    digests. `hasher` is the prove's tree hasher (merkle.TreeHasher); the
+    mesh and the streamed commit are Poseidon2's alone."""
     if sm_mesh is not None:
         from ..parallel.shard_sweep import commit_pipeline_sm
 
@@ -367,7 +368,7 @@ def _commit_pipeline(values, L: int, cap: int, stream: bool, sm_mesh=None):
         lde = lde_from_monomial(mono, L)
         _metrics.count("ntt.lde_from_monomial")
         _metrics.count("merkle.commits")
-        return mono, lde, commit_layers_device(lde, cap)
+        return mono, lde, hasher.commit_layers_device(lde, cap)
 
 
 def _streamed_commit_layers(mono, L: int, cap: int):
@@ -854,7 +855,7 @@ def _quotient_interp(T0_parts, T1_parts, Q: int, n: int):
 
 
 def _quotient_tail_fused(T0_parts, T1_parts, Q: int, n: int, L: int, cap: int,
-                         stream: bool = False):
+                         stream: bool = False, hasher=POSEIDON2):
     """Quotient interpolation + chunk split + LDE + commit (streamed from
     the monomials where the prove's commits stream).
 
@@ -866,13 +867,11 @@ def _quotient_tail_fused(T0_parts, T1_parts, Q: int, n: int, L: int, cap: int,
     was part of the round-4 cold-start bill. The extra launches cost tens
     of ms; the freed intermediates are GBs and the node stack shares its
     executable with every other oracle (merkle.commit_layers_device)."""
-    from ..merkle import commit_layers_device
-
     q_mono = _quotient_interp(tuple(T0_parts), tuple(T1_parts), Q, n)
     if stream:
         return q_mono, None, _streamed_commit_layers(q_mono, L, cap)
     q_lde = lde_from_monomial(q_mono, L)
-    return q_mono, q_lde, commit_layers_device(q_lde, cap)
+    return q_mono, q_lde, hasher.commit_layers_device(q_lde, cap)
 
 
 @jax.jit
@@ -1400,6 +1399,16 @@ def _prove_impl(
     Q_est = setup.vk.effective_quotient_degree()
     total_cols = (Ct + W + M) + (Ct + K + TW) + S_est + 2 * Q_est
     stream = fused and use_streamed_lde(total_cols, N)
+    # THE tree hasher, once a prove, from the key; Poseidon2 resolves to
+    # the functions this module always called (merkle.POSEIDON2)
+    hasher = tree_hasher(getattr(setup.vk, "tree_hasher", "poseidon2"))
+    _span_attr("prover.tree_hasher", hasher.name)
+    if sm_mesh is not None or not fused:
+        require_poseidon2_tree(hasher.name, "under a mesh")
+    if stream or setup.setup_lde is None:
+        require_poseidon2_tree(hasher.name, "on a streamed commit")
+    if hasher is not POSEIDON2:
+        _metrics.count("merkle.blake2s_compressions", 0)
 
     _chips = 1 if sm_mesh is None else sm_mesh.size
 
@@ -1465,11 +1474,11 @@ def _prove_impl(
     if fused:
         if res:
             wit_mono, wit_lde, layers = RES.commit_pipeline_p(
-                witness_cols, L, cap, stream, sm_mesh
+                witness_cols, L, cap, stream, sm_mesh, hasher
             )
         else:
             wit_mono, wit_lde, layers = _commit_pipeline(
-                witness_cols, L, cap, stream, sm_mesh
+                witness_cols, L, cap, stream, sm_mesh, hasher
             )
         _prefetch_r(layers[-1])  # cap d2h rides the queue
         with _transfer.pull_site("witness_cap"):
@@ -1571,7 +1580,7 @@ def _prove_impl(
         stack = RES.stage2_stack_fn_p(assembly, setup.selector_paths)
         s2_vals = stack(z_pp[0], z_pp[1], lk_inv, mult_dev, consts_dev)
         s2_mono, s2_lde, layers = RES.commit_pipeline_p(
-            s2_vals, L, cap, stream, sm_mesh
+            s2_vals, L, cap, stream, sm_mesh, hasher
         )
         del s2_vals
         _prefetch_r(layers[-1])
@@ -1660,7 +1669,7 @@ def _prove_impl(
         stack = _stage2_stack_fn(assembly, setup.selector_paths)
         s2_vals = stack(z_pp[0], z_pp[1], lk_inv, mult_dev, consts_dev)
         s2_mono, s2_lde, layers = _commit_pipeline(
-            s2_vals, L, cap, stream, sm_mesh
+            s2_vals, L, cap, stream, sm_mesh, hasher
         )
         del s2_vals
         _transfer.prefetch_async(layers[-1])
@@ -2034,11 +2043,13 @@ def _prove_impl(
                 q_lde, layers = commit_from_mono_sm(q_mono, L, cap, sm_mesh)
         elif res:
             q_mono, q_lde, layers = RES._quotient_tail_p(
-                tuple(T_parts0), tuple(T_parts1), Q, n, L, cap, stream
+                tuple(T_parts0), tuple(T_parts1), Q, n, L, cap, stream,
+                hasher,
             )
         else:
             q_mono, q_lde, layers = _quotient_tail_fused(
-                tuple(T_parts0), tuple(T_parts1), Q, n, L, cap, stream
+                tuple(T_parts0), tuple(T_parts1), Q, n, L, cap, stream,
+                hasher,
             )
         del T_parts0, T_parts1
         _prefetch_r(layers[-1])
@@ -2521,7 +2532,7 @@ def _prove_impl(
                 h = ext_f.add(h, (gf.mul(term_base, ch[0]), gf.mul(term_base, ch[1])))
 
     _sync_point(h, "deep_codeword")
-    fri = fri_prove(h, t, config, n, variant)
+    fri = fri_prove(h, t, config, n, variant, hasher)
     pow_nonce = pow_grind(t, config.pow_bits)
     _checkpoint(5, "pow_nonce", [pow_nonce])
 
@@ -2587,17 +2598,26 @@ def _prove_impl(
                 )
             return ("one", _defer(leaves_cols, idx_dev, 1))
 
-        def _defer_oracle(leaves_cols, tree):
-            vals_h = _defer_vals(leaves_cols)
-            gplans, assemble = tree.proof_gather_plans(idxs)
+        def _defer_levels(gplans):
+            """A tree's sibling gathers, a plan a level. The index arrays
+            go up as they did, one device array each, through ONE
+            `device_put` of the list: the device is idle until the gather
+            is dispatched, and a call an array cost the host its dispatch
+            overhead some 140 times a prove."""
             with _transfer.upload(
                 "query_path_indices",
                 sum(ix.nbytes for _layer, ix in gplans), len(gplans),
             ):
-                level_hs = [
-                    _defer(layer, jnp.asarray(ix), 0) for layer, ix in gplans
+                ixs = jax.device_put([ix for _layer, ix in gplans])
+                return [
+                    _defer(layer, ix, 0)
+                    for (layer, _ix), ix in zip(gplans, ixs)
                 ]
-            return vals_h, level_hs, assemble
+
+        def _defer_oracle(leaves_cols, tree):
+            vals_h = _defer_vals(leaves_cols)
+            gplans, assemble = tree.proof_gather_plans(idxs)
+            return vals_h, _defer_levels(gplans), assemble
 
         _q_flat = _quotient_oracle()
         if res:
@@ -2635,14 +2655,9 @@ def _prove_impl(
             gplans, assemble = tree.proof_gather_plans(
                 [int(p) for p in leaf_idx]
             )
-            with _transfer.upload(
-                "query_path_indices",
-                sum(ix.nbytes for _layer, ix in gplans), len(gplans),
-            ):
-                level_hs = [
-                    _defer(layer, jnp.asarray(ix), 0) for layer, ix in gplans
-                ]
-            fri_handles.append((g0_h, g1_h, level_hs, assemble, block))
+            fri_handles.append(
+                (g0_h, g1_h, _defer_levels(gplans), assemble, block)
+            )
             fidxs = leaf_idx
 
     # ONE fused gather dispatch + ONE host transfer
@@ -2706,12 +2721,11 @@ def _prove_impl(
 
         def _oracle_queries(handle):
             vals_h, level_hs, assemble = handle
-            vals = _take_vals(vals_h)
+            # Python ints through numpy's own loop (`tolist`), a row a query
+            vals = _take_vals(vals_h).T.tolist()
             paths = assemble([_take(h) for h in level_hs])
             return [
-                OracleQuery(
-                    leaf_values=[int(x) for x in vals[:, q]], path=paths[q]
-                )
+                OracleQuery(leaf_values=vals[q], path=paths[q])
                 for q in range(len(idxs))
             ]
 
@@ -2719,18 +2733,14 @@ def _prove_impl(
         fri_qs_per_round = []
         num_q = len(idxs)
         for g0_h, g1_h, level_hs, assemble, block in fri_handles:
-            gathered = np.stack([_take_vals(g0_h), _take_vals(g1_h)])
+            # a leaf is `block` (c0, c1) pairs: row q holds them in order
+            gathered = np.stack(
+                [_take_vals(g0_h), _take_vals(g1_h)], axis=-1
+            ).reshape(num_q, 2 * block).tolist()
             paths = assemble([_take(h) for h in level_hs])
             fri_qs_per_round.append(
                 [
-                    OracleQuery(
-                        leaf_values=[
-                            int(gathered[c, q * block + j])
-                            for j in range(block)
-                            for c in (0, 1)
-                        ],
-                        path=paths[q],
-                    )
+                    OracleQuery(leaf_values=gathered[q], path=paths[q])
                     for q in range(num_q)
                 ]
             )

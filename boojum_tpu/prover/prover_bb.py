@@ -217,6 +217,11 @@ def prove_full_babybear(
         "babybear full prover supports specialized lookup columns only"
     )
     assert setup.vk.transcript.endswith("babybear"), setup.vk.transcript
+    from .config import require_poseidon2_tree
+
+    require_poseidon2_tree(
+        getattr(setup.vk, "tree_hasher", "poseidon2"), "in the BabyBear prover"
+    )
     Q = setup.vk.effective_quotient_degree()
     num_pi = len(assembly.public_inputs)
     num_lk = (R_args + 1) if lookups else 0

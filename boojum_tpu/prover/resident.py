@@ -49,6 +49,7 @@ from ..field import extension as ext_f
 from ..field import gl
 from ..field import limb_ops as lop
 from ..field import limbs
+from ..merkle import POSEIDON2
 from ..ntt import limb_ntt as LN
 from ..ntt.ntt import _powers_np, bitreverse_indices
 from ..utils import metrics as _metrics
@@ -662,15 +663,13 @@ def quotient_interp_kernel_specs(Q: int, n: int) -> list:
 
 
 def _quotient_tail_p(T0_parts, T1_parts, Q: int, n: int, L: int, cap: int,
-                     stream: bool = False):
+                     stream: bool = False, hasher=POSEIDON2):
     """Plane twin of prover._quotient_tail_fused (same dispatch split)."""
-    from ..merkle import commit_layers_planes
-
     q_mono = quotient_interp_p(tuple(T0_parts), tuple(T1_parts), Q, n)
     if stream:
         return q_mono, None, streamed_commit_layers_p(q_mono, L, cap)
     q_lde = LN.lde_from_monomial_p(q_mono, L)
-    return q_mono, q_lde, commit_layers_planes(q_lde, cap)
+    return q_mono, q_lde, hasher.commit_layers_planes(q_lde, cap)
 
 
 # ---------------------------------------------------------------------------
@@ -1112,11 +1111,10 @@ def streamed_commit_layers_p(mono_p, L: int, cap: int):
         return node_layers_planes(digests, cap)
 
 
-def commit_pipeline_p(values_p, L: int, cap: int, stream: bool, sm_mesh=None):
+def commit_pipeline_p(values_p, L: int, cap: int, stream: bool, sm_mesh=None,
+                      hasher=POSEIDON2):
     """Plane twin of prover._commit_pipeline: values over H (B, n) planes
     -> (mono planes, lde planes | None, plane tree layers)."""
-    from ..merkle import commit_layers_planes
-
     if sm_mesh is not None:
         from ..parallel.shard_sweep import commit_pipeline_sm_p
 
@@ -1133,7 +1131,7 @@ def commit_pipeline_p(values_p, L: int, cap: int, stream: bool, sm_mesh=None):
         _metrics.count("ntt.lde_from_monomial")
         _metrics.count("merkle.commits")
         _metrics.count("merkle.resident_commits")
-        return mono, lde, commit_layers_planes(lde, cap)
+        return mono, lde, hasher.commit_layers_planes(lde, cap)
 
 
 # ---------------------------------------------------------------------------

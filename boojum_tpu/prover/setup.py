@@ -24,6 +24,7 @@ import numpy as np
 from ..field import gl
 from ..merkle import MerkleTreeWithCap
 from ..ntt import lde_from_monomial, monomial_from_values
+from .config import require_poseidon2_tree
 
 
 def build_selector_tree(gates):
@@ -237,6 +238,8 @@ class VerificationKey:
     quotient_degree: int | None = None
     # Fiat-Shamir transcript kind the proof/verifier must replay
     transcript: str = "poseidon2"
+    # Merkle tree hasher of the setup cap and of every oracle of a proof
+    tree_hasher: str = "poseidon2"
 
     def effective_quotient_degree(self) -> int:
         return self.quotient_degree or self.fri_lde_factor
@@ -249,6 +252,7 @@ class VerificationKey:
             "fri_lde_factor": self.fri_lde_factor,
             "quotient_degree": self.quotient_degree,
             "transcript": self.transcript,
+            "tree_hasher": self.tree_hasher,
             "cap_size": self.cap_size,
             "num_queries": self.num_queries,
             "pow_bits": self.pow_bits,
@@ -336,7 +340,9 @@ def generate_setup(assembly, config) -> SetupData:
     full_placement = np.concatenate(
         [assembly.copy_placement, assembly.lookup_placement], axis=0
     )
+    hasher_name = getattr(config, "tree_hasher", "poseidon2")
     if getattr(assembly, "field", "goldilocks") == "babybear":
+        require_poseidon2_tree(hasher_name, "in the BabyBear prover")
         return _generate_setup_babybear(
             assembly, config, full_placement, selector_paths,
             quotient_degree,
@@ -360,6 +366,7 @@ def generate_setup(assembly, config) -> SetupData:
     from .streaming import commit_streaming, use_streamed_lde
 
     if use_streamed_lde(setup_cols.shape[0], n * config.fri_lde_factor):
+        require_poseidon2_tree(hasher_name, "on a streamed commit")
         # beyond the footprint threshold the setup LDE is never
         # materialized: the tree commits from streamed column blocks and
         # the prover regenerates blocks from the monomials (streaming.py)
@@ -372,10 +379,14 @@ def generate_setup(assembly, config) -> SetupData:
         # same shape-keyed leaf-sponge + node-stack dispatches as the
         # prover's commit pipeline, so the setup commit shares executables
         # (and the precompile warm) with the proof oracles
-        from ..merkle import commit_layers_device
+        from ..merkle import tree_hasher
 
         tree = MerkleTreeWithCap.from_layers(
-            list(commit_layers_device(lde, config.merkle_tree_cap_size)),
+            list(
+                tree_hasher(hasher_name).commit_layers_device(
+                    lde, config.merkle_tree_cap_size
+                )
+            ),
             config.merkle_tree_cap_size,
         )
     vk = VerificationKey(
@@ -384,6 +395,7 @@ def generate_setup(assembly, config) -> SetupData:
         fri_lde_factor=config.fri_lde_factor,
         quotient_degree=quotient_degree,
         transcript=getattr(config, "transcript", "poseidon2"),
+        tree_hasher=hasher_name,
         cap_size=config.merkle_tree_cap_size,
         num_queries=config.num_queries,
         pow_bits=config.pow_bits,

@@ -47,6 +47,7 @@ class ShapeBucket:
     # (the dispatched fri_fold_k* kernel set depends on it)
     fri_schedule: tuple
     transcript: str
+    tree_hasher: str
     # -- column geometry ---------------------------------------------------
     num_copy_cols: int         # Cg
     num_lookup_cols: int       # LC
@@ -134,6 +135,9 @@ class ShapeBucket:
 
         fld = active_field()
         field_sfx = f":F{fld}" if fld != "goldilocks" else ""
+        # likewise a non-default tree hasher: its commit programs are its own
+        if self.tree_hasher != "poseidon2":
+            field_sfx += f":H{self.tree_hasher}"
         return (
             f"n2^{self.log_n}:L{self.lde_factor}:cap{self.cap_size}"
             f":q{self.quotient_degree}:Q{self.num_queries}"
@@ -201,7 +205,7 @@ def shape_bucket(assembly, config) -> ShapeBucket:
         config.fri_lde_factor, config.merkle_tree_cap_size,
         config.num_queries, config.pow_bits, config.fri_final_degree,
         tuple(config.fri_folding_schedule or ()), config.quotient_degree,
-        config.transcript,
+        config.transcript, getattr(config, "tree_hasher", "poseidon2"),
     )
     cache = getattr(assembly, "_shape_bucket_cache", None)
     if cache is None:
@@ -231,6 +235,7 @@ def shape_bucket(assembly, config) -> ShapeBucket:
             int(k) for k in (config.fri_folding_schedule or ())
         ),
         transcript=config.transcript,
+        tree_hasher=getattr(config, "tree_hasher", "poseidon2"),
         num_copy_cols=int(Cg),
         num_lookup_cols=int(LC),
         num_wit_cols=int(assembly.wit_placement.shape[0]),

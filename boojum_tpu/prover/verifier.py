@@ -93,6 +93,9 @@ def verify(vk, proof, gates) -> bool:
         return False
 
     # ---- transcript replay ------------------------------------------------
+    # every opened leaf and path is checked on the host under the key's
+    # tree hasher (Blake2s through hashlib, compat/blake2s_tree.py)
+    tree_hasher = getattr(vk, "tree_hasher", "poseidon2")
     t = make_transcript(getattr(vk, 'transcript', 'poseidon2'))
     t.witness_merkle_tree_cap(vk.setup_merkle_cap)
     t.witness_field_elements(proof.public_inputs)
@@ -291,19 +294,23 @@ def verify(vk, proof, gates) -> bool:
         idx = bs.get_index(t, log_full)
         # oracle membership
         if not verify_proof_over_cap(
-            q.witness.leaf_values, q.witness.path, proof.witness_cap, idx
+            q.witness.leaf_values, q.witness.path, proof.witness_cap, idx,
+            tree_hasher,
         ):
             return False
         if not verify_proof_over_cap(
-            q.stage2.leaf_values, q.stage2.path, proof.stage2_cap, idx
+            q.stage2.leaf_values, q.stage2.path, proof.stage2_cap, idx,
+            tree_hasher,
         ):
             return False
         if not verify_proof_over_cap(
-            q.quotient.leaf_values, q.quotient.path, proof.quotient_cap, idx
+            q.quotient.leaf_values, q.quotient.path, proof.quotient_cap, idx,
+            tree_hasher,
         ):
             return False
         if not verify_proof_over_cap(
-            q.setup.leaf_values, q.setup.path, vk.setup_merkle_cap, idx
+            q.setup.leaf_values, q.setup.path, vk.setup_merkle_cap, idx,
+            tree_hasher,
         ):
             return False
         if (
@@ -368,7 +375,8 @@ def verify(vk, proof, gates) -> bool:
             if len(oq.leaf_values) != 2 * block:
                 return False
             if not verify_proof_over_cap(
-                oq.leaf_values, oq.path, proof.fri_caps[r], leaf_idx
+                oq.leaf_values, oq.path, proof.fri_caps[r], leaf_idx,
+                tree_hasher,
             ):
                 return False
             leaves.append(

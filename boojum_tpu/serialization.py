@@ -54,6 +54,7 @@ def vk_from_json(s: str) -> VerificationKey:
             else None
         ),
         transcript=_checked_transcript(d.get("transcript", "poseidon2")),
+        tree_hasher=_checked_tree_hasher(d.get("tree_hasher", "poseidon2")),
     )
 
 
@@ -63,6 +64,14 @@ def _checked_transcript(kind: str) -> str:
     if kind not in TRANSCRIPTS:
         raise ValueError(f"unknown transcript kind in vk: {kind!r}")
     return kind
+
+
+def _checked_tree_hasher(name: str) -> str:
+    from .prover.config import TREE_HASHERS
+
+    if name not in TREE_HASHERS:
+        raise ValueError(f"unknown tree hasher in vk: {name!r}")
+    return name
 
 
 # -- setup fast serialization ------------------------------------------------

@@ -144,8 +144,13 @@ class _ByteTranscript:
             self.buffer += (int(e) % p).to_bytes(width, "little")
 
     def witness_merkle_tree_cap(self, cap):
+        """A cap digest goes in as its bytes, each word little-endian and
+        unreduced: a Blake2s digest's words are not below p (a Poseidon2
+        digest's are, so for it this is what absorbing elements was)."""
+        width = self._SPEC.elem_bytes
         for digest in cap:
-            self.witness_field_elements(digest)
+            for w in digest:
+                self.buffer += int(w).to_bytes(width, "little")
 
     def get_challenge(self) -> int:
         p = self._SPEC.p

@@ -309,3 +309,21 @@ def test_fused_inverse_ntt_at_the_era_chunks(one_chip, B, b, log_n):
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes >> 20 == 128
     assert mem.temp_size_in_bytes <= mem.output_size_in_bytes + (1 << 20)
+
+
+def test_blake2s_witness_leaf_program(one_chip):
+    """ISSUE 42: the Blake2s leaf hash of the SHA-256 cell's witness oracle
+    (93 columns x 2^19 leaves as the LDE planes lie) is plain XLA on word
+    vectors: it compiles for the chip with no Mosaic call, writes the
+    (2^19, 4) digest planes and holds no copy of the columns (a leaf-major
+    transpose or a padded block would be one)."""
+    from boojum_tpu.hashes import blake2s as b2s
+
+    B = COPY_COLS + 8 * 4 + 1
+    lde = _pair(one_chip, B, LDE, 1 << LOG_N)
+    compiled = jax.jit(b2s.leaf_hash_planes).lower(*lde).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    columns = 2 * 4 * B * LEAVES
+    assert mem.argument_size_in_bytes == columns
+    assert mem.temp_size_in_bytes < columns // 4

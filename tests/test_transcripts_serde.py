@@ -42,6 +42,37 @@ def test_byte_transcripts_deterministic_and_sensitive():
         assert more != c1[0]
 
 
+def test_byte_transcript_absorbs_a_cap_digest_as_its_32_bytes():
+    """A Blake2s tree's digest words are any 64-bit values: the cap goes in
+    unreduced, word by word little-endian (for a Poseidon2 digest, whose
+    words are below p, that is what absorbing them as elements was)."""
+    import hashlib
+
+    big = (gl.P, gl.P + 5, (1 << 64) - 1, 7)
+    small = (1, gl.P - 1, 3, 0)
+    for kind in ("blake2s", "keccak256"):
+        t = make_transcript(kind)
+        t.witness_merkle_tree_cap([big, small])
+        raw = b"".join(w.to_bytes(8, "little") for w in big + small)
+        assert bytes(t.buffer) == raw
+        as_elements = make_transcript(kind)
+        as_elements.witness_field_elements(big + small)
+        assert bytes(as_elements.buffer) != raw  # those are reduced mod p
+        same = make_transcript(kind)
+        same.witness_field_elements(small)
+        cap_only = make_transcript(kind)
+        cap_only.witness_merkle_tree_cap([small])
+        assert bytes(same.buffer) == bytes(cap_only.buffer)
+    t = Blake2sTranscript()
+    t.witness_merkle_tree_cap([big])
+    first = t.get_challenge()
+    seed = hashlib.blake2s(
+        b"\x00" * 32 + b"".join(w.to_bytes(8, "little") for w in big)
+    ).digest()
+    block = hashlib.blake2s(seed + (0).to_bytes(4, "little")).digest()
+    assert first == int.from_bytes(block[:8], "little") % gl.P
+
+
 def test_transcript_kinds_differ():
     b = Blake2sTranscript()
     k = Keccak256Transcript()
